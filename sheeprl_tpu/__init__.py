@@ -38,61 +38,16 @@ _load_dotenv()
 
 
 def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache, on by default (the idiom of
-    TPU-native frameworks: compiles are the dominant startup cost — the
-    full-scale DreamerV3 step is ~30-40s per config — and the cache also
-    dedupes identical-HLO graphs built by *different* Python closures
-    within one process, e.g. a benchmark's duty-cycle and end-to-end
-    variants of the same train step). Controls:
-
-      SHEEPRL_TPU_XLA_CACHE=0         disable
-      SHEEPRL_TPU_COMPILE_CACHE=...   the runner/bench shared location
-      JAX_COMPILATION_CACHE_DIR=...   override the cache location
-                                      (default: <tmpdir>/sheeprl_tpu_xla_cache)
-
-    One arming path for the whole repo: `compile/cache.py` (this call,
-    `parallel/mesh.distributed_setup` and `bench.py` all use it — one
-    directory resolution, one compile-time floor). Best-effort: backends
-    whose executables can't be serialized simply skip the cache (jax falls
-    back per-compile)."""
+    """Persistent XLA compilation cache, on by default (compiles are the
+    dominant startup cost — the full-scale DreamerV3 step is ~30-40s per
+    config — and the cache also dedupes identical-HLO graphs built by
+    *different* Python closures within one process). The ONE arming path
+    of the repo is `compile/cache.py`: `JAX_COMPILATION_CACHE_DIR` places
+    the cache, unset it lives at `<checkout>/logs/jax_compile_cache`;
+    `SHEEPRL_TPU_XLA_CACHE=0` disables it."""
     from .compile.cache import arm_compile_cache
 
     arm_compile_cache()
 
 
 _enable_compilation_cache()
-
-
-def _enable_partitionable_rng() -> None:
-    """Layout-invariant PRNG, on by default (SHEEPRL_TPU_PARTITIONABLE_RNG=0
-    opts out). With jax 0.4.37's default (`jax_threefry_partitionable`
-    False), random bits generated inside a sharded jit depend on the GSPMD
-    partitioning of the rng op — a DreamerV3 train step under the (data,
-    seq) mesh draws DIFFERENT posterior/prior samples than the identical
-    unsharded step (State/kl diverged 12% in
-    tests/test_algos/test_seq_parallel.py, compounding through the RSSM
-    scan). A sharded-by-construction framework needs sampling that is a
-    function of (key, shape) alone, so the partitionable threefry scheme is
-    armed process-wide. Random STREAMS change vs the old scheme (same key,
-    different numbers) — run-internal comparisons (checkpoint parity, warm
-    A/B, pipeline on/off) are unaffected because both arms draw from the
-    same scheme.
-
-    Set via env so importing sheeprl_tpu stays jax-free (sheeplint runs on
-    bare CPython in CI); if jax is already imported the live config is
-    updated too."""
-    import sys as _sys
-
-    explicit = _os.environ.get("JAX_THREEFRY_PARTITIONABLE")
-    on = _os.environ.get("SHEEPRL_TPU_PARTITIONABLE_RNG", "1") not in ("0", "false")
-    if explicit is None:
-        _os.environ["JAX_THREEFRY_PARTITIONABLE"] = "true" if on else "false"
-    else:  # an explicit jax-level setting wins over our default
-        on = explicit.lower() not in ("0", "false")
-    if "jax" in _sys.modules:
-        import jax
-
-        jax.config.update("jax_threefry_partitionable", on)
-
-
-_enable_partitionable_rng()

@@ -384,20 +384,20 @@ class RSSM(nn.Module):
     def _fused_step_weights(self, x: jax.Array, embedded_obs: jax.Array):
         """The fused-kernel weight tuple when this RSSM's module structure
         matches the kernel's contract (ops/pallas_kernels.fused_rssm_step),
-        else None -> the caller stays on the plain-XLA path.
+        else None -> the caller stays on the plain-XLA path. Either way the
+        decision is recorded (`kernel.select`, family rssm).
 
         Contract: single-hidden-layer LN MLPs without hidden biases (the
         DV3 `use_bias=not layer_norm` layout), a bias-free LN-GRU, one
         shared activation, and a weight set that fits the VMEM budget."""
-        from ...ops.pallas_kernels import fused_rssm_supported, use_pallas
+        from ...ops.pallas_kernels import fused_rssm_supported, select, use_pallas
 
-        if not use_pallas("rssm") or x.ndim != 2:
+        if not use_pallas("rssm", x, embedded_obs, self.recurrent_model):
             return None
         rm, tm, pm = self.recurrent_model, self.transition_model, self.representation_model
         mlp = getattr(rm, "mlp", None)
         rnn = getattr(rm, "rnn", None)
-        if mlp is None or rnn is None:
-            return None
+        norm = getattr(rnn, "norm", None)
 
         def one_hidden(m):
             return (
@@ -407,16 +407,19 @@ class RSSM(nn.Module):
                 and m.layers[0].bias is None
             )
 
-        if not (one_hidden(mlp) and one_hidden(tm) and one_hidden(pm)):
-            return None
-        if mlp.head is not None or tm.head is None or pm.head is None:
-            return None
-        if tm.head.bias is None or pm.head.bias is None:
-            return None
-        norm = getattr(rnn, "norm", None)
-        if norm is None or norm.scale is None or rnn.proj.bias is not None:
-            return None
-        if not (mlp.act == tm.act == pm.act):
+        fits = (
+            x.ndim == 2
+            and mlp is not None
+            and rnn is not None
+            and one_hidden(mlp) and one_hidden(tm) and one_hidden(pm)
+            and mlp.head is None and tm.head is not None and pm.head is not None
+            and tm.head.bias is not None and pm.head.bias is not None
+            and norm is not None and norm.scale is not None
+            and rnn.proj.bias is None
+            and mlp.act == tm.act == pm.act
+        )
+        if not fits:
+            select("rssm", False)
             return None
         dt = x.dtype
         weights = (

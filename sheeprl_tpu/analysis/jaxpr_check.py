@@ -183,6 +183,7 @@ _HOST_PRIMS = {
     "pure_callback",
     "io_callback",
     "debug_callback",
+    "debug_print",  # what jax.debug.print traces to in the installed jax
     "callback",
     "infeed",
     "outfeed",
@@ -240,12 +241,12 @@ def _subjaxprs(params: dict) -> Iterator[Any]:
     pjit/scan/remat ('jaxpr'), while ('cond_jaxpr'/'body_jaxpr'), cond
     ('branches'), custom_* ('call_jaxpr'), and any future param shape that
     stores jaxprs in lists/tuples."""
-    import jax
+    from jax.extend import core as jex_core
 
     def walk(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (list, tuple)):
             for el in v:
@@ -1005,8 +1006,8 @@ def capture_plan(algo: str, root_dir: str, extra_argv: list[str] | None = None):
     Sets `SHEEPRL_TPU_PLAN_MODE=capture` (CompilePlan.start() raises
     CaptureComplete before the first collection step) and
     `SHEEPRL_TPU_DONATE=1` (donation metadata must survive into the
-    lowering for SC003/the donation fingerprint — nothing executes, so the
-    CPU persistent-cache donation hazard is moot)."""
+    lowering for SC003/the donation fingerprint even when the caller's
+    environment carries the =0 kill switch)."""
     import sheeprl_tpu.algos  # noqa: F401 — fire @register_algorithm decorators
     from sheeprl_tpu.utils.registry import tasks
 

@@ -7,14 +7,13 @@ persistent cache through here at import time, before jax config must be
 touched); every jax import inside is lazy.
 """
 
-from .cache import MIN_COMPILE_SECS, CacheStats, arm_compile_cache, default_cache_dir
+from .cache import MIN_COMPILE_SECS, CacheStats, arm_compile_cache, cache_dir
 from .decisions import (
     Decision,
     decide,
     decide_remat,
     decision_key,
     measured_probe,
-    migrate_legacy_scan_unroll,
     remat_enabled,
     remat_mode,
 )
@@ -43,17 +42,16 @@ __all__ = [
     "WarmJit",
     "arm_compile_cache",
     "avals_of",
+    "cache_dir",
     "chunk_for_budget",
     "compiled_memory_stats",
     "decide",
     "decide_batch_chunk",
     "decide_remat",
     "decision_key",
-    "default_cache_dir",
     "ledger_entry",
     "lowered_op_counts",
     "measured_probe",
-    "migrate_legacy_scan_unroll",
     "predicted_cpu_compile_seconds",
     "remat_enabled",
     "remat_mode",

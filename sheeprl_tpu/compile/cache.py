@@ -1,34 +1,29 @@
-"""The ONE persistent-compilation-cache arming path (ISSUE 5 satellite).
+"""The ONE persistent-compilation-cache arming path.
 
-Before this module there were two competing cache-arming sites with two
-different thresholds: `sheeprl_tpu/__init__._enable_compilation_cache`
-(min_compile_time 0.5 s, armed at import) and
-`parallel/mesh.distributed_setup` (re-armed with 10.0 s when
-SHEEPRL_TPU_COMPILE_CACHE was set — so after distributed setup every
-executable compiling in 0.5-10 s silently stopped being cached, exactly the
-mid-cost policy/eval jits the warm-start subsystem wants to find on disk).
-`bench.py` carried a third copy of the 10 s arm. All three now call
-:func:`arm_compile_cache`; the single threshold lives in
-:data:`MIN_COMPILE_SECS`.
+:func:`arm_compile_cache` (called once, at package import) points jax's
+persistent compilation cache at :func:`cache_dir` with the single
+compile-time floor :data:`MIN_COMPILE_SECS`. Nothing else in the repo sets
+`jax_compilation_cache_dir`.
 
-Directory resolution order (first hit wins):
+Where the cache lives:
 
-  1. the explicit ``path`` argument;
-  2. ``SHEEPRL_TPU_COMPILE_CACHE`` (the runner/bench shared location);
-  3. ``JAX_COMPILATION_CACHE_DIR`` (jax's own env var);
-  4. a per-user tmpdir default (``<tmpdir>/sheeprl_tpu_xla_cache_<uid>`` —
-     a fixed name in world-writable /tmp invites permission collisions and
-     cache poisoning, since entries are deserialized executables).
+  1. ``JAX_COMPILATION_CACHE_DIR`` when set — the location is a deployment
+     setting, placed from outside;
+  2. otherwise ``<checkout>/logs/jax_compile_cache``, an absolute path
+     derived from this file — the same from every cwd and every process of
+     the checkout, so a second run finds what the first one wrote (never a
+     tmpdir, uid, pid or time).
 
-``SHEEPRL_TPU_XLA_CACHE=0`` disables the cache entirely (arm_compile_cache
-returns None and touches nothing).
+The unified decision store (``decisions.json``, compile/decisions.py) lives
+in the same directory. ``SHEEPRL_TPU_XLA_CACHE=0`` disables the cache
+entirely (arm_compile_cache returns None and touches nothing).
 
-Cache hit/miss observability rides jax.monitoring: jax 0.4.x records
-``/jax/compilation_cache/cache_hits`` / ``cache_misses`` events per backend
-compile, and :class:`CacheStats` counts them with the same
-attach/detach-listener pattern as telemetry's CompileTracker (jax's listener
-registry is append-only, so ONE module-level listener forwards to attached
-instances).
+Cache hit/miss observability rides jax.monitoring: jax records
+``/jax/compilation_cache/cache_hits`` per executable loaded from the cache
+and ``cache_misses`` per executable written to it, and :class:`CacheStats`
+counts them with the same attach/detach-listener pattern as telemetry's
+CompileTracker (jax's listener registry is append-only, so ONE module-level
+listener forwards to attached instances).
 """
 
 from __future__ import annotations
@@ -36,61 +31,45 @@ from __future__ import annotations
 import os
 import threading
 
-__all__ = ["MIN_COMPILE_SECS", "arm_compile_cache", "default_cache_dir", "CacheStats"]
+__all__ = ["MIN_COMPILE_SECS", "arm_compile_cache", "cache_dir", "CacheStats"]
 
 # The single compile-time floor below which executables are not persisted:
 # sub-half-second compiles recompile faster than a cache round-trip and would
-# bloat the cache. Everything at or above it — including the 0.5-10 s
-# mid-cost executables the old distributed_setup arm silently dropped — is
-# cached.
+# bloat the cache.
 MIN_COMPILE_SECS = 0.5
 
-
-def default_cache_dir() -> str:
-    import tempfile
-
-    uid = getattr(os, "getuid", lambda: "u")()
-    return os.path.join(tempfile.gettempdir(), f"sheeprl_tpu_xla_cache_{uid}")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def arm_compile_cache(
-    path: str | None = None,
-    *,
-    min_compile_secs: float | None = None,
-    export_env: bool = True,
-) -> str | None:
-    """Point jax's persistent compilation cache at one directory with one
-    threshold. Returns the armed path, or None when the cache is disabled
-    (``SHEEPRL_TPU_XLA_CACHE=0``) or jax is unavailable. Safe to call
-    repeatedly (idempotent re-arm with identical config).
+def cache_dir() -> str:
+    """The directory of the compile cache and the decision store."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _CHECKOUT, "logs", "jax_compile_cache"
+    )
 
-    ``export_env=True`` (default) also exports ``JAX_COMPILATION_CACHE_DIR``
-    so subprocesses (benches, spawned env workers, CLI runs under test)
-    share the same cache instead of creating their own.
+
+def arm_compile_cache(*, min_compile_secs: float | None = None) -> str | None:
+    """Point jax's persistent compilation cache at :func:`cache_dir` with
+    one threshold. Returns the armed path, or None when the cache is
+    disabled (``SHEEPRL_TPU_XLA_CACHE=0``) or jax is not installed (the
+    pure-AST lint lane imports the package on bare CPython). A jax that
+    refuses the configuration raises. Idempotent.
 
     ``min_compile_secs`` overrides :data:`MIN_COMPILE_SECS` — tests use 0.0
     to cache tiny graphs; production callers should not pass it.
     """
     if os.environ.get("SHEEPRL_TPU_XLA_CACHE", "1") == "0":
         return None
-    path = (
-        path
-        or os.environ.get("SHEEPRL_TPU_COMPILE_CACHE")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or default_cache_dir()
-    )
-    floor = MIN_COMPILE_SECS if min_compile_secs is None else min_compile_secs
     try:
         import jax
-
-        jax.config.update("jax_compilation_cache_dir", path)
-        # no size floor; the compile-time floor is the only gate
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
-        if export_env:
-            os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-    except Exception:
-        return None  # never block import/setup on cache wiring
+    except ImportError:
+        return None
+    path = cache_dir()
+    floor = MIN_COMPILE_SECS if min_compile_secs is None else min_compile_secs
+    jax.config.update("jax_compilation_cache_dir", path)
+    # no size floor; the compile-time floor is the only gate
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
     return path
 
 
@@ -103,7 +82,7 @@ _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 _lock = threading.Lock()
 _stats: set["CacheStats"] = set()
-_installed: bool | None = None
+_installed = False
 
 
 def _on_event(name: str, **kw) -> None:
@@ -117,31 +96,26 @@ def _on_event(name: str, **kw) -> None:
                 s._misses += 1
 
 
-def _install_listener() -> bool:
+def _install_listener() -> None:
     global _installed
-    if _installed is not None:
-        return _installed
-    try:
+    if not _installed:
         import jax.monitoring
 
         jax.monitoring.register_event_listener(_on_event)
         _installed = True
-    except Exception:
-        _installed = False
-    return _installed
 
 
 class CacheStats:
     """Counts persistent-cache hits and misses seen while attached."""
 
     def __init__(self) -> None:
-        self.supported = _install_listener()
+        _install_listener()
         self._hits = 0
         self._misses = 0
         self._attached = False
 
     def attach(self) -> "CacheStats":
-        if self.supported and not self._attached:
+        if not self._attached:
             with _lock:
                 _stats.add(self)
             self._attached = True

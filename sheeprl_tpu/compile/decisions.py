@@ -19,7 +19,8 @@ into the one shape they all share:
            `bytes` (smallest peak), or bytes-at-<=X%-time-cost (smallest
            peak among candidates within the time budget),
         -> persisted in ONE decision cache next to the compile cache
-           (`decisions.json`, keyed family|name|avals|jax version|backend),
+           (`decisions.json`, keyed family|name|avals|jax version|backend|
+           device kind),
            so a re-run with the same key skips every trial compile exactly
            like a warm compile cache skips the compile.
 
@@ -50,14 +51,12 @@ __all__ = [
     "decision_key",
     "load_cache",
     "measured_probe",
-    "migrate_legacy_scan_unroll",
     "remat_enabled",
     "remat_mode",
     "remat_time_cost_frac",
 ]
 
 CACHE_BASENAME = "decisions.json"
-LEGACY_SCAN_UNROLL_BASENAME = "scan_unroll.json"
 
 # The auto-remat acceptance gate: remat wins only when it reduces peak
 # bytes AND costs at most this fraction of the baseline's exec time.
@@ -106,20 +105,11 @@ def remat_enabled(value: Any) -> bool:
 
 
 def cache_path(explicit: str | None = None) -> str:
-    """The unified decision store lives next to the persistent compile
-    cache — same resolution order as compile/cache.py, without arming
-    anything."""
-    if explicit:
-        return explicit
-    base = (
-        os.environ.get("SHEEPRL_TPU_COMPILE_CACHE")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    )
-    if not base:
-        from .cache import default_cache_dir
+    """The unified decision store lives in the compile-cache directory
+    (compile/cache.py:cache_dir)."""
+    from .cache import cache_dir
 
-        base = default_cache_dir()
-    return os.path.join(base, CACHE_BASENAME)
+    return explicit or os.path.join(cache_dir(), CACHE_BASENAME)
 
 
 def load_cache(path: str) -> dict:
@@ -153,69 +143,16 @@ def _avals_tag(example: Sequence[Any]) -> str:
 
 def decision_key(family: str, name: str, example: Sequence[Any]) -> str:
     """The cache key: knob family + probe name + exact avals + jax version
-    + backend. Any drift in any component is a miss — a decision measured
-    on other shapes, another toolchain, or another chip never leaks."""
+    + backend + device kind. Any drift in any component is a miss — a
+    decision measured on other shapes, another toolchain, or another chip
+    generation (a "TPU v5 lite" winner on a v4) never leaks."""
     import jax
 
     return (
         f"{family}|{name}|{_avals_tag(example)}"
         f"|jax{jax.__version__}|{jax.default_backend()}"
+        f"|{jax.devices()[0].device_kind}"
     )
-
-
-def migrate_legacy_scan_unroll(
-    store_path: str, legacy_path: str | None = None
-) -> int:
-    """One-shot migration of a pre-ISSUE-11 `scan_unroll.json` winner store
-    into the unified decision cache: every legacy entry (key schema
-    `name|avals|jaxX|backend`) is rewritten under the new schema
-    (`scan_unroll|` prefix) as a full Decision record, the legacy file is
-    removed, and the count of migrated entries returned. Entries already
-    present in the unified cache win (they may be fresher). No-op (0) when
-    no legacy file exists or the store path IS the legacy name."""
-    if os.path.basename(store_path) == LEGACY_SCAN_UNROLL_BASENAME:
-        return 0  # an explicit store at the legacy name is not a legacy store
-    if legacy_path is None:
-        legacy_path = os.path.join(
-            os.path.dirname(store_path) or ".", LEGACY_SCAN_UNROLL_BASENAME
-        )
-    legacy = load_cache(legacy_path)
-    if not legacy:
-        return 0
-    store = load_cache(store_path)
-    migrated = 0
-    for old_key, rec in legacy.items():
-        new_key = f"scan_unroll|{old_key}"
-        if new_key in store or not isinstance(rec, dict) or "winner" not in rec:
-            continue
-        candidates = {}
-        for rung, secs in rec.get("timings_s", {}).items():
-            candidates[str(rung)] = {
-                "exec_seconds": float(secs),
-                "compile_seconds": float(rec.get("compile_s", {}).get(rung, 0.0)),
-                "bit_exact": bool(rec.get("bit_exact", {}).get(rung, True)),
-                "peak_bytes": None,
-                "temp_bytes": None,
-            }
-        store[new_key] = Decision(
-            family="scan_unroll",
-            name=str(rec.get("probe") or rec.get("name") or ""),
-            winner=str(rec["winner"]),
-            baseline="1",
-            objective="seconds",
-            candidates=candidates,
-            accepted=str(rec["winner"]) != "1",
-            source="cache",
-            key=new_key,
-        ).as_dict()
-        migrated += 1
-    if migrated:
-        _save_cache(store_path, store)
-    try:
-        os.remove(legacy_path)
-    except OSError:
-        pass
-    return migrated
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +248,19 @@ class Decision:
             div = self.candidate(self.winner).get("divergence")
             if div is not None:
                 out["divergence"] = div
+        # a candidate that RAISED (compile or run) loses the ladder by
+        # design, but never silently: the event names it and its message
+        errors = self.errors()
+        if errors:
+            out["errors"] = errors
         return out
+
+    def errors(self) -> dict[str, str]:
+        """label -> message of every candidate whose build/compile/run
+        raised (as opposed to losing on time, bytes or the receipt)."""
+        return {
+            lbl: rep["error"] for lbl, rep in self.candidates.items() if rep.get("error")
+        }
 
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "Decision":
@@ -470,8 +419,11 @@ def decide(
                     t0 = time.perf_counter()
                     out = jax.block_until_ready(compiled(*example))
                     samples.append(time.perf_counter() - t0)
-        except Exception as err:  # a broken candidate loses, never aborts
-            report.error = f"{type(err).__name__}: {err}"[:200]
+        except Exception as err:
+            # a broken candidate loses the ladder instead of aborting the
+            # run; `Decision.errors()` / the event's `errors` keep it visible
+            # and callers for whom it is fatal (serve --quant) raise on it
+            report.error = f"{type(err).__name__}: {err}"[:500]
             continue
         samples.sort()
         report.exec_seconds = samples[len(samples) // 2]

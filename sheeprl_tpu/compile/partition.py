@@ -34,7 +34,8 @@ first call of the full recon-loss gradient 182 s; DECODER-only gradient
 212 s; encoder-only gradient 3.1 s; forward-only 1.4 s; full grad at
 cnn_channels_multiplier 4 instead of 16: 6.2 s. Separating the phases with
 the AOT path (`lower().compile()` vs a timed call of the Compiled) then
-showed that on THIS toolchain (jaxlib 0.4.36 XLA:CPU) the conv-grad
+showed that on the toolchain of that sweep (jaxlib 0.4.36 XLA:CPU; not
+re-measured on the installed 0.9.0 — ROADMAP D5) the conv-grad
 *compile* is flat in batch (1.5-2.7 s at batch 2 through 32) and the
 scaling cost is EXECUTION of the transposed-conv gradient kernels
 (~40 s/image at multiplier 16, superlinear in channels ~(C1/C0)^2.4) —
@@ -87,7 +88,8 @@ __all__ = [
 ]
 
 # The compile-time predictor that GUARDS the trial compile. On the measured
-# toolchain (jaxlib 0.4.36) conv-grad compile is flat in batch (~0.1 s per
+# toolchain (jaxlib 0.4.36; not re-measured on the installed 0.9.0 — ROADMAP
+# D5) conv-grad compile is flat in batch (~0.1 s per
 # convolution, 2.3 s for the 23-conv recon at any batch), so this linear
 # model is a deliberate over-estimate: it only blocks the trial compile on
 # a toolchain whose conv-grad compile really is superlinear (the r4 dev-host

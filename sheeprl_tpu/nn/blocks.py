@@ -168,7 +168,7 @@ class CNN(Module):
         act = activation(self.act)
         for i, layer in enumerate(self.layers):
             norm = self.norms[i]
-            if (
+            block_ok = (
                 norm is not None
                 and norm.scale is not None
                 and layer.bias is None
@@ -178,9 +178,10 @@ class CNN(Module):
                 # toggle would change output shapes
                 and x.shape[-3] % 2 == 0
                 and x.shape[-2] % 2 == 0
-                and pallas_cnn.cnn_stage_supported(
-                    layer.kernel.shape, layer.stride, layer.padding, True, self.act
-                )
+            )
+            if pallas_cnn.cnn_stage_supported(
+                layer.kernel.shape, layer.stride, layer.padding, block_ok, self.act,
+                x, layer.kernel,
             ):
                 # fused Dreamer miniblock: conv + LayerNorm + SiLU in one
                 # Pallas kernel (ops/pallas_cnn.py)
@@ -259,14 +260,15 @@ class DeCNN(Module):
         last = len(self.layers) - 1
         for i, layer in enumerate(self.layers):
             norm = self.norms[i]
-            if (
+            block_ok = (
                 norm is not None
                 and norm.scale is not None
                 and layer.bias is None
                 and (i != last or self.act_last)
-                and pallas_cnn.cnn_stage_supported(
-                    layer.kernel.shape, layer.stride, layer.padding, True, self.act
-                )
+            )
+            if pallas_cnn.cnn_stage_supported(
+                layer.kernel.shape, layer.stride, layer.padding, block_ok, self.act,
+                x, layer.kernel,
             ):
                 # fused subpixel-deconv + LayerNorm + SiLU Pallas stage
                 x = pallas_cnn.deconv_ln_silu(

@@ -70,15 +70,15 @@ class LayerNormGRUCell(Module):
         return cls(proj=proj, norm=norm, hidden_size=hidden_size)
 
     def __call__(self, x: jax.Array, h: jax.Array) -> jax.Array:
-        from ..ops.pallas_kernels import layernorm_gru_cell, use_pallas
+        from ..ops.pallas_kernels import layernorm_gru_cell, select, use_pallas
 
-        if (
-            use_pallas("gru")
-            and self.norm is not None
+        fits = (
+            self.norm is not None
             and self.norm.scale is not None
             and self.proj.bias is None
             and x.ndim == 2
-        ):
+        )
+        if use_pallas("gru", x, h, self.proj.weight) and select("gru", fits):
             return layernorm_gru_cell(
                 x,
                 h,

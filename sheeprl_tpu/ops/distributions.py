@@ -444,9 +444,9 @@ class TwoHotEncodingDistribution(Distribution):
 
     def log_prob(self, x):
         # x: [..., 1] raw-scale targets
-        from .pallas_kernels import two_hot_log_prob, use_pallas
+        from .pallas_kernels import select, two_hot_log_prob, use_pallas
 
-        if use_pallas("two_hot"):
+        if use_pallas("two_hot", self.logits, x) and select("two_hot", True):
             k = self.logits.shape[-1]
             lp = two_hot_log_prob(
                 symlog(x).reshape(-1, 1).astype(jnp.float32),

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pallas_kernels import _VMEM, _cdiv, _interpret_mode, use_pallas
+from .pallas_kernels import _VMEM, _cdiv, _interpret_mode, select, use_pallas
 
 __all__ = ["conv_ln_silu", "deconv_ln_silu", "cnn_stage_supported"]
 
@@ -70,17 +70,22 @@ def _pick_blk(rows: int, row_bytes: int) -> int:
     return max(8 * (blk // 8), min(rows, 8))
 
 
-def cnn_stage_supported(kernel_shape, stride, padding, has_norm, act) -> bool:
+def cnn_stage_supported(kernel_shape, stride, padding, block_ok, act, *operands) -> bool:
     """Eligibility for the fused stage: the Dreamer k4/s2/SAME LayerNorm-SiLU
-    miniblock exactly (callers fall back to plain XLA otherwise)."""
-    return (
-        use_pallas("cnn")
+    miniblock exactly (callers take plain XLA otherwise). `block_ok` carries
+    the caller's own conditions (affine LayerNorm, bias-free conv, shapes the
+    kernel reproduces); `operands` are the stage's input and kernel (see
+    `use_pallas`). Records the decision (`kernel.select`, family cnn)."""
+    if not use_pallas("cnn", *operands):
+        return False
+    fits = (
+        bool(block_ok)
         and tuple(kernel_shape[:2]) == (4, 4)
         and tuple(stride) == (2, 2)
         and padding == "SAME"
-        and has_norm
         and act == "silu"
     )
+    return select("cnn", fits)
 
 
 def _silu(z):

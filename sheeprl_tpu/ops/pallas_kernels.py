@@ -16,13 +16,16 @@ as ONE kernel, `fused_rssm_step` below. Each kernel here
   - differentiates: forward runs the kernel, backward is an analytic VJP
     (two-hot, symlog) or a recompute-in-XLA VJP (GRU) so training numerics
     stay exact;
-  - degrades gracefully: `use_pallas()` gates on the backend, the
+  - is gated: `use_pallas()` is on when the default backend is a TPU, the
     SHEEPRL_TPU_PALLAS env var forces on/off, and interpret mode runs the
-    same kernels on CPU for numerics tests.
+    same kernels on CPU for numerics tests (`set_pallas(True,
+    interpret=True)`; tracing a kernel that way on a TPU backend raises).
 
 Callers (nn.recurrent.LayerNormGRUCell, ops.distributions.TwoHotEncoding-
-Distribution) fall back to their plain-XLA paths whenever the kernels are
-disabled or the shapes are unsupported, so behavior is identical either way.
+Distribution, ...) take their plain-XLA paths whenever the kernels are
+disabled or the shapes are unsupported, so behavior is identical either way —
+and every such dispatch decision lands in telemetry as one `kernel.select`
+event per trace (:func:`select`), so a run's record says which program ran.
 """
 
 from __future__ import annotations
@@ -34,17 +37,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from ..telemetry.core import emit as _telemetry_emit
+
+_VMEM = pltpu.VMEM
 
 __all__ = [
     "use_pallas",
     "set_pallas",
+    "select",
     "layernorm_gru_cell",
     "fused_rssm_step",
     "rssm_step_reference",
@@ -67,18 +69,38 @@ def set_pallas(enabled: bool | None, interpret: bool = False) -> None:
     _FORCED, _INTERPRET = enabled, interpret
 
 
+@functools.cache
+def _backend_is_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _interpret_mode() -> bool:
-    """Read the current interpret flag at trace time (pallas_cnn and other
-    kernel modules must see flips made after their import)."""
+    """The interpret flag every `pallas_call` here passes, read at trace
+    time (flips made after import must be seen). On a TPU backend a kernel
+    traced under the interpreter is an error, not a slow path."""
+    if _INTERPRET and _backend_is_tpu():
+        raise RuntimeError(
+            "a Pallas kernel is being traced with interpret=True on a TPU "
+            "backend; interpret mode is for CPU numerics tests only "
+            "(set_pallas(..., interpret=False))"
+        )
     return _INTERPRET
 
 
-@functools.cache
-def _backend_is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def select(family: str, selected: bool, reason: str | None = None, **detail) -> bool:
+    """Record one trace-time dispatch decision of a kernel family as a
+    `kernel.select` telemetry event (no-op without a live Telemetry) and
+    return `selected`. `reason` is "ok" when the kernel runs, else why the
+    XLA twin does: "disabled" (gate off), "partitioned" (the jit spans
+    several devices, see :func:`use_pallas`), "structure" (module/shape
+    outside the kernel's contract — the default for a refusal) or "vmem"
+    (with `bytes` vs `budget`)."""
+    _telemetry_emit(
+        "kernel.select", family=family, selected=bool(selected),
+        reason=reason or ("ok" if selected else "structure"),
+        interpret=_INTERPRET, **detail,
+    )
+    return bool(selected)
 
 
 def _env_flag(name: str) -> bool | None:
@@ -90,21 +112,46 @@ def _env_flag(name: str) -> bool | None:
     return None
 
 
-def use_pallas(kind: str | None = None) -> bool:
+def use_pallas(kind: str | None = None, *operands) -> bool:
     """Master gate, optionally refined per kernel family via
     SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SYMLOG|CNN|
     SAC_TRUNK) — the bench uses the per-kernel switches to attribute
-    wins/losses and keep only winners."""
+    wins/losses and keep only winners.
+
+    With `kind` (a dispatch site asking for its family) a refusal is
+    recorded (:func:`select`): "disabled", or "partitioned" when any leaf
+    of `operands` — the kernel's inputs AND weights: a policy step's pixels
+    come untyped from the host while its replicated params span the mesh —
+    is typed with a mesh of more than one device. jax carries the mesh in
+    the aval of everything computed from a mesh-placed jit input, and
+    Mosaic refuses such a call: "NotImplementedError:
+    Mosaic kernels cannot be automatically partitioned. Please wrap the
+    call in a shard_map." (jax 0.9.0; `dreamer_v3 --num_devices 4` on the
+    v5e 2x2 host, PR 21). Until the kernels are wrapped in shard_map
+    (ROADMAP S4), a jit partitioned over several devices takes the XLA
+    twins; the interpreter lowers to plain XLA ops and is not affected."""
     if _FORCED is not None:
         enabled = _FORCED
     else:
         master = _env_flag("SHEEPRL_TPU_PALLAS")
         enabled = _backend_is_tpu() if master is None else master
-    if enabled and kind is not None:
+    if kind is None:
+        return enabled
+    if enabled:
         per_kind = _env_flag(f"SHEEPRL_TPU_PALLAS_{kind.upper()}")
         if per_kind is not None:
-            return per_kind
-    return enabled
+            enabled = per_kind
+    if not enabled:
+        return select(kind, False, "disabled")
+    if not _INTERPRET:
+        devices = max(
+            (jax.typeof(leaf).sharding.mesh.size
+             for leaf in jax.tree_util.tree_leaves(operands)),
+            default=0,
+        )
+        if devices > 1:
+            return select(kind, False, "partitioned", devices=devices)
+    return True
 
 
 def _block_all(shape_dtypes):
@@ -190,7 +237,7 @@ def _gru_forward_with_residuals(x, h, w, scale, offset, eps):
             pl.BlockSpec((bn, 3 * hidden), lambda i: (i, 0), memory_space=_VMEM),
             pl.BlockSpec((bn, 1), lambda i: (i, 0), memory_space=_VMEM),
         ),
-        interpret=_INTERPRET,
+        interpret=_interpret_mode(),
     )(x, h, w, scale, offset)
 
 
@@ -233,7 +280,7 @@ def _gru_forward(x, h, w, scale, offset, eps):
             pl.BlockSpec(offset.shape, lambda i: (0,), memory_space=_VMEM),
         ],
         out_specs=pl.BlockSpec((bn, hidden), lambda i: (i, 0), memory_space=_VMEM),
-        interpret=_INTERPRET,
+        interpret=_interpret_mode(),
     )(x, h, w, scale, offset)
 
 
@@ -461,7 +508,7 @@ def _fused_rssm_forward(
             pl.BlockSpec((bn, sd), lambda i: (i, 0), memory_space=_VMEM),
             pl.BlockSpec((bn, sd), lambda i: (i, 0), memory_space=_VMEM),
         ),
-        interpret=_INTERPRET,
+        interpret=_interpret_mode(),
     )(
         x, h, emb, wm, sm, om, wg, sg, og,
         wt1, st1, ot1, wt2, bt2, wr1, sr1, or1, wr2, br2,
@@ -520,14 +567,24 @@ def _fused_rssm_bwd(act, eps, residuals, g):
 fused_rssm_step.defvjp(_fused_rssm_fwd, _fused_rssm_bwd)
 
 
+def _fits_vmem(family: str, weights) -> bool:
+    """The whole-weights-in-VMEM guard of the fused kernels; records the
+    verdict (selected / refused with bytes vs budget)."""
+    total = sum(int(w.size) * w.dtype.itemsize for w in weights)
+    ok = total <= _FUSED_VMEM_BUDGET_BYTES
+    return select(
+        family, ok, None if ok else "vmem",
+        bytes=total, budget=_FUSED_VMEM_BUDGET_BYTES,
+    )
+
+
 def fused_rssm_supported(act: str, *weights) -> bool:
     """Trace-time dispatch guard shared with the RSSM module: the activation
     must have an in-kernel implementation and the step's weights must
     co-reside in VMEM with room for the row blocks."""
     if act not in _KERNEL_ACTS:
-        return False
-    total = sum(int(w.size) * w.dtype.itemsize for w in weights)
-    return total <= _FUSED_VMEM_BUDGET_BYTES
+        return select("rssm", False, detail=f"activation {act!r}")
+    return _fits_vmem("rssm", weights)
 
 
 # =============================================================================
@@ -623,7 +680,7 @@ def fused_int8_trunk(x, s0, w0, ws0, b0, s1, w1, ws1, b1, sm, wm, wsm, bm):
         out_specs=pl.BlockSpec(
             (bn, out_dim), lambda i: (i, 0), memory_space=_VMEM
         ),
-        interpret=_INTERPRET,
+        interpret=_interpret_mode(),
     )(x, s0, w0, ws0, b0, s1, w1, ws1, b1, sm, wm, wsm, bm)
 
 
@@ -631,8 +688,7 @@ def fused_int8_trunk_supported(*weights) -> bool:
     """Trace-time dispatch guard (the fused_rssm_supported pattern): the
     trunk's quantized weights + scales + biases must co-reside in VMEM
     with room for the row blocks."""
-    total = sum(int(w.size) * w.dtype.itemsize for w in weights)
-    return total <= _FUSED_VMEM_BUDGET_BYTES
+    return _fits_vmem("sac_trunk", weights)
 
 
 # =============================================================================
@@ -691,7 +747,7 @@ def _two_hot_forward(x, logits, bins):
             pl.BlockSpec((1, k), lambda i: (0, 0), memory_space=_VMEM),
         ],
         out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0), memory_space=_VMEM),
-        interpret=_INTERPRET,
+        interpret=_interpret_mode(),
     )(x, logits, bins)
 
 
@@ -742,7 +798,7 @@ def _elementwise(kernel, x):
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=_VMEM)],
         out_specs=pl.BlockSpec(memory_space=_VMEM),
-        interpret=_INTERPRET,
+        interpret=_interpret_mode(),
     )(x)
 
 

@@ -19,8 +19,7 @@ Since ISSUE 11 the ladder itself — per-rung AOT `lower().compile()`,
 exec timing, BIT-EXACTNESS receipts vs rung 1, winner persistence — is
 the unified measured-decision framework (`compile/decisions.py`, knob
 family `scan_unroll`): winners live in the ONE decision cache next to the
-compile cache (`decisions.json`) instead of the pre-ISSUE-11 private
-`scan_unroll.json`, whose entries are one-shot migrated on first use.
+compile cache (`decisions.json`).
 `UnrollDecision` remains this module's typed view of the decision.
 """
 
@@ -190,12 +189,11 @@ def autotune_unroll(
     bit-exactness receipt vs rung 1 (a non-bit-exact rung is disqualified);
     the winner is the fastest surviving rung, ties breaking toward the
     SMALLER rung (less code), and persists in the shared decision cache —
-    a re-run with the same (name, avals, jax version, backend) key skips
-    the whole ladder."""
+    a re-run with the same (name, avals, jax version, backend, device kind)
+    key skips the whole ladder."""
     from ..compile import decisions as dec
 
     path = dec.cache_path(store_path)
-    dec.migrate_legacy_scan_unroll(path)
     ladder = list(dict.fromkeys(int(r) for r in rungs))
     if 1 not in ladder:
         ladder.insert(0, 1)

@@ -72,23 +72,7 @@ def distributed_setup(
     process_id: Optional[int] = None,
 ) -> None:
     """Initialize multi-host JAX (one call per host process). No-ops when
-    single-host or when the TPU pod runtime auto-configures itself.
-
-    Also the framework's hook for the persistent compilation cache: when
-    SHEEPRL_TPU_COMPILE_CACHE names a directory, compiled executables are
-    cached across processes/sessions. This is how the CPU receipt runners
-    amortize the XLA:CPU conv-gradient compile pathology (the SAC-AE
-    reconstruction jit alone costs ~16 min at pixel sizes — once), and it
-    makes resumed TPU bench sessions rebuild closures nearly for free.
-    Arming goes through the repo's ONE helper (`compile/cache.py`) — this
-    call previously re-armed with a private 10 s compile-time floor, so
-    after distributed setup every 0.5-10 s executable silently stopped
-    being cached (ISSUE 5 satellite)."""
-    cache_dir = os.environ.get("SHEEPRL_TPU_COMPILE_CACHE")
-    if cache_dir:
-        from ..compile.cache import arm_compile_cache
-
-        arm_compile_cache(cache_dir)
+    single-host or when the TPU pod runtime auto-configures itself."""
     if num_processes is not None and num_processes > 1:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,

@@ -8,19 +8,23 @@ its predicted peak bytes fit the serving memory budget.
 
 The decision ladder mirrors `compile/partition.decide_batch_chunk`:
 
-  0. ledger-first — the committed sheepmem ledger carries measured
-     argument/peak bytes for every `<spec>/policy_b<rung>` serving jit
-     (the `@serve` capture variants, ISSUE 15 satellite); the live
-     footprint is predicted by scaling with the argument-byte ratio, zero
-     lowering, zero trial compile;
-  1. no ledger entry — trial-AOT-compile the rung once and read XLA's own
-     `memory_analysis()`; the measurement is memoized in the unified
-     decision cache (compile/decisions.py, family `serve_ladder`), so a
-     restarted server never re-probes.
+  0. ledger-first, CPU backend only — the committed sheepmem ledger carries
+     argument/peak bytes captured on XLA:CPU for every
+     `<spec>/policy_b<rung>` serving jit (the `@serve` capture variants,
+     ISSUE 15 satellite); the live footprint is predicted by scaling with
+     the argument-byte ratio, zero lowering, zero trial compile. A ledger
+     captured on the CPU never steers a run on an accelerator;
+  1. otherwise — trial-AOT-compile the rung once on the live backend and
+     read XLA's own `memory_analysis()`; the measurement is memoized in
+     the unified decision cache (compile/decisions.py, family
+     `serve_ladder`), so a restarted server never re-probes. A rung whose
+     trial compile fails raises: it could not serve either.
 
-Rung 1 is always kept (a server that can serve nothing is not a server —
-if even batch 1 exceeds the budget the operator must shrink the model,
-not the ladder).
+The budget is what the live device reports free (`memory_stats()`), or
+the partition heuristic's host budget where the backend reports none
+(XLA:CPU). Rung 1 is always kept (a server that can serve nothing is not a
+server — if even batch 1 exceeds the budget the operator must shrink the
+model, not the ladder).
 """
 
 from __future__ import annotations
@@ -92,11 +96,18 @@ def ledger_spec(algo: str) -> str:
 
 
 def serve_mem_budget_bytes() -> int:
-    """Peak-bytes budget per serving executable. Defaults to the partition
-    heuristic's CPU budget; SHEEPRL_TPU_SERVE_MEM_MB overrides."""
+    """Peak-bytes budget per serving executable: SHEEPRL_TPU_SERVE_MEM_MB
+    when set, else the memory the live device reports free, else (a backend
+    without `memory_stats()`, i.e. XLA:CPU) the partition heuristic's host
+    budget."""
     mb = os.environ.get("SHEEPRL_TPU_SERVE_MEM_MB")
     if mb:
         return int(float(mb) * 2**20)
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
     return partition_mem_budget_bytes()
 
 
@@ -104,7 +115,7 @@ def serve_mem_budget_bytes() -> int:
 class RungDecision:
     rung: int
     accepted: bool
-    source: str  # 'ledger' | 'probe' | 'floor' | 'error'
+    source: str  # 'ledger' | 'probe' | 'floor'
     peak_bytes: int
     reason: str
 
@@ -129,13 +140,6 @@ def size_ladder(
     for rung in rungs:
         example = example_of(rung)
         peak, source, note = _predict_peak(fn, example, spec, rung, store_path)
-        if peak is None:
-            # unmeasurable (lowering failed, no ledger): keep the rung —
-            # refusing to serve on a broken probe is worse than serving
-            decisions.append(
-                RungDecision(rung, True, "error", 0, f"unmeasured ({note}); kept")
-            )
-            continue
         if peak <= budget:
             decisions.append(
                 RungDecision(
@@ -166,10 +170,14 @@ def size_ladder(
 
 def _predict_peak(
     fn: Callable, example: tuple, spec: str, rung: int, store_path: str | None
-) -> tuple[int | None, str, str]:
-    """-> (predicted peak bytes | None, source, note)."""
+) -> tuple[int, str, str]:
+    """-> (predicted peak bytes, source, note)."""
+    import jax
+
     key = f"{spec}/policy_b{rung}"
-    mem = ledger_entry(key, "memory")
+    # the committed ledger is an XLA:CPU capture: it may predict for a CPU
+    # run only, an accelerator run measures on the live device
+    mem = ledger_entry(key, "memory") if jax.default_backend() == "cpu" else None
     if mem and mem.get("peak_bytes") and mem.get("argument_bytes"):
         try:
             live_args = _example_arg_bytes(example)
@@ -196,18 +204,13 @@ def _predict_peak(
     from ..compile.plan import avals_of
 
     def _measure() -> dict:
-        try:
-            exe = fn.lower(*avals_of(example)).compile()
-        except Exception as err:
-            return {"error": f"trial compile failed: {type(err).__name__}"}
+        exe = fn.lower(*avals_of(example)).compile()
         stats = compiled_memory_stats(exe) or {}
         return {"peak_bytes": int(stats.get("peak_bytes", 0))}
 
     record, src = dec.measured_probe(
         "serve_ladder", key, example, _measure, store_path=store_path
     )
-    if record.get("error"):
-        return None, "error", record["error"]
     tag = "probe cache" if src == "cache" else "probe"
     return int(record.get("peak_bytes", 0)), "probe", tag
 

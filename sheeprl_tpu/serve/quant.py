@@ -233,30 +233,35 @@ class QuantState:
                     return lambda *a: step_int8(_q, *a)
                 return lambda *a: step_f32(_p, *a)
 
-            try:
-                d = dec.decide(
-                    "serve_quant",
-                    # the bound is part of the name: a tight-bound re-run
-                    # must re-measure, never inherit a loose-bound winner
-                    f"policy_b{rung}@{self.bound:g}",
-                    ["f32", "int8"],
-                    build,
-                    example,
-                    objective="seconds",
-                    quality_metric=action_divergence,
-                    quality_bound=self.bound,
-                    store_path=self.store_path,
-                )
-            except Exception as err:
+            d = dec.decide(
+                "serve_quant",
+                # the bound is part of the name: a tight-bound re-run
+                # must re-measure, never inherit a loose-bound winner
+                f"policy_b{rung}@{self.bound:g}",
+                ["f32", "int8"],
+                build,
+                example,
+                objective="seconds",
+                quality_metric=action_divergence,
+                quality_bound=self.bound,
+                store_path=self.store_path,
+            )
+            rep = d.candidate("int8")
+            if rep.get("error"):
+                # losing on time or on the quality bound keeps the rung on
+                # f32 by design; an int8 program that cannot compile or run
+                # is a defect `--quant int8` must not paper over
                 self._event(
                     "serve.quant_rung", rung=rung, accepted=False,
-                    error=f"{type(err).__name__}: {err}"[:200],
+                    fused=self._fused, error=rep["error"],
                 )
-                continue
+                raise RuntimeError(
+                    f"--quant int8: the int8 candidate of rung {rung} failed "
+                    f"to compile or run (fused={self._fused}): {rep['error']}"
+                )
             self.decisions[rung] = d
             if d.winner == "int8":
                 won.add(rung)
-            rep = d.candidate("int8")
             self._event(
                 "serve.quant_rung", rung=rung, accepted=d.winner == "int8",
                 divergence=rep.get("divergence"), bound=self.bound,
@@ -302,23 +307,29 @@ def _sac_fused_ready(policy, actor) -> bool:
     """Structural guard for the fused kernel (the fused_rssm dispatch
     pattern): SAC, gate on, a 2-layer biased relu trunk with no norms and
     no MLP head, every trunk weight quantized, and the whole quantized
-    weight set within the kernel's VMEM budget."""
+    weight set within the kernel's VMEM budget. The decision is recorded
+    (`kernel.select`, family sac_trunk)."""
     from ..ops import pallas_kernels as pk
     from ..ops.quant import QuantLinear
 
-    if getattr(policy, "algo", None) != "sac" or not pk.use_pallas("sac_trunk"):
+    if getattr(policy, "algo", None) != "sac":
+        return False  # another policy family: the kernel is not a candidate
+    if not pk.use_pallas("sac_trunk"):
         return False
     model = getattr(actor, "model", None)
     fc_mean = getattr(actor, "fc_mean", None)
-    if model is None or fc_mean is None:
-        return False
-    if model.act != "relu" or model.head is not None:
-        return False
-    if len(model.layers) != 2 or any(n is not None for n in model.norms):
-        return False
-    parts = [*model.layers, fc_mean]
-    if not all(isinstance(p, QuantLinear) and p.bias is not None for p in parts):
-        return False
+    parts = [] if model is None else [*model.layers, fc_mean]
+    fits = (
+        model is not None
+        and fc_mean is not None
+        and model.act == "relu"
+        and model.head is None
+        and len(model.layers) == 2
+        and all(n is None for n in model.norms)
+        and all(isinstance(p, QuantLinear) and p.bias is not None for p in parts)
+    )
+    if not fits:
+        return pk.select("sac_trunk", False)
     weights = [a for p in parts for a in (p.w_q, p.w_scale, p.in_scale, p.bias)]
     return pk.fused_int8_trunk_supported(*weights)
 

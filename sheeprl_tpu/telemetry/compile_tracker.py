@@ -4,7 +4,7 @@ A mid-run recompile (a shape drifting, a weak_type flip, a python-scalar
 static arg changing) silently costs seconds to minutes on TPU and the only
 prior symptom was a dip in `Time/step_per_second`. `jax.monitoring` fires a
 duration event per backend compile (`/jax/core/compile/
-backend_compile_duration` on jax 0.4.x) plus tracing/lowering durations, so
+backend_compile_duration`) plus tracing/lowering durations, so
 counting those gives recompile count and total compile seconds with zero
 instrumentation of the jitted functions themselves.
 
@@ -26,7 +26,7 @@ import threading
 
 __all__ = ["CompileTracker", "monitoring_supported"]
 
-# event-name fragments that mark one backend compile / its phases (jax 0.4.x
+# event-name fragments that mark one backend compile / its phases (jax
 # emits /jax/core/compile/{jaxpr_trace,jaxpr_to_mlir_module,backend_compile}
 # _duration; the backend_compile one fires exactly once per XLA compile)
 _COMPILE_EVENT = "backend_compile_duration"

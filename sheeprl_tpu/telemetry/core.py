@@ -38,11 +38,14 @@ import time
 import traceback
 from typing import Any, Callable, Iterator
 
+from ..compile.cache import CacheStats
 from .compile_tracker import CompileTracker
 from .events import JsonlEventLog
 from .phase import PhaseTimers
 
-__all__ = ["Telemetry", "emit", "active_telemetry", "device_memory_gauges"]
+__all__ = [
+    "Telemetry", "emit", "active_telemetry", "device_memory_gauges", "device_report",
+]
 
 # ---------------------------------------------------------------------------
 # Global emit: shared helpers that should not depend on a Telemetry handle
@@ -81,6 +84,33 @@ def _install_excepthook() -> None:
 
     sys.excepthook = hook
     _excepthook_installed = True
+
+
+def device_report() -> dict[str, Any]:
+    """What this process runs on, as jax reports it — opens the backend, and
+    raises if jax cannot (a run whose device is unknown must not start):
+    platform, device kind, local/global device count, the jax / jaxlib /
+    libtpu versions and the armed compile-cache directory."""
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+
+    dev = jax.devices()[0]
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    return {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "local_devices": jax.local_device_count(),
+        "global_devices": jax.device_count(),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+        "cache_dir": jax.config.jax_compilation_cache_dir,
+    }
 
 
 def device_memory_gauges() -> dict[str, float]:
@@ -140,6 +170,7 @@ class Telemetry:
         self._last_nan_warn = 0.0
         self._closed = not enabled
         self._compiles = CompileTracker()
+        self._cache = CacheStats()  # persistent compile-cache hits/misses
         self._tracer = None
         write_jsonl = enabled and rank == 0 and log_dir is not None
         filename = (
@@ -152,6 +183,7 @@ class Telemetry:
         )
         if enabled:
             self._compiles.attach()
+            self._cache.attach()
             _install_excepthook()
             atexit.register(self._atexit)
             _active.append(self)
@@ -173,7 +205,8 @@ class Telemetry:
     ) -> "Telemetry":
         """The mains' shared construction helper: always-on unless
         SHEEPRL_TPU_TELEMETRY=0, JSONL/heartbeat on process 0 only, and a
-        `start` lifecycle event carrying the run identity. Checkpoint and
+        `start` lifecycle event carrying the run identity and the device
+        report (`device_report()`: it opens the backend). Checkpoint and
         profile-window lifecycle events arrive via the module-level `emit`
         (save_checkpoint / StepProfiler publish them directly). `role`
         selects the sheepscope shard filename (actor{N}/serve) and stamps
@@ -196,13 +229,6 @@ class Telemetry:
             role=role, run_id=ensure_run_id() if enabled else None,
         )
         if enabled:
-            try:
-                import jax
-
-                backend = jax.default_backend()
-                n_local = len(jax.local_devices())
-            except Exception:
-                backend, n_local = "unknown", 0
             telem.event(
                 "start",
                 algo=algo,
@@ -210,8 +236,7 @@ class Telemetry:
                 seed=getattr(args, "seed", None),
                 num_envs=getattr(args, "num_envs", None),
                 precision=getattr(args, "precision", None),
-                backend=backend,
-                local_devices=n_local,
+                **device_report(),
                 rank=rank,
                 log_dir=log_dir,
                 role=telem.role,
@@ -270,6 +295,9 @@ class Telemetry:
             out["XLA/compile_seconds"] = comp["compile_seconds"]
             out["XLA/total_compiles"] = comp["total_compiles"]
             out["XLA/total_compile_seconds"] = comp["total_compile_seconds"]
+        cache = self._cache.snapshot()
+        out["XLA/cache_hits"] = cache["hits"]
+        out["XLA/cache_misses"] = cache["misses"]
         out.update(device_memory_gauges())
         gauge_errors = 0
         for source in self._gauge_sources:
@@ -374,7 +402,11 @@ class Telemetry:
         """Normal end-of-run teardown: flush open phases, emit `end`."""
         if self._closed:
             return
-        self.event("end", phases=self.timers.flush())
+        cache = self._cache.snapshot()
+        self.event(
+            "end", phases=self.timers.flush(),
+            cache_hits=cache["hits"], cache_misses=cache["misses"],
+        )
         try:
             atexit.unregister(self._atexit)
         # sheeplint: disable=SL012 — unregister during interpreter teardown;
@@ -386,6 +418,7 @@ class Telemetry:
     def _teardown(self) -> None:
         self._closed = True
         self._compiles.detach()
+        self._cache.detach()
         self._log.close()
         if self in _active:
             _active.remove(self)

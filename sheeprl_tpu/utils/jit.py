@@ -1,29 +1,8 @@
 """Donation-aware jit: `donating_jit` is `jax.jit` whose `donate_argnums`
-is applied only where donation is known-safe.
-
-Why this exists: on the CPU backend with the persistent compilation cache
-enabled, executing a DESERIALIZED cached executable that carries
-input-output aliasing (donation) intermittently corrupts the glibc heap —
-"corrupted double-linked list" aborts / segfaults inside the train step,
-reproduced deterministically-enough on jax 0.4.37/jaxlib 0.4.36 by warming
-the cache and rerunning any SAC-family test in a process with a heavy
-native import set (torch + scipy + tensorstore + grpc). Freshly compiled
-donating executables are fine; cache-off runs are fine; non-donating
-cached executables are fine. The missing ingredient is the aliasing
-metadata surviving serialization on XLA:CPU.
-
-Policy (overridable with SHEEPRL_TPU_DONATE=0/1):
-  - non-CPU backends: donate (HBM reuse is the whole point on TPU, and the
-    corruption has only been observed on deserialized CPU executables);
-  - CPU without a persistent cache dir: donate;
-  - CPU with the persistent cache (the tier-1 test configuration): DON'T —
-    host memory is plentiful there and a copy is cheaper than a crashed
-    suite.
-
-The replay-ring scatter jits in data/buffers.py keep raw `jax.jit`
-donation: their compiles are far below the cache's 0.5s compile-time floor
-so they never produce cached (deserializable) executables, and un-donating
-them would copy the whole HBM ring every env step.
+can be switched off process-wide with SHEEPRL_TPU_DONATE=0 (the debugging
+escape hatch for a suspected aliasing bug — HBM reuse is the whole point on
+TPU, so donation is on everywhere by default: tests and chip run the same
+program).
 """
 
 from __future__ import annotations
@@ -35,16 +14,7 @@ __all__ = ["donating_jit", "donation_safe"]
 
 
 def donation_safe() -> bool:
-    forced = os.environ.get("SHEEPRL_TPU_DONATE")
-    if forced == "0":
-        return False
-    if forced == "1":
-        return True
-    import jax
-
-    if jax.default_backend() != "cpu":
-        return True
-    return not bool(jax.config.jax_compilation_cache_dir)
+    return os.environ.get("SHEEPRL_TPU_DONATE") != "0"
 
 
 def donating_jit(fun: Callable | None = None, *, donate_argnums: Any = (), **kw):

@@ -58,7 +58,7 @@ def test_sc001_float64_leak():
     def f(x):
         return x.astype(jnp.float64) * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed, _ = _trace(f, sds((4,), jnp.float32))
     findings = jc.analyze_closed_jaxpr(closed)
     assert "SC001" in _rules_hit(findings)

@@ -134,13 +134,14 @@ def test_sc010_suppression_carries_justification(monkeypatch):
 
 
 def test_sc011_dropped_donation_fires():
-    """Donate an argument no output can alias (dtype change): the jaxpr
+    """Donate an argument no output can alias (byte-width change — a
+    same-width dtype change IS aliased by the installed XLA): the jaxpr
     screen (SC003) flags intent, and SC011 proves from the EXECUTABLE that
     XLA realized no alias."""
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def step(x):
-        return x.astype(jnp.int32)
+        return x.astype(jnp.bfloat16)
 
     ex = lambda: (sds((1024,), jnp.float32),)  # noqa: E731
     report = mc.analyze_entry("fix@dropped", _entry("step", step, ex))

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from sheeprl_tpu.compile import CacheStats, MIN_COMPILE_SECS, arm_compile_cache
+from sheeprl_tpu.compile import CacheStats, MIN_COMPILE_SECS, arm_compile_cache, cache_dir
 
 
 @pytest.fixture
@@ -21,7 +21,6 @@ def restore_cache_config():
             k: os.environ.get(k)
             for k in (
                 "JAX_COMPILATION_CACHE_DIR",
-                "SHEEPRL_TPU_COMPILE_CACHE",
                 "SHEEPRL_TPU_XLA_CACHE",
             )
         },
@@ -41,33 +40,52 @@ def restore_cache_config():
             os.environ[k] = v
 
 
-def test_one_threshold_for_everyone(tmp_path, restore_cache_config):
-    """The satellite fix: every arming path lands the SAME compile-time
-    floor (the old distributed_setup re-arm used a silent 10 s)."""
-    path = arm_compile_cache(str(tmp_path / "c1"))
-    assert path == str(tmp_path / "c1")
+def test_env_var_places_cache_and_decision_store(tmp_path, restore_cache_config):
+    """JAX_COMPILATION_CACHE_DIR wins: the cache is armed there with the one
+    compile-time floor, and the decision store follows the directory."""
+    from sheeprl_tpu.compile import decisions
+
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    path = arm_compile_cache()
+    assert path == str(tmp_path / "placed")
     assert jax.config.jax_compilation_cache_dir == path
     assert jax.config.jax_persistent_cache_min_compile_time_secs == MIN_COMPILE_SECS
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == path
+    assert decisions.cache_path() == os.path.join(path, "decisions.json")
 
-    # distributed_setup routes through the same helper with the same floor
-    os.environ["SHEEPRL_TPU_COMPILE_CACHE"] = str(tmp_path / "c2")
+    # distributed_setup arms nothing of its own
     from sheeprl_tpu.parallel.mesh import distributed_setup
 
     distributed_setup()
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "c2")
-    assert jax.config.jax_persistent_cache_min_compile_time_secs == MIN_COMPILE_SECS
+    assert jax.config.jax_compilation_cache_dir == path
 
-
-def test_resolution_order_and_disable(tmp_path, restore_cache_config):
-    os.environ["SHEEPRL_TPU_COMPILE_CACHE"] = str(tmp_path / "envvar")
-    assert arm_compile_cache() == str(tmp_path / "envvar")
-    # explicit path wins over the env var
-    assert arm_compile_cache(str(tmp_path / "explicit")) == str(
-        tmp_path / "explicit"
-    )
     os.environ["SHEEPRL_TPU_XLA_CACHE"] = "0"
-    assert arm_compile_cache(str(tmp_path / "off")) is None
+    assert arm_compile_cache() is None
+
+
+def test_default_is_absolute_in_checkout_from_any_cwd(tmp_path, restore_cache_config):
+    """Unset: one absolute path inside the checkout, the same from two
+    different working directories (fresh processes, the import-time arm)."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    expected = os.path.join(repo, "logs", "jax_compile_cache")
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    assert cache_dir() == expected
+    assert arm_compile_cache() == expected
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = repo
+    code = (
+        "import sheeprl_tpu, jax; from sheeprl_tpu.compile import decisions; "
+        "print(jax.config.jax_compilation_cache_dir); print(decisions.cache_path())"
+    )
+    for cwd in (str(tmp_path), repo):
+        out = subprocess.run(
+            [sys.executable, "-c", code], cwd=cwd, env=env,
+            capture_output=True, text=True, timeout=120, check=True,
+        ).stdout.split()
+        assert out == [expected, os.path.join(expected, "decisions.json")], (cwd, out)
 
 
 @pytest.mark.timeout(120)
@@ -75,10 +93,9 @@ def test_cache_hit_miss_counting(tmp_path, restore_cache_config):
     """Compile the same program twice (fresh jit objects, so no in-memory
     dispatch-cache reuse): first is a persistent-cache miss, second a hit.
     min_compile_secs=0 lets the tiny test graph qualify for caching."""
-    arm_compile_cache(str(tmp_path / "cache"), min_compile_secs=0.0)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    arm_compile_cache(min_compile_secs=0.0)
     stats = CacheStats().attach()
-    if not stats.supported:
-        pytest.skip("jax.monitoring unavailable")
 
     def build():
         # non-trivial enough that XLA actually compiles a module

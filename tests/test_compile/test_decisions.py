@@ -173,52 +173,6 @@ def test_remat_no_scan_keeps_baseline(tmp_path):
     assert d.winner == "off" and not d.accepted
 
 
-def test_scan_unroll_legacy_store_migration(tmp_path, monkeypatch):
-    """Satellite: a pre-ISSUE-11 `scan_unroll.json` winner store is
-    one-shot migrated into the unified cache under the new key schema —
-    the old winner is served as a cache hit (no re-measurement), and the
-    legacy file is gone."""
-    from sheeprl_tpu.ops import scan as scan_mod
-
-    def fn(xs):
-        def step(c, x):
-            return c + x, c + x
-
-        _, ys = jax.lax.scan(step, jnp.float32(0.0), xs, unroll=scan_mod.scan_unroll())
-        return ys
-
-    xs = jnp.arange(12.0)
-    # the legacy key schema: name|avals|jaxX|backend (ops/scan.py @ PR 9)
-    legacy_key = (
-        f"test.mig|float32[12]|jax{jax.__version__}|{jax.default_backend()}"
-    )
-    legacy = {
-        legacy_key: {
-            "probe": "test.mig", "winner": 4,
-            "timings_s": {"1": 0.5, "4": 0.125},
-            "compile_s": {"1": 0.01, "4": 0.02},
-            "bit_exact": {"1": True, "4": True},
-        }
-    }
-    with open(tmp_path / "scan_unroll.json", "w") as fh:
-        json.dump(legacy, fh)
-    store = str(tmp_path / "decisions.json")
-    try:
-        d = scan_mod.autotune_unroll(
-            "test.mig", fn, (xs,), rungs=(1, 4), repeats=1,
-            store_path=store, apply=True,
-        )
-        # served from the MIGRATED entry: no measurement, old winner kept
-        assert d.source == "cache"
-        assert d.winner == 4
-        assert scan_mod.scan_unroll() == 4
-        assert not (tmp_path / "scan_unroll.json").exists()
-        with open(store) as fh:
-            assert f"scan_unroll|{legacy_key}" in json.load(fh)
-    finally:
-        scan_mod.set_unroll(None)
-
-
 def test_batch_chunk_probe_served_from_cache(tmp_path):
     """The decide_batch_chunk measurement (lowering + trial compile) is
     memoized in the unified cache: the second call never lowers or

@@ -26,8 +26,7 @@ _NEVER_CALLED_SCRIPT = textwrap.dedent(
     os.environ["JAX_PLATFORMS"] = "cpu"
     # a persistent-cache hit would make the compile instant and the race
     # moot — force a real in-flight XLA compile at exit
-    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    os.environ.pop("SHEEPRL_TPU_COMPILE_CACHE", None)
+    os.environ["SHEEPRL_TPU_XLA_CACHE"] = "0"
     os.environ.pop("SHEEPRL_TPU_PLAN_MODE", None)
     import jax
     import jax.numpy as jnp

@@ -62,8 +62,11 @@ def test_aot_vs_direct_bit_exact():
     avals produces bitwise-identical outputs to the cold jit path."""
     train_step, state, data, key = _sac_step()
     flag = jnp.asarray(True)
-    # cold/direct path first (its own jit cache entry)
-    s_direct, m_direct = train_step(state, data, key, flag)
+    # cold/direct path first (its own jit cache entry); the step donates its
+    # state, so it gets a copy and `state` stays alive for the AOT call
+    s_direct, m_direct = train_step(
+        jax.tree_util.tree_map(jnp.copy, state), data, key, flag
+    )
 
     plan = CompilePlan.from_args(_Args())
     wrapped = plan.register(

@@ -259,3 +259,83 @@ def test_fused_rssm_dispatch_falls_back_on_mismatch(pallas_interpret):
         b["post"], b["rec"], b["act"], b["emb"], b["first"], b["key"]
     )
     assert all(np.all(np.isfinite(np.asarray(o, dtype=np.float32))) for o in out)
+
+
+def test_default_width_fused_rssm_refusal_is_recorded(pallas_interpret, tmp_path):
+    """At the default DreamerV3 width the fused RSSM step is never selected
+    (its six weights outgrow the whole-weights-in-VMEM budget) — and that
+    decision is a `kernel.select` telemetry event, not a silent fall-through."""
+    import json
+
+    from sheeprl_tpu import nn
+    from sheeprl_tpu.algos.dreamer_v3.agent import RSSM, RecurrentModel
+    from sheeprl_tpu.telemetry import Telemetry
+
+    # DreamerV3Args defaults: 32x32 latent + 2 actions, recurrent/dense/hidden
+    # 512, cnn x32 on 64x64 -> 4096-wide embedding
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    rm = RecurrentModel.init(ks[0], 32 * 32 + 2, 512, 512, layer_norm=True, activation="silu")
+    tm = nn.MLP.init(ks[1], 512, [512], 32 * 32, act="silu", layer_norm=True,
+                     use_bias=False, norm_eps=1e-3)
+    pm = nn.MLP.init(ks[2], 512 + 4096, [512], 32 * 32, act="silu", layer_norm=True,
+                     use_bias=False, norm_eps=1e-3)
+    rssm = RSSM(recurrent_model=rm, representation_model=pm, transition_model=tm,
+                discrete=32, unimix=0.01)
+    telem = Telemetry(str(tmp_path))
+    try:
+        for dtype in (jnp.float32, jnp.bfloat16):
+            x = jnp.zeros((16, 32 * 32 + 2), dtype)
+            emb = jnp.zeros((16, 4096), dtype)
+            assert rssm._fused_step_weights(x, emb) is None
+    finally:
+        telem.close()
+    with open(tmp_path / "telemetry.jsonl") as fh:
+        events = [json.loads(line) for line in fh]
+    picks = [e for e in events if e["event"] == "kernel.select" and e["family"] == "rssm"]
+    assert len(picks) == 2
+    for e in picks:
+        assert e["selected"] is False and e["reason"] == "vmem"
+        assert e["bytes"] > e["budget"] == pk._FUSED_VMEM_BUDGET_BYTES
+    # f32 weights are twice the bf16 ones (22.0 vs 11.0 MiB against 10 MiB)
+    assert picks[0]["bytes"] > picks[1]["bytes"]
+
+
+def test_partitioned_jit_takes_the_xla_twin_and_says_so(tmp_path):
+    """Mosaic cannot auto-partition a kernel ("wrap the call in a shard_map"),
+    so a dispatch site whose operand is typed with a multi-device mesh takes
+    the XLA twin — recorded as `kernel.select reason=partitioned`. Kernels are
+    forced on WITHOUT the interpreter here: reaching Mosaic would fail on CPU."""
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sheeprl_tpu.telemetry import Telemetry
+
+    cell = LayerNormGRUCell.init(jax.random.PRNGKey(0), 6, 8, use_bias=False)
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+    x = jax.device_put(
+        jax.random.normal(jax.random.PRNGKey(1), (8, 6)), NamedSharding(mesh, P("data"))
+    )
+    h = jax.device_put(
+        jax.random.normal(jax.random.PRNGKey(2), (8, 8)), NamedSharding(mesh, P("data"))
+    )
+    want = cell(x, h)  # auto mode on CPU: kernels off
+    # the policy-step shape of the same hazard: inputs straight from the host
+    # (no mesh in their type), params replicated over the mesh
+    cell_r = jax.device_put(cell, NamedSharding(mesh, P()))
+    x_host, h_host = jnp.asarray(np.asarray(x)), jnp.asarray(np.asarray(h))
+    telem = Telemetry(str(tmp_path))
+    pk.set_pallas(True, interpret=False)
+    try:
+        got = jax.jit(lambda c, a, b: c(a, b))(cell, x, h)
+        got_r = jax.jit(lambda c, a, b: c(a, b))(cell_r, x_host, h_host)
+    finally:
+        pk.set_pallas(None, interpret=False)
+        telem.close()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_r), np.asarray(want), atol=1e-6)
+    with open(tmp_path / "telemetry.jsonl") as fh:
+        picks = [e for e in map(json.loads, fh) if e["event"] == "kernel.select"]
+    assert [(e["family"], e["selected"], e["reason"], e["devices"]) for e in picks] == [
+        ("gru", False, "partitioned", 4)
+    ] * 2

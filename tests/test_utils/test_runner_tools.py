@@ -6,8 +6,6 @@ import json
 import os
 import sys
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
@@ -20,16 +18,16 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 def test_sweep_matches_only_python_runner_processes():
     from sweep_runners import _is_runner_cmd
 
-    # real runners, in the shapes the autobench loop spawns them
+    # real runners, in the shapes an operator spawns them
     assert _is_runner_cmd("python tools/dv1_learning_run.py --root logs/x")
-    assert _is_runner_cmd("python3 -u /root/repo/tools/pixel_chip_run.py")
+    assert _is_runner_cmd("python3 -u /root/repo/tools/dv3_pixel_learning_run.py")
     assert _is_runner_cmd("/usr/bin/python3.10 tools/sac_ae_pixel_learning_run.py")
 
     # ADVICE r5: these used to be SIGKILLed by the substring match
     assert not _is_runner_cmd("tail -f logs/dv1_learning_run.py.out")
     assert not _is_runner_cmd("vim tools/dv1_learning_run.py")
-    assert not _is_runner_cmd("grep -r pixel_chip_run.py tools/")
-    assert not _is_runner_cmd("less pixel_chip_run.py")
+    assert not _is_runner_cmd("grep -r dv3_pixel_learning_run.py tools/")
+    assert not _is_runner_cmd("less dv3_pixel_learning_run.py")
     # the sweep itself, and unrelated python work
     assert not _is_runner_cmd("python tools/sweep_runners.py --dry-run")
     assert not _is_runner_cmd("python bench.py --tiny")
@@ -127,44 +125,3 @@ def test_ledger_meta_carries_code_fingerprint(tmp_path, monkeypatch):
     led3 = bench.PhaseLedger(path, {"algo": "t"})
     assert not led3.done("A")
     assert led3.resumed_from_sidecar is False
-
-
-def test_bench_compile_cache_arming(monkeypatch):
-    import bench
-
-    # explicit '' disables; unset + tiny stays hermetic (no env mutation)
-    monkeypatch.setenv("SHEEPRL_TPU_COMPILE_CACHE", "")
-    bench._arm_compile_cache(tiny=False)
-    assert os.environ["SHEEPRL_TPU_COMPILE_CACHE"] == ""
-
-    monkeypatch.delenv("SHEEPRL_TPU_COMPILE_CACHE", raising=False)
-    bench._arm_compile_cache(tiny=True)
-    assert "SHEEPRL_TPU_COMPILE_CACHE" not in os.environ
-
-    # full bench: defaults to the runners' shared location and applies it
-    bench._arm_compile_cache(tiny=False)
-    assert os.environ["SHEEPRL_TPU_COMPILE_CACHE"] == "logs/jax_compile_cache"
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "logs/jax_compile_cache"
-    import jax
-
-    assert jax.config.jax_compilation_cache_dir == "logs/jax_compile_cache"
-
-
-@pytest.fixture(autouse=True)
-def _restore_cache_config():
-    """test_bench_compile_cache_arming mutates global jax config + env; put
-    both back so the suite's shared-cache contract (conftest) holds."""
-    import jax
-
-    before_cfg = jax.config.jax_compilation_cache_dir
-    before_env = {
-        k: os.environ.get(k)
-        for k in ("SHEEPRL_TPU_COMPILE_CACHE", "JAX_COMPILATION_CACHE_DIR")
-    }
-    yield
-    jax.config.update("jax_compilation_cache_dir", before_cfg)
-    for k, v in before_env.items():
-        if v is None:
-            os.environ.pop(k, None)
-        else:
-            os.environ[k] = v

@@ -21,9 +21,8 @@ The soft handler uses SIGALRM/SIGTERM -> Python exception, which only fires
 between bytecodes — a long native call defers it, hence the separate hard
 timer with a grace window sized to one pathological compile.
 
-Runners also get the persistent compilation cache
-(SHEEPRL_TPU_COMPILE_CACHE -> jax_compilation_cache_dir via
-parallel/mesh.py:distributed_setup) so a pathological compile is paid once
+Runners share the package's persistent compilation cache
+(sheeprl_tpu/compile/cache.py) so a pathological compile is paid once
 across bounded sessions, not once per resume.
 """
 
@@ -39,13 +38,6 @@ import time
 
 class BudgetExpired(Exception):
     """Soft deadline (or SIGTERM from the session-end sweep) hit."""
-
-
-def enable_compile_cache(path: str = "logs/jax_compile_cache") -> None:
-    """Arm the persistent compilation cache for this process (read by
-    distributed_setup before any jit compiles). Call before importing the
-    algo mains' jits."""
-    os.environ.setdefault("SHEEPRL_TPU_COMPILE_CACHE", path)
 
 
 def bounded_runner_main(

@@ -268,16 +268,10 @@ def propose_remat(ledger: dict, top: int = 8) -> list[dict]:
 def decision_cache_path(explicit: str | None = None) -> str:
     if explicit:
         return explicit
-    base = (
-        os.environ.get("SHEEPRL_TPU_COMPILE_CACHE")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    )
-    if not base:
-        import tempfile
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    from sheeprl_tpu.compile.cache import cache_dir
 
-        uid = getattr(os, "getuid", lambda: "u")()
-        base = os.path.join(tempfile.gettempdir(), f"sheeprl_tpu_xla_cache_{uid}")
-    return os.path.join(base, "decisions.json")
+    return os.path.join(cache_dir(), "decisions.json")
 
 
 def load_decisions(path: str) -> dict:

@@ -1,7 +1,6 @@
 """Session-end straggler sweep (VERDICT r4 #4).
 
-SIGTERMs any `tools/*_learning_run.py` / `pixel_chip_run.py` process still
-alive — the bounded harness (tools/runner_common.py) turns SIGTERM into the
+SIGTERMs any `tools/*_learning_run.py` process still alive — the bounded harness (tools/runner_common.py) turns SIGTERM into the
 graceful checkpoint-then-eval path, so a swept runner lands a
 partial/resumable receipt instead of dying silently. After a grace window,
 survivors (stuck in native code) get SIGKILL; their mid-run checkpoints
@@ -9,8 +8,7 @@ remain resumable and runner_common's hard timer has usually already written
 a stub.
 
 Usage: python tools/sweep_runners.py [--grace-s 900] [--dry-run]
-Intended callers: the autobench loop's session boundary and any operator
-ending a work session.
+Intended caller: any operator ending a work session.
 """
 
 from __future__ import annotations
@@ -21,7 +19,7 @@ import signal
 import subprocess
 import time
 
-PATTERNS = ("learning_run.py", "pixel_chip_run.py")
+PATTERNS = ("learning_run.py",)
 
 
 def _is_runner_cmd(cmd: str) -> bool:

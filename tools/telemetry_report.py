@@ -294,21 +294,13 @@ def load_concurrency_ledger(path: str | None = None) -> dict:
 def load_decision_cache(path: str | None = None) -> dict:
     """The unified sheepopt decision cache (`decisions.json` next to the
     compile cache, compile/decisions.py) — stdlib-only, empty dict when
-    absent. Resolution mirrors the writer: explicit path, then the
-    compile-cache env vars, then the tempdir default."""
+    absent. An explicit path, else the writer's own resolution
+    (compile/cache.py:cache_dir)."""
     if path is None:
-        base = (
-            os.environ.get("SHEEPRL_TPU_COMPILE_CACHE")
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        )
-        if not base:
-            import tempfile
+        sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        from sheeprl_tpu.compile.cache import cache_dir
 
-            uid = getattr(os, "getuid", lambda: "u")()
-            base = os.path.join(
-                tempfile.gettempdir(), f"sheeprl_tpu_xla_cache_{uid}"
-            )
-        path = os.path.join(base, "decisions.json")
+        path = os.path.join(cache_dir(), "decisions.json")
     try:
         with open(path, encoding="utf-8") as fh:
             return json.load(fh)
@@ -683,7 +675,8 @@ def render(summary: dict) -> str:
     lines.append("== run ==")
     lines.append(
         f"algo={start.get('algo', '?')} env={start.get('env_id', '?')} "
-        f"seed={start.get('seed', '?')} backend={start.get('backend', '?')} "
+        f"seed={start.get('seed', '?')} platform={start.get('platform', '?')} "
+        f"device_kind={start.get('device_kind', '?')} "
         f"devices={start.get('local_devices', '?')}"
     )
     if summary["first_ts"] is not None and summary["last_ts"] is not None:

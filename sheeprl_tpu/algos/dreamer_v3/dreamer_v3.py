@@ -97,6 +97,13 @@ from ..dreamer_v2.utils import maybe_autotune_scan_unroll, maybe_decide_remat
 from .utils import make_device_preprocess, test
 
 
+# the named regions of the train step, in the order they run
+TRAIN_STEP_SCOPES = (
+    "wm/encoder", "wm/rssm_scan", "wm/decoder", "wm/heads", "wm/loss", "wm/opt",
+    "imagine", "moments", "actor/loss", "actor/opt", "critic/loss", "critic/opt",
+)
+
+
 class DV3TrainState(nn.Module):
     world_model: WorldModel
     actor: object
@@ -154,7 +161,12 @@ def make_train_step(
     reward/continue heads, imagination over the T*B flattened axis) compute
     in that layout, while sharding constraints reshard the RSSM scan's
     inputs/outputs to batch-only — GSPMD inserts the all-gather/slice
-    collectives over ICI at the two phase boundaries."""
+    collectives over ICI at the two phase boundaries.
+
+    Its regions carry `jax.named_scope`s (TRAIN_STEP_SCOPES): a scope reaches
+    the `op_name` of every operation traced under it, and of its backward
+    (`jvp(wm/encoder)`, `transpose(jvp(wm/encoder))`), so a device trace can
+    be split by region. Metadata only: the compiled program is the same."""
     stoch_size = args.stochastic_size * args.discrete_size
     horizon = args.horizon
     action_splits = np.cumsum(actions_dim)[:-1]
@@ -194,24 +206,26 @@ def make_train_step(
             # batch-over-"data" with the seq groups replicating the scan
             # (scan_batch_spec explains why this beats the fully-sharded
             # alternative under GSPMD)
-            embedded = constrain_scan_inputs(
-                constrain, scan_spec, wm.encoder(batch_obs)
-            )
+            with jax.named_scope("wm/encoder"):
+                embedded = constrain_scan_inputs(
+                    constrain, scan_spec, wm.encoder(batch_obs)
+                )
             posterior0 = jnp.zeros(
                 (B, args.stochastic_size, args.discrete_size), compute_dtype
             )
             recurrent0 = jnp.zeros((B, args.recurrent_state_size), compute_dtype)
-            recurrent_states, priors_logits, posteriors, posteriors_logits = (
-                wm.rssm.scan_dynamic(
-                    posterior0,
-                    recurrent0,
-                    constrain_scan_inputs(constrain, scan_spec, batch_actions),
-                    embedded,
-                    constrain_scan_inputs(constrain, scan_spec, is_first),
-                    k_wm,
-                    remat=use_remat,
+            with jax.named_scope("wm/rssm_scan"):
+                recurrent_states, priors_logits, posteriors, posteriors_logits = (
+                    wm.rssm.scan_dynamic(
+                        posterior0,
+                        recurrent0,
+                        constrain_scan_inputs(constrain, scan_spec, batch_actions),
+                        embedded,
+                        constrain_scan_inputs(constrain, scan_spec, is_first),
+                        k_wm,
+                        remat=use_remat,
+                    )
                 )
-            )
             # back to time-sharded for the decoder/reward/continue heads
             # (a local T-slice out of the replicated-scan layout)
             recurrent_states, priors_logits, posteriors, posteriors_logits = (
@@ -224,41 +238,44 @@ def make_train_step(
             latent_states = jnp.concatenate(
                 [posteriors.reshape(T, B, -1), recurrent_states], axis=-1
             )
-            reconstructed = {
-                k: v.astype(jnp.float32)
-                for k, v in wm.observation_model(latent_states).items()
-            }
+            with jax.named_scope("wm/decoder"):
+                reconstructed = {
+                    k: v.astype(jnp.float32)
+                    for k, v in wm.observation_model(latent_states).items()
+                }
             po = {
                 k: MSEDistribution(_mode=reconstructed[k], dims=3) for k in cnn_keys
             }
             po.update(
                 {k: SymlogDistribution(_mode=reconstructed[k], dims=1) for k in mlp_keys}
             )
-            pr = TwoHotEncodingDistribution(
-                logits=wm.reward_model(latent_states).astype(jnp.float32), dims=1
-            )
-            pc = Independent(
-                base=Bernoulli(
-                    logits=wm.continue_model(latent_states).astype(jnp.float32)
-                ),
-                event_ndims=1,
-            )
+            with jax.named_scope("wm/heads"):
+                pr = TwoHotEncodingDistribution(
+                    logits=wm.reward_model(latent_states).astype(jnp.float32), dims=1
+                )
+                pc = Independent(
+                    base=Bernoulli(
+                        logits=wm.continue_model(latent_states).astype(jnp.float32)
+                    ),
+                    event_ndims=1,
+                )
             shaped = (T, B, args.stochastic_size, args.discrete_size)
-            losses = reconstruction_loss(
-                po,
-                obs_targets,
-                pr,
-                data["rewards"],
-                priors_logits.reshape(shaped),
-                posteriors_logits.reshape(shaped),
-                args.kl_dynamic,
-                args.kl_representation,
-                args.kl_free_nats,
-                args.kl_regularizer,
-                pc,
-                continue_targets,
-                args.continue_scale_factor,
-            )
+            with jax.named_scope("wm/loss"):
+                losses = reconstruction_loss(
+                    po,
+                    obs_targets,
+                    pr,
+                    data["rewards"],
+                    priors_logits.reshape(shaped),
+                    posteriors_logits.reshape(shaped),
+                    args.kl_dynamic,
+                    args.kl_representation,
+                    args.kl_free_nats,
+                    args.kl_regularizer,
+                    pc,
+                    continue_targets,
+                    args.continue_scale_factor,
+                )
             rec_loss = losses[0]
             return rec_loss, (losses, recurrent_states, posteriors, priors_logits, posteriors_logits)
 
@@ -266,10 +283,11 @@ def make_train_step(
             jax.value_and_grad(world_loss_fn, has_aux=True)(state.world_model)
         )
         rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = wm_losses
-        wm_updates, world_opt = world_optimizer.update(
-            wm_grads, state.world_opt, state.world_model
-        )
-        world_model = optax.apply_updates(state.world_model, wm_updates)
+        with jax.named_scope("wm/opt"):
+            wm_updates, world_opt = world_optimizer.update(
+                wm_grads, state.world_opt, state.world_model
+            )
+            world_model = optax.apply_updates(state.world_model, wm_updates)
 
         # ---- behaviour: imagination + actor ---------------------------------
         # imagination flattens [T, B] -> rows; a (seq, data)-sharded [T, B]
@@ -310,74 +328,78 @@ def make_train_step(
             # H imagination steps emitting the pre-step latent, plus the final
             # latent/action pair outside the scan: H+1 trajectory entries from
             # exactly H RSSM transitions (reference loop, dreamer_v3.py:217-223)
-            (prior_h, recurrent_h), (latents, actions_h) = jax.lax.scan(
-                img_step,
-                (imagined_prior0, recurrent0),
-                img_keys[:horizon],
-                unroll=ops.scan_unroll(),
-            )
-            latent_h = jnp.concatenate([prior_h, recurrent_h], axis=-1)
-            last_acts, _ = actor(jax.lax.stop_gradient(latent_h), key=img_keys[horizon])
-            imagined_trajectories = jnp.concatenate(
-                [latents, latent_h[None]], axis=0
-            )  # [H+1, T*B, L]
-            imagined_actions = jnp.concatenate(
-                [actions_h, jnp.concatenate(last_acts, axis=-1)[None]], axis=0
-            )  # [H+1, T*B, A]
+            with jax.named_scope("imagine"):
+                (prior_h, recurrent_h), (latents, actions_h) = jax.lax.scan(
+                    img_step,
+                    (imagined_prior0, recurrent0),
+                    img_keys[:horizon],
+                    unroll=ops.scan_unroll(),
+                )
+                latent_h = jnp.concatenate([prior_h, recurrent_h], axis=-1)
+                last_acts, _ = actor(jax.lax.stop_gradient(latent_h), key=img_keys[horizon])
+            with jax.named_scope("actor/loss"):
+                imagined_trajectories = jnp.concatenate(
+                    [latents, latent_h[None]], axis=0
+                )  # [H+1, T*B, L]
+                imagined_actions = jnp.concatenate(
+                    [actions_h, jnp.concatenate(last_acts, axis=-1)[None]], axis=0
+                )  # [H+1, T*B, A]
 
-            predicted_values = TwoHotEncodingDistribution(
-                logits=state.critic(imagined_trajectories).astype(jnp.float32),
-                dims=1,
-            ).mean
-            predicted_rewards = TwoHotEncodingDistribution(
-                logits=world_model.reward_model(imagined_trajectories).astype(
-                    jnp.float32
-                ),
-                dims=1,
-            ).mean
-            continues = Independent(
-                base=Bernoulli(
-                    logits=world_model.continue_model(imagined_trajectories).astype(
+                predicted_values = TwoHotEncodingDistribution(
+                    logits=state.critic(imagined_trajectories).astype(jnp.float32),
+                    dims=1,
+                ).mean
+                predicted_rewards = TwoHotEncodingDistribution(
+                    logits=world_model.reward_model(imagined_trajectories).astype(
                         jnp.float32
+                    ),
+                    dims=1,
+                ).mean
+                continues = Independent(
+                    base=Bernoulli(
+                        logits=world_model.continue_model(imagined_trajectories).astype(
+                            jnp.float32
+                        )
+                    ),
+                    event_ndims=1,
+                ).mode
+                continues = jnp.concatenate([true_continue0, continues[1:]], axis=0)
+
+                lambda_values = ops.lambda_values_dv3(
+                    predicted_rewards[1:],
+                    predicted_values[1:],
+                    continues[1:] * args.gamma,
+                    lmbda=args.lmbda,
+                )
+                discount = jax.lax.stop_gradient(
+                    jnp.cumprod(continues * args.gamma, axis=0) / args.gamma
+                )
+
+            with jax.named_scope("moments"):
+                new_moments, (offset, invscale) = state.moments.update(lambda_values)
+            with jax.named_scope("actor/loss"):
+                normed_lambda_values = (lambda_values - offset) / invscale
+                normed_baseline = (predicted_values[:-1] - offset) / invscale
+                advantage = normed_lambda_values - normed_baseline
+
+                policies = actor.dists(jax.lax.stop_gradient(imagined_trajectories))
+                if is_continuous:
+                    objective = advantage
+                else:
+                    per_head_actions = jnp.split(
+                        jax.lax.stop_gradient(imagined_actions), action_splits, axis=-1
                     )
-                ),
-                event_ndims=1,
-            ).mode
-            continues = jnp.concatenate([true_continue0, continues[1:]], axis=0)
-
-            lambda_values = ops.lambda_values_dv3(
-                predicted_rewards[1:],
-                predicted_values[1:],
-                continues[1:] * args.gamma,
-                lmbda=args.lmbda,
-            )
-            discount = jax.lax.stop_gradient(
-                jnp.cumprod(continues * args.gamma, axis=0) / args.gamma
-            )
-
-            new_moments, (offset, invscale) = state.moments.update(lambda_values)
-            normed_lambda_values = (lambda_values - offset) / invscale
-            normed_baseline = (predicted_values[:-1] - offset) / invscale
-            advantage = normed_lambda_values - normed_baseline
-
-            policies = actor.dists(jax.lax.stop_gradient(imagined_trajectories))
-            if is_continuous:
-                objective = advantage
-            else:
-                per_head_actions = jnp.split(
-                    jax.lax.stop_gradient(imagined_actions), action_splits, axis=-1
-                )
-                log_probs = sum(
-                    p.log_prob(a)[..., None]
-                    for p, a in zip(policies, per_head_actions)
-                )
-                objective = log_probs[:-1] * jax.lax.stop_gradient(advantage)
-            entropies = [_policy_entropy(p) for p in policies]
-            if any(e is None for e in entropies):
-                entropy = jnp.zeros_like(objective)
-            else:
-                entropy = args.actor_ent_coef * sum(entropies)[..., None][:-1]
-            policy_loss = -jnp.mean(discount[:-1] * (objective + entropy))
+                    log_probs = sum(
+                        p.log_prob(a)[..., None]
+                        for p, a in zip(policies, per_head_actions)
+                    )
+                    objective = log_probs[:-1] * jax.lax.stop_gradient(advantage)
+                entropies = [_policy_entropy(p) for p in policies]
+                if any(e is None for e in entropies):
+                    entropy = jnp.zeros_like(objective)
+                else:
+                    entropy = args.actor_ent_coef * sum(entropies)[..., None][:-1]
+                policy_loss = -jnp.mean(discount[:-1] * (objective + entropy))
             return policy_loss, (
                 imagined_trajectories,
                 lambda_values,
@@ -388,17 +410,21 @@ def make_train_step(
         (policy_loss, (imagined_trajectories, lambda_values, discount, new_moments)), actor_grads = (
             jax.value_and_grad(actor_loss_fn, has_aux=True)(state.actor)
         )
-        actor_updates, actor_opt = actor_optimizer.update(
-            actor_grads, state.actor_opt, state.actor
-        )
-        actor = optax.apply_updates(state.actor, actor_updates)
+        with jax.named_scope("actor/opt"):
+            actor_updates, actor_opt = actor_optimizer.update(
+                actor_grads, state.actor_opt, state.actor
+            )
+            actor = optax.apply_updates(state.actor, actor_updates)
 
         # ---- critic ----------------------------------------------------------
         traj_sg = jax.lax.stop_gradient(imagined_trajectories[:-1])
-        target_values = TwoHotEncodingDistribution(
-            logits=target_critic(traj_sg).astype(jnp.float32), dims=1
-        ).mean
 
+        with jax.named_scope("critic/loss"):
+            target_values = TwoHotEncodingDistribution(
+                logits=target_critic(traj_sg).astype(jnp.float32), dims=1
+            ).mean
+
+        @jax.named_scope("critic/loss")
         def critic_loss_fn(critic):
             qv = TwoHotEncodingDistribution(
                 logits=critic(traj_sg).astype(jnp.float32), dims=1
@@ -408,10 +434,11 @@ def make_train_step(
             return jnp.mean(value_loss * discount[:-1, :, 0])
 
         value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(state.critic)
-        critic_updates, critic_opt = critic_optimizer.update(
-            critic_grads, state.critic_opt, state.critic
-        )
-        critic = optax.apply_updates(state.critic, critic_updates)
+        with jax.named_scope("critic/opt"):
+            critic_updates, critic_opt = critic_optimizer.update(
+                critic_grads, state.critic_opt, state.critic
+            )
+            critic = optax.apply_updates(state.critic, critic_updates)
 
         shaped = (T, B, args.stochastic_size, args.discrete_size)
         post_entropy = (
@@ -482,9 +509,10 @@ def make_blob_step(codec, obs_keys, dev_preprocess, actions_dim, is_continuous):
         u8, f32, idx = codec.unpack(blob)
         o = {**u8, **{kk: f32[kk] for kk in obs_keys if kk in f32}}
         mask = {kk: v for kk, v in o.items() if kk.startswith("mask")} or None
-        new_s, acts = p.step(
-            s, dev_preprocess(o), k, expl, is_training=True, mask=mask
-        )
+        with jax.named_scope("player/step"):
+            new_s, acts = p.step(
+                s, dev_preprocess(o), k, expl, is_training=True, mask=mask
+            )
         row = {kk: v[None] for kk, v in o.items()}
         row["actions"] = acts[None].astype(jnp.float32)
         for kk in ("rewards", "dones", "is_first"):
@@ -695,9 +723,10 @@ def main(argv: Sequence[str] | None = None) -> None:
     _dev_preprocess = make_device_preprocess(cnn_keys)
 
     def _player_step(p, s, o, k, expl, mask):
-        new_s, acts = p.step(
-            s, _dev_preprocess(o), k, expl, is_training=True, mask=mask
-        )
+        with jax.named_scope("player/step"):
+            new_s, acts = p.step(
+                s, _dev_preprocess(o), k, expl, is_training=True, mask=mask
+            )
         # per-head env indices computed on device: the per-step d2h pull is
         # a few ints; the one-hot stays device-resident for rb.add
         return new_s, acts, env_action_indices(acts, actions_dim, is_continuous)
@@ -1039,9 +1068,15 @@ def main(argv: Sequence[str] | None = None) -> None:
         steps_iter = range(start_step, num_updates + 1)
     for global_step in steps_iter:
         guard.tick(global_step)  # fires injected sig* faults for this step
-        telem.mark("rollout")
+        # the loop body is one `iteration` span; its spans (howto/
+        # observability.md has the table) are opened where the work is, and
+        # what none covers is the iteration's self time. `phase=` names the
+        # `Time/*` sum a finer span is logged under: the four the loop always
+        # had, because every scalar logged here costs the device idle time
+        telem.iteration(global_step)
         blob_added = False
         if use_flock:
+            telem.mark("rollout")
             # actors collect; one loop iteration corresponds to ONE replay
             # row landing fleet-wide (num_envs env steps — the same
             # global_step unit as the in-process path). The wait is the
@@ -1063,6 +1098,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                 time.sleep(0.01)
         elif use_jax_env:
             # ---- Anakin collection: one jitted scan per chunk ---------------
+            telem.mark("rollout")
             key, roll_key = jax.random.split(key)
             random_phase = (
                 global_step <= learning_starts and args.checkpoint_path is None
@@ -1095,6 +1131,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             and args.checkpoint_path is None
             and "minedojo" not in args.env_id
         ):
+            telem.mark("rollout")
             pairs = [
                 _random_actions(action_space, actions_dim, is_continuous)
                 for _ in range(args.num_envs)
@@ -1105,6 +1142,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             # ONE transfer for the whole step: obs + prev rewards/dones/
             # is_first + ring write indices; the jit returns the device
             # replay row and add_direct scatters it transfer-free
+            telem.mark("rollout/pack", phase="rollout")
             idx = rb.reserve(1)
             blob = codec.pack(
                 {k: np.asarray(obs[k]) for k in u8_keys},
@@ -1116,6 +1154,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                 },
                 idx,
             )
+            telem.mark("rollout/policy_dispatch", phase="rollout")
             key, step_key = jax.random.split(key)
             player_state, env_idx_dev, row, idx_dev = blob_step(
                 player, player_state, jnp.asarray(blob), step_key, expl_dev
@@ -1124,13 +1163,18 @@ def main(argv: Sequence[str] | None = None) -> None:
             # the replay scatter dispatches (ActionPipeline; with --pipeline
             # off the handle is a plain deferred np.asarray)
             idx_handle = pipe.action.dispatch(env_idx_dev)
+            telem.mark("rollout/add_dispatch", phase="rollout")
             rb.add_direct(row, idx_dev)
             blob_added = True
+            # the host blocked on the policy step
+            telem.mark("rollout/action_wait", phase="rollout")
             env_idx = idx_handle.get()  # the ONLY per-step d2h pull
+            telem.mark(None)
             env_actions = list(
                 indices_to_env_actions(env_idx, actions_dim, is_continuous)
             )
         else:
+            telem.mark("rollout")
             # raw puts (uint8 for pixels): normalization happens inside the
             # jitted player step, and these same device arrays feed rb.add
             device_obs = {k: jnp.asarray(np.asarray(obs[k])) for k in obs_keys}
@@ -1165,8 +1209,10 @@ def main(argv: Sequence[str] | None = None) -> None:
                 rb.add(add_data)
             device_step_obs = None
 
+            telem.mark("rollout/env_step", phase="rollout")
             next_obs, rewards, terms, truncs, infos = envs.step(env_actions)
             dones = np.logical_or(terms, truncs).astype(np.float32)
+            telem.mark(None)
 
             step_data["is_first"] = np.zeros((args.num_envs, 1), np.float32)
             for i, info in enumerate(infos):
@@ -1200,6 +1246,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             if dones_idxes:
                 # terminal rows carry the true final observation and zero actions
                 # (reference dreamer_v3.py:609-628)
+                telem.mark("rollout/reset", phase="rollout")
                 n_reset = len(dones_idxes)
                 reset_data = {k: real_next_obs[k][dones_idxes][None] for k in obs_keys}
                 reset_data["dones"] = np.ones((1, n_reset, 1), np.float32)
@@ -1215,6 +1262,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                 reset_mask = np.zeros((args.num_envs,), np.float32)
                 reset_mask[dones_idxes] = 1.0
                 player_state = player.reset_states(player_state, jnp.asarray(reset_mask))
+                telem.mark(None)
 
         step_before_training -= anakin_chunk if use_jax_env else 1
 
@@ -1236,16 +1284,23 @@ def main(argv: Sequence[str] | None = None) -> None:
                 sequence_length=args.per_rank_sequence_length,
                 n_samples=n_samples,
             )
+            telem.mark("buffer/stage", phase="buffer/sample")
             staged = stage_batch(local_data, to_host=jax.process_count() > 1)
-            telem.mark("train/dispatch")
             for i in range(n_samples):
                 if gradient_steps % args.critic_target_network_update_freq == 0:
                     tau = 1.0 if gradient_steps == 0 else args.critic_tau
                 else:
                     tau = 0.0
+                # two spans a train step: the staged block's row (`v[i]`: a
+                # dozen tiny programs) apart from the step's own enqueue. With
+                # several steps an iteration the runtime holds the host back
+                # inside a later row's dispatches until a program ahead of
+                # them ends; one span over the loop hid that as dispatch time
+                telem.mark("train/slice", phase="train/dispatch")
                 sample = {k: v[i] for k, v in staged.items()}
                 if n_dev > 1:
                     sample = shard_time_batch(sample, mesh, time_axis=0, batch_axis=1)
+                telem.mark("train/dispatch")
                 key, train_key = jax.random.split(key)
                 sample = resilience.poison_batch(sample, global_step)  # nan.* sites
                 state, metrics = train_step(state, sample, train_key, jnp.float32(tau))
@@ -1254,6 +1309,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                 for name, val in metrics.items():
                     aggregator.update(name, val)
                 profiler.tick()
+            telem.mark(None)
             player = make_player(state)
             if use_flock:
                 telem.mark("flock/publish")
@@ -1266,6 +1322,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                     span=None if pub is None else pub.id,
                 )
                 telem.tracer.end(pub, version=version)
+                telem.mark(None)
             step_before_training = args.train_every // single_global_step
             if args.expl_decay:
                 expl_decay_steps += 1
@@ -1278,16 +1335,24 @@ def main(argv: Sequence[str] | None = None) -> None:
                 expl_dev = jnp.float32(expl_amount)
             aggregator.update("Params/exploration_amount", expl_amount)
 
-        telem.mark("log")
         sps = (global_step - start_step + 1) * args.num_envs / (
             time.perf_counter() - start_time
         )
         # deferred drain: with --pipeline on this resolves the PREVIOUS
         # interval's snapshot (its d2h copies landed during this step) and
-        # costs zero synchronous round trips; off mode computes eagerly
-        for drained, dstep in pipe.drain_metrics(aggregator, global_step):
-            logger.log_dict(telem.interval(drained, dstep, sps), dstep)
+        # costs zero synchronous round trips; off mode computes eagerly,
+        # which blocks on the iteration's last train step
+        telem.mark("log/pull", phase="log")
+        drains = pipe.drain_metrics(aggregator, global_step)
+        telem.mark("log/write", phase="log")
+        scalars = 1
+        for drained, dstep in drains:
+            merged = telem.interval(drained, dstep, sps)
+            logger.log_dict(merged, dstep)
+            scalars += len(merged)
         logger.log("Time/step_per_second", sps, global_step)
+        telem.count(scalars=scalars)
+        telem.mark(None)
 
         # ---- checkpoint ------------------------------------------------------
         if (
@@ -1297,6 +1362,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             or guard.preempted
         ):
             ckpt_path = os.path.join(log_dir, "checkpoints", f"ckpt_{global_step}")
+            telem.mark("checkpoint", phase="log")
             save_checkpoint(
                 ckpt_path,
                 {
@@ -1322,11 +1388,13 @@ def main(argv: Sequence[str] | None = None) -> None:
                 # (bit-exact buffer wire codecs, sampler PRNG included) so a
                 # restarted learner resumes with zero committed rows lost
                 service.save_sidecar(ckpt_path)
+            telem.mark(None)
 
         if guard.preempted:
             # the in-flight step finished and its grace checkpoint
             # committed: exit with the distinct resumable rc
             raise resilience.Preempted(global_step, guard.preempt_signal or "")
+    telem.iteration(None)
     for drained, dstep in pipe.flush_metrics():
         logger.log_dict(telem.interval(drained, dstep, None), dstep)
     profiler.close()

@@ -1216,6 +1216,7 @@ class AsyncReplayBuffer:
     # sheeplint: disable=SL001 — sub-cache-floor compile, never deserialized;
     # donation keeps the per-step HBM ring scatter copy-free (utils/jit.py)
     @partial(jax.jit, donate_argnums=0, static_argnums=(3, 4))
+    @jax.named_scope("replay/add")
     def _store_add_packed(store, direct, packed, layout, data_len):
         """Per-step scatter fed by ONE host->device transfer per width class
         (the write-head/env indices ride inside the packed group as
@@ -1414,6 +1415,7 @@ class AsyncReplayBuffer:
         jax.jit,
         static_argnames=("n_samples", "seq_len", "sequential", "sample_next_obs", "obs_keys"),
     )
+    @jax.named_scope("replay/sample")
     def _store_sample(
         store, key, packed_idx,
         n_samples, seq_len, sequential, sample_next_obs, obs_keys,

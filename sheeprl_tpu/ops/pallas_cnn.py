@@ -191,6 +191,7 @@ def _enc_call(x, w3, scale, offset, eps, residuals):
         ],
         out_specs=tuple(out_specs) if residuals else out_specs[0],
         interpret=_interpret_mode(),
+        name="cnn_enc_fwd_res" if residuals else "cnn_enc_fwd",
     )(*taps, w3, scale, offset)
     if residuals:
         y, pre = out
@@ -349,6 +350,7 @@ def _dec_call(x, w3, scale, offset, eps, residuals):
         ],
         out_specs=tuple(out_specs) if residuals else out_specs[0],
         interpret=_interpret_mode(),
+        name="cnn_dec_fwd_res" if residuals else "cnn_dec_fwd",
     )(*taps, w3, scale, offset)
     if residuals:
         y, pre = out

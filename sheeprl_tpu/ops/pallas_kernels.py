@@ -44,6 +44,7 @@ from ..telemetry.core import emit as _telemetry_emit
 _VMEM = pltpu.VMEM
 
 __all__ = [
+    "KERNEL_NAMES",
     "use_pallas",
     "set_pallas",
     "select",
@@ -57,6 +58,23 @@ __all__ = [
     "symlog",
     "symexp",
 ]
+
+# The names the kernels carry on the device, by family (the `kind` of
+# `use_pallas` / `select`). `pallas_call(name=)` names the HLO instruction
+# (`%gru_fwd_res.7`), and a profiler trace names the kernel's events by it, so
+# a reduction finds a family by these prefixes whatever the shapes are.
+# `<x>_fwd` is the forward as called outside differentiation (a policy step),
+# `<x>_fwd_res` the forward under differentiation, which also writes the
+# residuals its backward reads. Every family's backward is plain XLA: no
+# kernel is a `_bwd`.
+KERNEL_NAMES: dict[str, tuple[str, ...]] = {
+    "gru": ("gru_fwd", "gru_fwd_res"),
+    "rssm": ("rssm_step_fwd",),
+    "cnn": ("cnn_enc_fwd", "cnn_enc_fwd_res", "cnn_dec_fwd", "cnn_dec_fwd_res"),
+    "two_hot": ("two_hot_fwd",),
+    "sac_trunk": ("int8_trunk_fwd",),
+}
+
 
 _FORCED: bool | None = None
 _INTERPRET = False  # tests flip this to run kernels on CPU
@@ -238,6 +256,7 @@ def _gru_forward_with_residuals(x, h, w, scale, offset, eps):
             pl.BlockSpec((bn, 1), lambda i: (i, 0), memory_space=_VMEM),
         ),
         interpret=_interpret_mode(),
+        name="gru_fwd_res",
     )(x, h, w, scale, offset)
 
 
@@ -281,6 +300,7 @@ def _gru_forward(x, h, w, scale, offset, eps):
         ],
         out_specs=pl.BlockSpec((bn, hidden), lambda i: (i, 0), memory_space=_VMEM),
         interpret=_interpret_mode(),
+        name="gru_fwd",
     )(x, h, w, scale, offset)
 
 
@@ -509,6 +529,7 @@ def _fused_rssm_forward(
             pl.BlockSpec((bn, sd), lambda i: (i, 0), memory_space=_VMEM),
         ),
         interpret=_interpret_mode(),
+        name="rssm_step_fwd",
     )(
         x, h, emb, wm, sm, om, wg, sg, og,
         wt1, st1, ot1, wt2, bt2, wr1, sr1, or1, wr2, br2,
@@ -681,6 +702,7 @@ def fused_int8_trunk(x, s0, w0, ws0, b0, s1, w1, ws1, b1, sm, wm, wsm, bm):
             (bn, out_dim), lambda i: (i, 0), memory_space=_VMEM
         ),
         interpret=_interpret_mode(),
+        name="int8_trunk_fwd",
     )(x, s0, w0, ws0, b0, s1, w1, ws1, b1, sm, wm, wsm, bm)
 
 
@@ -748,6 +770,7 @@ def _two_hot_forward(x, logits, bins):
         ],
         out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0), memory_space=_VMEM),
         interpret=_interpret_mode(),
+        name="two_hot_fwd",
     )(x, logits, bins)
 
 

@@ -1,16 +1,15 @@
-"""Runtime telemetry subsystem (ISSUE 2): always-on phase timers, XLA
+"""Runtime telemetry subsystem (ISSUE 2): always-on phase timers and loop spans, XLA
 recompile/memory tracking, a NaN/inf watchdog, and a rank-0 structured JSONL
 event log with console heartbeat — shared by every algorithm main. See
 howto/observability.md for the schema and `tools/telemetry_report.py` for
 offline analysis of a finished or crashed run."""
 
 from .compile_tracker import CompileTracker, monitoring_supported
-from .core import Telemetry, active_telemetry, device_memory_gauges, emit
+from .core import Telemetry, active_telemetry, emit
 from .events import JsonlEventLog
 from .phase import PhaseTimers
 from .trace import (
     ClockSync,
-    ProfileWindow,
     Span,
     Tracer,
     ensure_run_id,
@@ -26,12 +25,10 @@ __all__ = [
     "CompileTracker",
     "JsonlEventLog",
     "PhaseTimers",
-    "ProfileWindow",
     "Span",
     "Telemetry",
     "Tracer",
     "active_telemetry",
-    "device_memory_gauges",
     "emit",
     "ensure_run_id",
     "handle_profile_frame",

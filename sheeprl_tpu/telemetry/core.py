@@ -7,11 +7,21 @@ one-line console heartbeat. A main wires it in ~3 calls:
 
     telem = Telemetry.from_args(args, log_dir, rank, algo="ppo")
     ...
+    telem.iteration(global_step)     # the loop body: the spans' parent
     telem.mark("rollout")            # or: with telem.phase("rollout"): ...
     ...
+    telem.mark("rollout/env_step", phase="rollout")  # a finer span, same sum
+    ...
+    telem.mark("log/write", phase="log")
     logger.log_dict(telem.interval(aggregator.compute(), global_step, sps), step)
+    telem.count(scalars=n)           # a counter on the open span, for a reader that is there
     ...
     telem.close()
+
+`mark` / `phase` are the only two ways a main opens a phase; each is at once
+a `Time/<phase>_seconds` sum, a `sheeprl/<phase>` annotation in any open
+profiler session, and a span kept in memory (phase.py) that `close()` and
+`abort()` write out as `span` events.
 
 `interval()` merges everything the subsystem measured since the last call
 into the metric dict (so the phase/compile/memory series ride the existing
@@ -22,10 +32,11 @@ no jit retraces — so the instrumented hot loop stays within noise of the
 uninstrumented one (bench.py --telemetry A/B + the overhead smoke test are
 the receipts).
 
-Kill switch: SHEEPRL_TPU_TELEMETRY=0 disables the subsystem (interval()
-passes metrics through untouched); non-rank-0 processes keep the timers (the
-merged dict goes to their no-op logger anyway) but never write JSONL or
-heartbeat lines.
+Kill switches: SHEEPRL_TPU_TELEMETRY=0 disables the subsystem (interval()
+passes metrics through untouched, no phase opens); SHEEPRL_TPU_TRACE=0 keeps
+the `Time/*` sums and drops the annotations and the spans. Non-rank-0
+processes keep the timers (the merged dict goes to their no-op logger
+anyway) but never write JSONL or heartbeat lines.
 """
 
 from __future__ import annotations
@@ -36,12 +47,14 @@ import os
 import sys
 import time
 import traceback
-from typing import Any, Callable, Iterator
+from contextlib import nullcontext
+from typing import Any, Callable, ContextManager
 
 from ..compile.cache import CacheStats
 from .compile_tracker import CompileTracker
 from .events import JsonlEventLog
 from .phase import PhaseTimers
+from .trace import trace_enabled
 
 __all__ = [
     "Telemetry", "emit", "active_telemetry", "device_memory_gauges", "device_report",
@@ -162,7 +175,7 @@ class Telemetry:
         self.run_id = run_id
         self.log_dir = log_dir
         self.heartbeat_s = heartbeat_s
-        self.timers = PhaseTimers()
+        self.timers = PhaseTimers(spans=enabled and trace_enabled())
         self._gauge_sources: list[Callable[[], dict[str, float]]] = []
         self._last_step: int | None = None
         self._last_heartbeat = time.monotonic()
@@ -256,12 +269,20 @@ class Telemetry:
         return telem
 
     # ---- phase timing -----------------------------------------------------
-    def phase(self, name: str) -> Iterator[None]:
-        return self.timers.phase(name)
+    def phase(self, name: str) -> ContextManager[None]:
+        return self.timers.phase(name) if self.enabled else nullcontext()
 
-    def mark(self, name: str | None) -> None:
+    def mark(self, name: str | None, phase: str | None = None) -> None:
         if self.enabled:
-            self.timers.mark(name)
+            self.timers.mark(name, phase)
+
+    def iteration(self, step: int | None) -> None:
+        if self.enabled:
+            self.timers.iteration(step)
+
+    def count(self, **counters: Any) -> None:
+        if self.enabled:
+            self.timers.count(**counters)
 
     # ---- gauges / events --------------------------------------------------
     def add_gauges(self, source: Callable[[], dict[str, float]]) -> None:
@@ -373,8 +394,16 @@ class Telemetry:
         print(" ".join(bits), file=sys.stderr)
 
     # ---- lifecycle --------------------------------------------------------
+    def _write_spans(self) -> None:
+        """The loop's spans, kept in memory until now (phase.py): every way
+        out of a run writes them, the preempted and the crashed one too."""
+        if self._log.enabled:
+            for record in self.timers.drain():
+                self.event("span", **record)
+
     def _atexit(self) -> None:
         if not self._closed:
+            self._write_spans()
             self.event(
                 "crash",
                 error=_last_exc[0] if _last_exc else "process exited without close()",
@@ -388,6 +417,7 @@ class Telemetry:
         a completed one by the missing `end`."""
         if self._closed:
             return
+        self._write_spans()
         if error is not None:
             self.event("crash", error=error, handled=True)
         try:
@@ -402,6 +432,7 @@ class Telemetry:
         """Normal end-of-run teardown: flush open phases, emit `end`."""
         if self._closed:
             return
+        self._write_spans()
         cache = self._cache.snapshot()
         self.event(
             "end", phases=self.timers.flush(),

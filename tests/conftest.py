@@ -84,3 +84,25 @@ def _enforce_timeout_marker(request):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
+
+
+@pytest.fixture(autouse=True)
+def _no_fault_plan_outlives_its_test():
+    """`resilience.arm_faults` (and a main's `--faults`) exports the plan to
+    the environment so that spawned env workers inherit it. A test that arms
+    one used to leave it there — `monkeypatch.delenv(..., raising=False)` of
+    a name that is unset records nothing to undo, and `reset_plan()` drops
+    the parsed plan but not the variable — so the next in-process `main` of
+    the same xdist worker re-armed it (`prepare_run` -> `arm_faults(None)`)
+    and met an `env.step@3`, a `sigterm@3` or a `nan.grad@2` nobody asked
+    for. Whatever a test does to the variable ends with the test."""
+    from sheeprl_tpu.resilience import inject
+
+    before = os.environ.get(inject.ENV_VAR)
+    yield
+    if os.environ.get(inject.ENV_VAR) != before:
+        if before is None:
+            del os.environ[inject.ENV_VAR]
+        else:
+            os.environ[inject.ENV_VAR] = before
+        inject.reset_plan()

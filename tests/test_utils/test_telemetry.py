@@ -75,6 +75,172 @@ def test_mark_sections_accumulate_and_flush_restarts_open_phase():
 
 
 # ---------------------------------------------------------------------------
+# spans: the same transitions, kept in memory and written when the run ends
+# ---------------------------------------------------------------------------
+
+
+def _spans(path):
+    with open(path) as f:
+        events = [json.loads(line) for line in f]
+    return [e for e in events if e["event"] == "span"], events
+
+
+def test_spans_nest_under_the_phase_the_mark_and_the_iteration():
+    t = PhaseTimers()
+    t.iteration(7)
+    t.mark("rollout/pack")
+    with t.phase("outer"):
+        with t.phase("inner"):
+            pass
+    t.mark("log/write")  # a mark after a mark closes the previous span
+    t.count(scalars=85)
+    t.count(flushed=True)
+    t.iteration(8)  # ... and so does the next iteration, which closes 7 too
+    t.mark("rollout/pack")
+    by = {(s["name"], s["step"]): s for s in t.drain()}  # drain closes what is open
+    it7, it8 = by["iteration", 7], by["iteration", 8]
+    assert it7["parent"] is None and it8["parent"] is None and it7["span"] != it8["span"]
+    assert by["rollout/pack", 7]["parent"] == it7["span"]
+    assert by["outer", 7]["parent"] == by["rollout/pack", 7]["span"]
+    assert by["outer/inner", 7]["parent"] == by["outer", 7]["span"]
+    assert by["log/write", 7]["parent"] == it7["span"]
+    assert by["log/write", 7]["scalars"] == 85 and by["log/write", 7]["flushed"] is True
+    assert by["rollout/pack", 8]["parent"] == it8["span"]
+    # linear marks tile their parent: one ends where the next starts
+    assert by["rollout/pack", 7]["t1"] == by["log/write", 7]["t0"]
+    assert by["log/write", 7]["t1"] == it7["t1"] == it8["t0"]
+    assert {"name", "span", "parent", "t0", "t1", "dur_ms", "step", "p0"} <= set(it7)
+    assert list(t.drain()) == []  # the ring is handed out once
+
+
+def test_children_and_self_time_make_up_the_parent():
+    t = PhaseTimers()
+    t.iteration(0)
+    time.sleep(0.001)  # self time: no child covers it
+    t.mark("a")
+    time.sleep(0.001)
+    t.mark("b")
+    time.sleep(0.001)
+    t.mark(None)
+    time.sleep(0.001)
+    spans = list(t.drain())
+    parent = next(s for s in spans if s["name"] == "iteration")
+    children = [s for s in spans if s["parent"] == parent["span"]]
+    assert [c["name"] for c in children] == ["a", "b"]
+    assert all(parent["p0"] <= c["p0"] and c["t1"] <= parent["t1"] for c in children)
+    self_ms = parent["dur_ms"] - sum(c["dur_ms"] for c in children)
+    assert 1.5 < self_ms < parent["dur_ms"] - 1.5  # the two uncovered sleeps, nothing else
+    # the iteration is a span, not a phase: it has no Time/* sum of its own
+    assert set(t.flush()) == {"a", "b"}
+
+
+def test_a_finer_span_is_logged_under_the_sum_its_mark_names(tmp_path):
+    """A logged scalar costs the loop time, a span costs none: the mains keep
+    their few `Time/*` sums and open finer spans under them."""
+    telem = Telemetry(str(tmp_path), rank=0, algo="unit")
+    telem.iteration(1)
+    telem.mark("rollout/pack", phase="rollout")
+    time.sleep(0.001)
+    telem.mark("rollout/env_step", phase="rollout")
+    time.sleep(0.001)
+    telem.mark("buffer/sample")
+    telem.mark(None)
+    merged = telem.interval({"Loss/x": 1.0}, step=1)
+    telem.close()
+    assert {k for k in merged if k.startswith("Time/")} == {"Time/rollout_seconds", "Time/buffer/sample_seconds"}
+    assert merged["Time/rollout_seconds"] >= 0.002
+    spans, _ = _spans(tmp_path / "telemetry.jsonl")
+    assert [s["name"] for s in spans] == ["rollout/pack", "rollout/env_step", "buffer/sample", "iteration"]
+
+
+def test_the_ring_keeps_the_newest_spans_and_drops_the_oldest():
+    t = PhaseTimers(ring=4)
+    for i in range(10):
+        t.mark(f"m{i}")
+    t.mark(None)
+    assert [s["name"] for s in t.drain()] == ["m6", "m7", "m8", "m9"]
+
+
+@pytest.mark.parametrize("way_out", ["close", "abort", "abort_with_error"])
+def test_every_way_out_of_a_run_writes_the_spans(tmp_path, way_out):
+    telem = Telemetry(str(tmp_path), rank=0, algo="unit")
+    telem.iteration(3)
+    telem.mark("rollout/env_step")
+    telem.count(scalars=4)
+    telem.mark(None)
+    path = tmp_path / "telemetry.jsonl"
+    assert _spans(path)[0] == []  # nothing is written per span inside the loop
+    if way_out == "close":
+        telem.close()
+    else:
+        telem.abort("RuntimeError: boom" if way_out == "abort_with_error" else None)
+    spans, events = _spans(path)
+    assert [(s["name"], s["step"]) for s in spans] == [("rollout/env_step", 3), ("iteration", 3)]
+    assert spans[0]["scalars"] == 4 and spans[0]["parent"] == spans[1]["span"]
+    # a post-mortem still tells the ways out apart, and the spans come before the verdict
+    assert (events[-1]["event"] == "end") == (way_out == "close")
+    assert ("crash" in [e["event"] for e in events]) == (way_out == "abort_with_error")
+
+
+@pytest.mark.parametrize("switch", ["SHEEPRL_TPU_TELEMETRY", "SHEEPRL_TPU_TRACE"])
+def test_the_kill_switches_govern_the_spans(tmp_path, monkeypatch, switch):
+    monkeypatch.setenv(switch, "0")
+    enabled = switch != "SHEEPRL_TPU_TELEMETRY"  # what `from_args` reads
+    telem = Telemetry(str(tmp_path), rank=0, algo="unit", enabled=enabled)
+    telem.iteration(1)
+    telem.mark("rollout")
+    with telem.phase("inner"):  # phase() respects `enabled` as mark() does
+        time.sleep(0.001)
+    telem.count(n=1)
+    merged = telem.interval({"Loss/x": 1.0}, step=1)
+    telem.close()
+    assert not telem.timers.ring
+    path = tmp_path / "telemetry.jsonl"
+    if enabled:  # tracing off: the Time/* sums stay, no span is kept or written
+        assert merged["Time/inner_seconds"] > 0.0
+        assert _spans(path)[0] == []
+    else:  # telemetry off: no phase opens at all, no file
+        assert merged == {"Loss/x": 1.0} and telem.timers.flush() == {}
+        assert not path.exists()
+
+
+def test_phases_lie_in_the_profilers_host_plane_on_its_clock(tmp_path):
+    """In any open `jax.profiler` session the program's phases are
+    `sheeprl/<phase>` events of the host plane, the iteration a step event
+    that carries its step; outside a session nothing is annotated."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    t = PhaseTimers()
+    t.iteration(1)  # no session open: a flag test, no annotation
+    t.mark("before")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        t.iteration(7)
+        t.mark("rollout/pack")
+        with t.phase("inner"):
+            time.sleep(0.001)
+        t.mark("log/write")
+        t.iteration(None)
+    finally:
+        jax.profiler.stop_trace()
+    (trace,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    host = [p for p in ProfileData.from_file(trace).planes if p.name == "/host:CPU"]
+    events = {e.name: e for p in host for line in p.lines for e in line.events if e.name.startswith("sheeprl/")}
+    assert set(events) == {"sheeprl/iteration", "sheeprl/rollout/pack", "sheeprl/inner", "sheeprl/log/write"}
+    assert dict(events["sheeprl/iteration"].stats)["step_num"] == 7
+    it, inner = events["sheeprl/iteration"], events["sheeprl/inner"]
+    assert it.start_ns <= inner.start_ns and inner.start_ns + inner.duration_ns <= it.start_ns + it.duration_ns
+    assert inner.duration_ns >= 1e6
+    # the annotations change nothing about what the ring keeps
+    assert [s["name"] for s in t.drain()] == ["before", "iteration", "inner", "rollout/pack", "log/write", "iteration"]
+
+
+# ---------------------------------------------------------------------------
 # compile tracker
 # ---------------------------------------------------------------------------
 

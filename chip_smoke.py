@@ -15,6 +15,10 @@ children's own records (`telemetry*.jsonl`), never from its own beliefs:
                            64x64x3 pixels: >= 3 train steps after the compile
                            steps, every loss finite
   phase B  trainer, bf16   the same under `--precision bfloat16`
+  phase R  replay ring     the ring's add and sample, compiled by the chip's
+                           own compiler at the benchmark cells' shapes
+                           (3.9 GiB of pixels, nothing allocated): no
+                           ring-sized copy, temporaries under 1 % of the ring
   phase C  server          `python -m sheeprl_tpu serve --algo sac` at the
                            default SAC widths answers sequential, multi-row
                            and concurrent requests from a `ServeClient` here;
@@ -249,11 +253,57 @@ def phase_trainer(name: str, extra: list[str], device: dict) -> dict:
         say(f"  replay transport: {t['transport']} ({t['reason']})")
         check(t["transport"] == "blob",
               f"{name}: step-blob bitcast roundtrip failed on the chip: {t['reason']}")
+    stores = [e for e in events if e["event"] == "replay.store"]
+    if check(len(stores) == 1, f"{name}: {len(stores)} replay.store events, expected one (at allocation)"):
+        keys = stores[0]["keys"]
+        say("  replay store: " + "; ".join(
+            f"{k} {v['dtype']}{v['logical']} as {v['storage']} {v['bytes'] / 2**20:.1f} MiB ({v['format']})"
+            for k, v in sorted(keys.items())))
+        check(keys.get("rgb", {}).get("format") == "lane_dense",
+              f"{name}: the pixel ring is not stored lane-dense: {keys.get('rgb')}")
     broken = [e for e in events if e.get("errors")]
     check(not broken, f"{name}: measured decisions with failed candidates {broken[:1]}")
     cache = report_cache(events, name)
     say(f"  wall {secs:.0f}s")
     return {"cache": cache}
+
+
+# --------------------------------------------------------------------------- phase R
+
+# the benchmark's two cells: ring rows, environments, n_samples (B=16 x T=64)
+RING_CELLS = ((86016, 4, 4), (21504, 16, 1))
+RING_ITEMS = {
+    "rgb": ((64, 64, 3), "uint8"), "actions": ((18,), "float32"), "rewards": ((1,), "float32"),
+    "dones": ((1,), "float32"), "is_first": ((1,), "float32"),
+}
+
+
+def phase_replay_programs() -> None:
+    """One child compiles the ring's two programs on the chip, from shapes,
+    and reports what `sheeprl_tpu/data/store_check.py` reads off them."""
+    say("== phase R: the replay ring's compiled add and sample")
+    code = (
+        "import json; from sheeprl_tpu.data import store_check\n"
+        f"for rows, envs, n in {RING_CELLS!r}:\n"
+        f"    rep = store_check.report(rows, envs, {RING_ITEMS!r}, batch=16, seq_len=64, n_samples=n)\n"
+        "    rep['faults'] = store_check.faults(rep)\n"
+        "    print('REPLAY_PROGRAMS ' + json.dumps({'rows': rows, 'envs': envs, **rep}))\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, env=child_env(),
+        capture_output=True, text=True, timeout=300,
+    )
+    reports = [json.loads(l[len("REPLAY_PROGRAMS "):]) for l in proc.stdout.splitlines()
+               if l.startswith("REPLAY_PROGRAMS ")]
+    if not check(proc.returncode == 0 and len(reports) == len(RING_CELLS),
+                 f"replay programs: child rc={proc.returncode}, {len(reports)} reports\n{proc.stderr[-2000:]}"):
+        return
+    for rep in reports:
+        what = f"ring {rep['rows']} x {rep['envs']}"
+        say(f"  {what}: {rep['store_bytes'] / 2**30:.2f} GiB, rgb {rep['formats']['rgb']}; "
+            f"add temp {rep['add']['temp_bytes']} B, aliased {rep['add']['alias_bytes'] / 2**30:.2f} GiB; "
+            f"sample temp {rep['sample']['temp_bytes']} B")
+        check(not rep["faults"], f"replay programs, {what}: {rep['faults']}")
 
 
 # --------------------------------------------------------------------------- phase C
@@ -414,6 +464,7 @@ def main() -> None:
     try:
         results["dv3_f32"] = phase_trainer("dv3_f32", [], device)
         results["dv3_bf16"] = phase_trainer("dv3_bf16", ["--precision", "bfloat16"], device)
+        phase_replay_programs()
         results["serve_f32"] = phase_server("serve_f32", [], device, None)
         results["serve_int8"] = phase_server(
             "serve_int8", ["--quant", "int8"], device, results["serve_f32"].get("reference"),

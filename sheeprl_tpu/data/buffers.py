@@ -9,6 +9,14 @@ Re-designs the reference's four TensorDict buffer semantics
     trips; `sample` is a jitted gather whose random indices are drawn with
     `jax.random` *on device*. Under a mesh the ring can be sharded on the
     env axis, making sampling a local gather + no collective.
+    "In place" is the compiler's to grant, not the donation's: the TPU lays
+    a `u8[rows, envs, 64, 64, 3]` ring out with the *row* axis in the lanes,
+    and the scatter and gather over `(row, env)` then each copy the whole
+    ring into a row-major layout first (and the scatter back). So
+    `AsyncReplayBuffer` keeps an item of several axes that fills whole
+    lanes with its axes folded into one (`_storage_item`), where both run
+    on the ring as it lies; `data/store_check.py` holds the compiled
+    programs to that.
   - **host**: numpy (optionally `np.memmap`) ring with identical index
     semantics, for capacities that exceed HBM (the reference's
     `memmap_buffer=True` pixel-Dreamer case); samples are assembled on host
@@ -1004,6 +1012,30 @@ class EpisodeBuffer:
         return buf
 
 
+_LANES = 128  # elements in one row of a TPU tile, for every dtype
+
+
+def _storage_item(item: tuple[int, ...], dtype) -> tuple[tuple[int, ...], str]:
+    """On-device storage shape of one ring item, and why: decided from the
+    array alone. An item of several axes whose elements fill whole lanes is
+    kept **lane-dense**, its axes folded into one (`u8[64,64,3]` as
+    `u8[12288]`, 96 lanes): a ring row is then whole tile rows, and the
+    row scatter and row gather over `(row, env)` run in place on the layout
+    the ring already has. With the item's own axes kept, the TPU's default
+    layout puts the ring's *row* axis in the lanes, and both index
+    operations first relayout the whole ring to reach a row (PERF.md,
+    PR 27). Every other item (a vector, a scalar, a frame that would leave a
+    lane part empty: folding that one does not change its layout) is stored
+    as is."""
+    n = int(np.prod(item, dtype=np.int64))
+    nbytes = n * np.dtype(dtype).itemsize
+    if len(item) < 2:
+        return item, f"as_is: item_bytes={nbytes}, one axis"
+    if n % _LANES:
+        return item, f"as_is: item_bytes={nbytes}, {n} elements fill no whole lanes"
+    return (n,), "lane_dense"
+
+
 class _AsyncEnvView:
     """Single-env handle into the unified device store of an
     `AsyncReplayBuffer`, exposing the slice of the `ReplayBuffer` surface the
@@ -1031,10 +1063,13 @@ class _AsyncEnvView:
     @property
     def buffer(self):
         self._parent._flush_staged()
-        store = self._parent._store
-        if store is None:
+        parent = self._parent
+        if parent._store is None:
             return None
-        return {k: v[:, self._env : self._env + 1] for k, v in store.items()}
+        return {
+            k: parent._logical(k, v[:, self._env : self._env + 1])
+            for k, v in parent._store.items()
+        }
 
     def set_at(self, key: str, time_idx: int, value) -> None:
         self._parent._set_at(self._env, key, time_idx, value)
@@ -1046,14 +1081,21 @@ class AsyncReplayBuffer:
     (reference buffers.py:537-699).
 
     Storage backends:
-      - **device**: ONE unified HBM store `[capacity, n_envs, *item]` with a
-        per-env write-head vector. `add` is a single jitted scatter at
-        `(rows, env_cols)` and `sample` a single jitted gather for the whole
-        batch — one dispatch each, instead of the n_envs-fan-out a
-        buffer-per-env design pays (which dominates the end-to-end step time
-        when host<->device latency is non-trivial). Per-env independence is
-        index arithmetic: each env column has its own position/full state and
-        sampling validity window.
+      - **device**: ONE unified HBM store with a per-env write-head vector,
+        logically `[capacity, n_envs, *item]` per key. `add` is a single
+        jitted scatter at `(rows, env_cols)` and `sample` a single jitted
+        gather for the whole batch — one dispatch each, instead of the
+        n_envs-fan-out a buffer-per-env design pays (which dominates the
+        end-to-end step time when host<->device latency is non-trivial).
+        Per-env independence is index arithmetic: each env column has its
+        own position/full state and sampling validity window.
+        A key's *storage* shape is decided at allocation from its dtype and
+        item shape alone (`_storage_item`, recorded as the `replay.store`
+        telemetry event): pixels are kept lane-dense, `[capacity, n_envs,
+        prod(item)]`, so that both programs touch only the rows they write
+        and read; vectors and scalars are stored as they arrive. The
+        logical shape is what every method takes and returns, and what all
+        four checkpoint forms hold.
       - **host**/memmap: one numpy `ReplayBuffer` per env (adds are cheap on
         host; capacities beyond HBM).
     """
@@ -1089,6 +1131,8 @@ class AsyncReplayBuffer:
         self._buf: list[ReplayBuffer] | None = None
         # device path: unified store + per-env head state
         self._store: dict[str, jax.Array] | None = None
+        # logical item shape of every key the store keeps in another shape
+        self._items: dict[str, tuple[int, ...]] = {}
         self._upos = np.zeros(n_envs, dtype=np.int64)
         self._ufull = np.zeros(n_envs, dtype=bool)
         self._epoch = 0
@@ -1200,21 +1244,56 @@ class AsyncReplayBuffer:
         ]
 
     # -- device path: unified store ------------------------------------------
+    def _plan_store(self, data: Mapping[str, "np.ndarray | jax.Array"]) -> dict:
+        """Decide each key's on-device storage shape from what its
+        `[T, n_envs, *item]` array itself shows (`_storage_item`), remember
+        the logical item shape of every key stored otherwise, and record
+        the decision once (`replay.store` telemetry event, beside
+        `replay.transport`). Returns `key -> storage item shape`."""
+        from ..telemetry.core import emit
+
+        lead = (self._buffer_size, self._n_envs)
+        plan, report = {}, {}
+        self._items = {}
+        for k, v in data.items():
+            item, dtype = tuple(v.shape[2:]), np.dtype(v.dtype)
+            plan[k], why = _storage_item(item, dtype)
+            if plan[k] != item:
+                self._items[k] = item
+            report[k] = {
+                "logical": [*lead, *item],
+                "storage": [*lead, *plan[k]],
+                "dtype": dtype.name,
+                "bytes": int(np.prod(lead + plan[k], dtype=np.int64)) * dtype.itemsize,
+                "format": why,
+            }
+        emit("replay.store", keys=report)
+        return plan
+
     def _allocate_store(self, data: Batch) -> None:
+        plan = self._plan_store(data)
         self._store = {
             k: jnp.zeros(
-                (self._buffer_size, self._n_envs, *v.shape[2:]), dtype=v.dtype
+                (self._buffer_size, self._n_envs, *plan[k]), dtype=v.dtype
             )
             for k, v in data.items()
         }
+
+    def _logical(self, key: str, stored):
+        """A `[rows, envs, *storage item]` block of `key` (device or host)
+        in the buffer's logical `[rows, envs, *item]` shape."""
+        item = self._items.get(key)
+        return stored if item is None else stored.reshape(*stored.shape[:2], *item)
 
     def _next_key(self) -> jax.Array:
         self._key, sub = jax.random.split(self._key)
         return sub
 
     @staticmethod
-    # sheeplint: disable=SL001 — sub-cache-floor compile, never deserialized;
-    # donation keeps the per-step HBM ring scatter copy-free (utils/jit.py)
+    # sheeplint: disable=SL001 — sub-cache-floor compile, never deserialized.
+    # Donation lets the output take the ring's buffer; that the scatter then
+    # writes its rows and copies nothing else is the storage shape's doing
+    # (`_storage_item`), and `store_check.py` reads it off the compiled text
     @partial(jax.jit, donate_argnums=0, static_argnums=(3, 4))
     @jax.named_scope("replay/add")
     def _store_add_packed(store, direct, packed, layout, data_len):
@@ -1234,10 +1313,14 @@ class AsyncReplayBuffer:
         n_sel = idx.shape[0] // 2
         starts, cols = idx[:n_sel], idx[n_sel:]
         rows = (starts[None, :] + jnp.arange(data_len)[:, None]) % capacity
-        return {
-            k: store[k].at[rows, cols[None, :]].set(data[k].astype(store[k].dtype))
-            for k in store
-        }
+
+        def put(ring, value):
+            # the value in the key's storage shape: a no-op for a key stored
+            # as is, the item axes folded into one for a lane-dense key
+            value = value.reshape(*value.shape[:2], *ring.shape[2:])
+            return ring.at[rows, cols[None, :]].set(value.astype(ring.dtype))
+
+        return {k: put(store[k], data[k]) for k in store}
 
     def _flush_staged(self) -> None:
         """Write all staged full-width rows with one scatter. Bookkeeping
@@ -1413,12 +1496,14 @@ class AsyncReplayBuffer:
     @staticmethod
     @partial(
         jax.jit,
-        static_argnames=("n_samples", "seq_len", "sequential", "sample_next_obs", "obs_keys"),
+        static_argnames=(
+            "n_samples", "seq_len", "sequential", "sample_next_obs", "obs_keys", "items",
+        ),
     )
     @jax.named_scope("replay/sample")
     def _store_sample(
         store, key, packed_idx,
-        n_samples, seq_len, sequential, sample_next_obs, obs_keys,
+        n_samples, seq_len, sequential, sample_next_obs, obs_keys, items=(),
     ):
         """One gather for the whole batch: each output row draws a start
         index inside its env's validity window, windows index the ring
@@ -1444,20 +1529,23 @@ class AsyncReplayBuffer:
         start = jnp.where(r < f, r, r - f + p)
         idx = (start[:, None] + jnp.arange(seq_len)) % capacity  # [BD, L]
         ecol = env_idx[:, None]
+        logical = dict(items)
 
-        def gather(v, ix):
-            g = v[ix, ecol]  # [BD, L, *item]
+        def gather(k, ix):
+            g = store[k][ix, ecol]  # [BD, L, *storage item]
+            if k in logical:
+                g = g.reshape(*g.shape[:2], *logical[k])  # [BD, L, *item]
             if not sequential:
                 return g[:, 0]
             batch = bd // n_samples
             g = g.reshape(n_samples, batch, seq_len, *g.shape[2:])
             return jnp.swapaxes(g, 1, 2)  # [n_samples, L, B, *item]
 
-        out = {k: gather(v, idx) for k, v in store.items()}
+        out = {k: gather(k, idx) for k in store}
         if sample_next_obs:
             nxt = (idx + 1) % capacity
             for k in obs_keys:
-                out[f"next_{k}"] = gather(store[k], nxt)
+                out[f"next_{k}"] = gather(k, nxt)
         return out
 
     def sample(
@@ -1512,6 +1600,7 @@ class AsyncReplayBuffer:
             self._sequential,
             sample_next_obs,
             self._obs_keys if sample_next_obs else (),
+            tuple(self._items.items()),
         )
 
     def _sample_host(
@@ -1551,7 +1640,7 @@ class AsyncReplayBuffer:
                     "buffer_size": self._buffer_size, "n_envs": 1,
                 }
                 return {"buffers": [dict(empty) for _ in range(self._n_envs)]}
-            host = {k: np.asarray(v) for k, v in self._store.items()}
+            host = {k: self._logical(k, np.asarray(v)) for k, v in self._store.items()}
             return {
                 "buffers": [
                     {
@@ -1595,6 +1684,7 @@ class AsyncReplayBuffer:
                 # envs that never received data (buf=None) contribute a zero
                 # column; their pos/full restore as 0/False below
                 template = next(s["buf"] for s in buffers if s["buf"] is not None)
+                plan = self._plan_store(template)
                 self._store = {
                     k: jnp.asarray(
                         np.concatenate(
@@ -1605,7 +1695,7 @@ class AsyncReplayBuffer:
                                 for s in buffers
                             ],
                             axis=1,
-                        )
+                        ).reshape(self._buffer_size, self._n_envs, *plan[k])
                     )
                     for k in template.keys()
                 }

@@ -500,3 +500,359 @@ class TestPackedDeviceAdds:
         assert not dev.prefers_host_adds
         assert host.prefers_host_adds
         assert staged.prefers_host_adds
+
+
+# ---------------------------------------------------------------------------
+# PR 27: the device store against a plain numpy ring, bit for bit. The store
+# keeps an item of several axes that fills whole lanes (`u8[64,64,3]`) with
+# its axes folded into one, every other item as it arrives; nothing of that
+# may show through add / sample / row surgery / any checkpoint form.
+# ---------------------------------------------------------------------------
+
+RING_ITEMS = {
+    "u8_64x64x3": ((64, 64, 3), np.uint8),   # lane-dense: 12288 = 96 x 128
+    "u8_5x7x3": ((5, 7, 3), np.uint8),       # 105 elements fill no lane
+    "f32_18": ((18,), np.float32),
+    "f32_1": ((1,), np.float32),
+}
+RING_ENVS = [1, 4, 16]
+CAP = 12
+
+
+def _stamped(rng, item, dtype, t, envs, serial):
+    """`[t, len(envs), *item]` of random bits whose first element counts the
+    rows written (mod 251), so a misplaced row cannot pass by chance."""
+    shape = (t, len(envs), *item)
+    if dtype == np.uint8:
+        v = rng.integers(0, 256, shape, dtype=np.uint8)
+    else:
+        v = rng.normal(size=shape).astype(dtype)
+    flat = v.reshape(t, len(envs), -1)
+    flat[:, :, 0] = ((serial + np.arange(t))[:, None] * 7 + np.asarray(envs)[None, :]) % 251
+    return v
+
+
+class _NumpyRing:
+    """The semantics of `AsyncReplayBuffer`, written out: one ring per env,
+    each with its own head."""
+
+    def __init__(self, capacity, n_envs):
+        self.cap, self.n_envs = capacity, n_envs
+        self.buf = None
+        self.pos = np.zeros(n_envs, np.int64)
+        self.full = np.zeros(n_envs, bool)
+
+    def add(self, data, envs=None):
+        envs = range(self.n_envs) if envs is None else envs
+        if self.buf is None:
+            self.buf = {
+                k: np.zeros((self.cap, self.n_envs, *v.shape[2:]), v.dtype) for k, v in data.items()
+            }
+        for col, e in enumerate(envs):
+            for t in range(next(iter(data.values())).shape[0]):
+                for k, v in data.items():
+                    self.buf[k][self.pos[e], e] = v[t, col]
+                self.pos[e] += 1
+                if self.pos[e] == self.cap:
+                    self.pos[e], self.full[e] = 0, True
+
+    def starts(self, draws, env_idx, exclude):
+        """The window start of each output row from its uniform draw `r` in
+        `[0, n_valid)`: valid starts are those whose window of `exclude + 1`
+        rows does not cross the env's write head."""
+        out = []
+        for r, e in zip(draws, env_idx):
+            first = max(self.pos[e] - exclude, 0)
+            out.append(r if r < first else r - first + self.pos[e])
+        return np.asarray(out)
+
+    def n_valid(self, exclude):
+        first = self.pos - exclude
+        second_end = np.where(first >= 0, self.cap, self.cap + first)
+        return np.where(self.full, np.maximum(first, 0) + second_end - self.pos, first)
+
+
+def _ring_of(rb):
+    st = rb.to_state_dict()["buffers"]
+    return {k: np.concatenate([s["buf"][k] for s in st], axis=1) for k in st[0]["buf"]}
+
+
+def _assert_same_ring(rb, ref):
+    got = _ring_of(rb)
+    assert set(got) == set(ref.buf)
+    for k in got:
+        assert got[k].dtype == ref.buf[k].dtype and got[k].shape == ref.buf[k].shape
+        np.testing.assert_array_equal(got[k].view(np.uint8), ref.buf[k].view(np.uint8), err_msg=k)
+    assert [b.pos for b in rb.buffer] == ref.pos.tolist()
+    assert list(rb.full) == ref.full.tolist()
+
+
+def _filled(item_name, n_envs, sequential=True, seed=3):
+    """A device buffer and its numpy twin after full-width adds past the
+    wrap and reset rows for a subset of envs (heads no longer equal)."""
+    item, dtype = RING_ITEMS[item_name]
+    rng = np.random.default_rng(seed)
+    rb = AsyncReplayBuffer(CAP, n_envs=n_envs, storage="device", sequential=sequential,
+                           obs_keys=("obs",), seed=seed)
+    ref = _NumpyRing(CAP, n_envs)
+    serial = 0
+    for t in (5, 1, 9):  # 15 rows: wraps a ring of 12
+        data = {"obs": _stamped(rng, item, dtype, t, range(n_envs), serial),
+                "rewards": _stamped(rng, (1,), np.float32, t, range(n_envs), serial)}
+        rb.add(data)
+        ref.add(data)
+        serial += t
+    subset = sorted({0, n_envs - 1, n_envs // 2})[: max(1, n_envs // 2)]
+    data = {"obs": _stamped(rng, item, dtype, 2, subset, serial),
+            "rewards": _stamped(rng, (1,), np.float32, 2, subset, serial)}
+    rb.add(data, indices=subset)
+    ref.add(data, subset)
+    return rb, ref, rng, serial + 2
+
+
+def ring_cases(f):
+    f = pytest.mark.parametrize("item_name", list(RING_ITEMS))(f)
+    return pytest.mark.parametrize("n_envs", RING_ENVS)(f)
+
+
+class TestDeviceStoreAgainstNumpyRing:
+    @ring_cases
+    def test_add_and_reset_rows(self, item_name, n_envs):
+        rb, ref, _, _ = _filled(item_name, n_envs)
+        _assert_same_ring(rb, ref)
+        # the per-env view shows the env's own column in the logical shape
+        item, _ = RING_ITEMS[item_name]
+        col = rb.buffer[n_envs - 1].buffer["obs"]
+        assert col.shape == (CAP, 1, *item)
+        np.testing.assert_array_equal(np.asarray(col)[:, 0], ref.buf["obs"][:, n_envs - 1])
+
+    @ring_cases
+    @pytest.mark.parametrize("data_len", [1, 8])
+    def test_add_direct(self, item_name, n_envs, data_len):
+        item, dtype = RING_ITEMS[item_name]
+        rng = np.random.default_rng(data_len)
+        rb = AsyncReplayBuffer(CAP, n_envs=n_envs, storage="device", sequential=True, obs_keys=("obs",))
+        ref = _NumpyRing(CAP, n_envs)
+        for i in range(3 if data_len == 8 else 14):  # both cross the ring's end
+            data = {"obs": _stamped(rng, item, dtype, data_len, range(n_envs), i * data_len),
+                    "rewards": _stamped(rng, (1,), np.float32, data_len, range(n_envs), i)}
+            idx = rb.reserve(data_len)
+            rb.add_direct({k: jnp.asarray(v) for k, v in data.items()}, jnp.asarray(idx), data_len)
+            ref.add(data)
+        _assert_same_ring(rb, ref)
+
+    @ring_cases
+    def test_set_at(self, item_name, n_envs):
+        item, dtype = RING_ITEMS[item_name]
+        rb, ref, rng, _ = _filled(item_name, n_envs)
+        env = n_envs - 1
+        value = _stamped(rng, item, dtype, 1, [env], 99)[0, 0]
+        rb.buffer[env].set_at("obs", 7, value)
+        ref.buf["obs"][7, env] = value
+        _assert_same_ring(rb, ref)
+
+    @ring_cases
+    @pytest.mark.parametrize("n_samples", [1, 4])
+    def test_sequential_sample_across_the_wrap(self, item_name, n_envs, n_samples):
+        import jax
+
+        rb, ref, _, _ = _filled(item_name, n_envs)
+        batch, seq_len = 16, 5
+        _, sub = jax.random.split(rb.get_sample_state()[0])
+        env_idx = np.tile(np.repeat(np.arange(n_envs), batch // n_envs), n_samples)
+        n_valid = ref.n_valid(seq_len - 1)
+        draws = np.asarray(jax.random.randint(sub, (env_idx.size,), 0, np.maximum(n_valid[env_idx], 1)))
+        starts = ref.starts(draws, env_idx, seq_len - 1)
+        rows = (starts[:, None] + np.arange(seq_len)) % CAP  # [n_samples*batch, L]
+        assert (rows[:, 1:] < rows[:, :-1]).any(), "no window wrapped: the case tests nothing"
+        out = rb.sample(batch, sequence_length=seq_len, n_samples=n_samples)
+        for k in ("obs", "rewards"):
+            want = ref.buf[k][rows, env_idx[:, None]]  # [n_samples*batch, L, *item]
+            want = want.reshape(n_samples, batch, seq_len, *want.shape[2:]).swapaxes(1, 2)
+            got = np.asarray(out[k])
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8), err_msg=k)
+
+    @ring_cases
+    def test_sample_next_obs(self, item_name, n_envs):
+        import jax
+
+        rb, ref, _, _ = _filled(item_name, n_envs, sequential=False)
+        batch = 16
+        _, sub = jax.random.split(rb.get_sample_state()[0])
+        env_idx = np.repeat(np.arange(n_envs), batch // n_envs)
+        n_valid = ref.n_valid(1)
+        draws = np.asarray(jax.random.randint(sub, (batch,), 0, np.maximum(n_valid[env_idx], 1)))
+        rows = ref.starts(draws, env_idx, 1)
+        out = rb.sample(batch, sample_next_obs=True)
+        assert set(out) == {"obs", "rewards", "next_obs"}
+        np.testing.assert_array_equal(np.asarray(out["obs"]), ref.buf["obs"][rows, env_idx])
+        np.testing.assert_array_equal(np.asarray(out["next_obs"]), ref.buf["obs"][(rows + 1) % CAP, env_idx])
+        np.testing.assert_array_equal(np.asarray(out["rewards"]), ref.buf["rewards"][rows, env_idx])
+
+    @ring_cases
+    @pytest.mark.parametrize("form", ["state_dict", "npz", "bytes"])
+    def test_checkpoint_round_trip(self, item_name, n_envs, form, tmp_path):
+        rb, ref, rng, serial = _filled(item_name, n_envs)
+        if form == "state_dict":
+            back = AsyncReplayBuffer(CAP, n_envs=n_envs, storage="device", sequential=True, obs_keys=("obs",))
+            back.load_state_dict(rb.to_state_dict())
+            back.set_sample_state(rb.get_sample_state())
+        elif form == "npz":
+            rb.save(str(tmp_path / "ring.npz"))
+            back = AsyncReplayBuffer(CAP, n_envs=n_envs, storage="device", sequential=True, obs_keys=("obs",))
+            back.load(str(tmp_path / "ring.npz"))
+        else:
+            back = AsyncReplayBuffer.from_bytes(rb.to_bytes(), storage="device")
+        _assert_same_ring(back, ref)
+        # and it goes on as the original does: same windows, then the same adds
+        a = rb.sample(16, sequence_length=4, n_samples=2)
+        b = back.sample(16, sequence_length=4, n_samples=2)
+        for k in a:
+            np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]), err_msg=k)
+        item, dtype = RING_ITEMS[item_name]
+        data = {"obs": _stamped(rng, item, dtype, 3, range(n_envs), serial),
+                "rewards": _stamped(rng, (1,), np.float32, 3, range(n_envs), serial)}
+        back.add(data)
+        ref.add(data)
+        _assert_same_ring(back, ref)
+
+    @pytest.mark.parametrize("item_name", list(RING_ITEMS))
+    def test_the_parents_on_disk_layout_loads_and_is_what_is_saved(self, item_name, tmp_path):
+        """A checkpoint as the parent commit wrote it, built here by hand from
+        the layout `to_state_dict` documents: per env `b<i>_pos`, `b<i>_full`
+        and one `[capacity, 1, *item]` array per key."""
+        n_envs = 4
+        item, dtype = RING_ITEMS[item_name]
+        rng = np.random.default_rng(1)
+        ref = _NumpyRing(CAP, n_envs)
+        ref.add({"obs": _stamped(rng, item, dtype, 17, range(n_envs), 0),
+                 "rewards": _stamped(rng, (1,), np.float32, 17, range(n_envs), 0)})
+        flat = {"n_envs": np.int64(n_envs), "buffer_size": np.int64(CAP)}
+        for i in range(n_envs):
+            flat[f"b{i}_pos"] = np.int64(ref.pos[i])
+            flat[f"b{i}_full"] = np.bool_(ref.full[i])
+            for k, v in ref.buf.items():
+                flat[f"b{i}_buf_{k}"] = v[:, i : i + 1]
+        np.savez(tmp_path / "parent.npz", **flat)
+
+        rb = AsyncReplayBuffer(CAP, n_envs=n_envs, storage="device", sequential=True, obs_keys=("obs",))
+        rb.load(str(tmp_path / "parent.npz"))
+        _assert_same_ring(rb, ref)
+        rb.save(str(tmp_path / "again.npz"))
+        again = np.load(tmp_path / "again.npz")
+        assert set(again.files) == set(flat) | {"sampler_state"}
+        for k, v in flat.items():
+            assert again[k].shape == np.shape(v) and again[k].dtype == np.asarray(v).dtype, k
+            np.testing.assert_array_equal(again[k], v, err_msg=k)
+        for s in rb.to_state_dict()["buffers"]:
+            assert s["buf"]["obs"].shape == (CAP, 1, *item) and s["n_envs"] == 1
+
+    def test_same_seed_draws_the_parents_windows(self):
+        """Values recorded from the parent commit (PR 26) for this seed and
+        this history: the sampler's PRNG stream and index rule are unchanged."""
+        rb = AsyncReplayBuffer(12, n_envs=4, storage="device", sequential=True, seed=5)
+        obs = (np.arange(17)[:, None, None] + 1000 * np.arange(4)[None, :, None]).astype(np.float32)
+        rb.add({"observations": obs})
+        rb.add({"observations": (500 + np.arange(2)[:, None, None]
+                                 + 1000 * np.array([1, 3])[None, :, None]).astype(np.float32)}, indices=[1, 3])
+        two = np.asarray(rb.sample(8, sequence_length=5, n_samples=2)["observations"])[..., 0].astype(int)
+        one = np.asarray(rb.sample(8, sequence_length=5, n_samples=1)["observations"])[..., 0].astype(int)
+        assert two[:, 0].tolist() == [[5, 7, 1014, 1013, 2006, 2007, 3007, 3008],
+                                      [8, 12, 1014, 1013, 2012, 2010, 3008, 3013]]
+        assert two[1, -1].tolist() == [12, 16, 1501, 1500, 2016, 2014, 3012, 3500]
+        assert one[0, 0].tolist() == [8, 9, 1008, 1013, 2007, 2012, 3009, 3011]
+        assert one[0, -1].tolist() == [12, 13, 1012, 1500, 2011, 2016, 3013, 3015]
+        flat = AsyncReplayBuffer(16, n_envs=2, storage="device", sequential=False, seed=11)
+        flat.add({"observations": (np.arange(9)[:, None, None]
+                                   + 100 * np.arange(2)[None, :, None]).astype(np.float32)})
+        s = flat.sample(6, sample_next_obs=True)
+        assert np.asarray(s["observations"])[..., 0].astype(int).tolist() == [0, 0, 6, 106, 103, 100]
+
+    def test_the_format_is_decided_from_the_array_and_recorded(self, monkeypatch):
+        from sheeprl_tpu.data.buffers import _storage_item
+        from sheeprl_tpu.telemetry import core
+
+        assert _storage_item((64, 64, 3), np.uint8) == ((12288,), "lane_dense")
+        assert _storage_item((16, 8), np.float32) == ((128,), "lane_dense")
+        for item, dtype in (((5, 7, 3), np.uint8), ((18,), np.float32), ((1,), np.float32), ((256,), np.float32)):
+            stored, why = _storage_item(item, dtype)
+            assert stored == item and why.startswith(f"as_is: item_bytes={int(np.prod(item)) * np.dtype(dtype).itemsize}")
+
+        class Recorder:
+            events = []
+
+            def event(self, name, **data):
+                self.events.append((name, data))
+
+        monkeypatch.setattr(core, "_active", [Recorder()])
+        rb = AsyncReplayBuffer(8, n_envs=2, storage="device", sequential=True)
+        rb.add({"rgb": np.zeros((1, 2, 64, 64, 3), np.uint8), "vec": np.zeros((1, 2, 18), np.float32)})
+        rb.add({"rgb": np.zeros((1, 2, 64, 64, 3), np.uint8), "vec": np.zeros((1, 2, 18), np.float32)})
+        assert rb._store["rgb"].shape == (8, 2, 12288) and rb._store["vec"].shape == (8, 2, 18)
+        (name, data), = Recorder.events  # once, at allocation
+        assert name == "replay.store"
+        assert data["keys"]["rgb"] == {
+            "logical": [8, 2, 64, 64, 3], "storage": [8, 2, 12288], "dtype": "uint8",
+            "bytes": 8 * 2 * 12288, "format": "lane_dense",
+        }
+        assert data["keys"]["vec"]["storage"] == [8, 2, 18] and data["keys"]["vec"]["bytes"] == 8 * 2 * 18 * 4
+        assert data["keys"]["vec"]["format"].startswith("as_is: item_bytes=72")
+
+
+# the ring's two device programs, as compiled (sheeprl_tpu/data/store_check.py)
+
+
+@pytest.mark.parametrize("n_envs,n_samples,data_len", [(4, 4, 1), (16, 1, 1), (4, 1, 8)])
+def test_compiled_add_and_sample_touch_their_rows_only(n_envs, n_samples, data_len):
+    """The optimised HLO, on this backend: the add aliases every ring to its
+    output, and nothing but that in-place update has a result of the pixel
+    ring's size. Compiled from shapes: a 768 MiB ring costs nothing here."""
+    from chip_smoke import RING_ITEMS  # the five keys of the benchmark's cells
+    from sheeprl_tpu.data import store_check
+
+    rep = store_check.report(
+        65536 // n_envs, n_envs, RING_ITEMS, batch=16, seq_len=8, n_samples=n_samples, data_len=data_len
+    )
+    assert rep["formats"]["rgb"] == "lane_dense"
+    assert rep["add"]["aliased_parameters"] == [0, 1, 2, 3, 4]
+    assert rep["add"]["ring_sized"] == [] and rep["sample"]["ring_sized"] == []
+    assert store_check.faults(rep) == []
+
+
+def test_store_check_names_a_whole_ring_copy():
+    """The parent's compiled add, cut to its ring-sized lines (PR 27, v5e):
+    the copy in, the scatter fusion, the copy back."""
+    from sheeprl_tpu.data import store_check
+
+    ring = "u8[21504,16,64,64,3]"
+    text = f"""HloModule jit__store_add_packed, is_scheduled=true, input_output_alias={{ {{0}}: (0, {{}}, may-alias) }}, entry_computation_layout={{...}}
+
+%fused_computation.2 (param_0.2: {ring}, param_1: s32[16,2], param_2: u8[16,1,1,64,64,3]) -> {ring} {{
+  %param_0.2 = {ring}{{3,2,4,1,0:T(8,128)(4,1)}} parameter(0)
+  ROOT %scatter.10 = {ring}{{3,2,4,1,0:T(8,128)(4,1)}} scatter(%param_0.2, %param_1, %param_2), update_window_dims={{1,2,3}}, to_apply=%region_2.5
+}}
+
+ENTRY %main.7 (store.1: {ring}, idx.1: s32[32]) -> {ring} {{
+  %store.1 = {ring}{{0,3,4,2,1:T(8,128)(4,1)}} parameter(0)
+  %copy.8 = {ring}{{3,2,4,1,0:T(8,128)(4,1)}} copy(%store.1), sharding={{replicated}}
+  %fusion.2 = {ring}{{3,2,4,1,0:T(8,128)(4,1)}} fusion(%copy.8, %copy-done.2, %bitcast.17), kind=kCustom, calls=%fused_computation.2
+  ROOT %copy.11 = {ring}{{0,3,4,2,1:T(8,128)(4,1)}} copy(%fusion.2)
+}}
+"""
+    count = {21504 * 16 * 64 * 64 * 3}
+    assert store_check._ring_sized(text, count, allow_update=True) == [
+        f"copy.8 = {ring}{{3,2,4,1,0:T(8,128)(4,1)}} copy", f"copy.11 = {ring}{{0,3,4,2,1:T(8,128)(4,1)}} copy",
+    ]
+    # in the sample nothing may be ring-sized, an update neither
+    assert len(store_check._ring_sized(text, count, allow_update=False)) == 4
+    rep = {
+        "store_bytes": 100, "formats": {},
+        "add": {"temp_bytes": 200, "alias_bytes": 100, "aliased_parameters": [0], "store_parameters": 1,
+                "ring_sized": ["copy.8"]},
+        "sample": {"temp_bytes": 0, "alias_bytes": 0, "aliased_parameters": [], "ring_sized": []},
+    }
+    found = store_check.faults(rep)
+    assert len(found) == 2 and "copy.8" in found[0] and "temporaries" in found[1]
+    rep["add"].update(aliased_parameters=[], ring_sized=[], temp_bytes=0)
+    assert "aliases 0 of 1" in store_check.faults(rep)[0]

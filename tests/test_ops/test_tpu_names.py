@@ -245,3 +245,20 @@ def test_the_policy_step_and_the_replay_ring_carry_their_scopes():
         n_samples=1, seq_len=2, sequential=True, sample_next_obs=False, obs_keys=("rgb",),
     )
     assert '"jit(_store_sample)/replay/sample/' in op_names(sample)
+
+
+@pytest.mark.parametrize("cell", [0, 1], ids=["ratio1024", "ratio64"])
+def test_the_replay_ring_is_touched_in_place_on_the_tpu(one_chip, no_compile_cache, cell):
+    """The ring's add and sample at the benchmark cells' shapes, compiled for
+    the described v5e: no ring-sized copy, temporaries under 1 % of the
+    3.9 GiB ring (the parent's programs held 7.9 GiB of them: PERF.md, PR 27).
+    `chip_smoke.py` makes the same check, on the same shapes, with the chip's
+    own compile."""
+    from chip_smoke import RING_CELLS, RING_ITEMS
+    from sheeprl_tpu.data import store_check
+
+    rows, n_envs, n_samples = RING_CELLS[cell]
+    rep = store_check.report(rows, n_envs, RING_ITEMS, batch=B, seq_len=64, n_samples=n_samples, sharding=one_chip)
+    assert rep["formats"]["rgb"] == "lane_dense"
+    assert store_check.faults(rep) == [], rep
+    assert rep["add"]["temp_bytes"] < 2**20 and rep["sample"]["temp_bytes"] < 2**21

@@ -1345,13 +1345,19 @@ def main(argv: Sequence[str] | None = None) -> None:
         telem.mark("log/pull", phase="log")
         drains = pipe.drain_metrics(aggregator, global_step)
         telem.mark("log/write", phase="log")
+        # `Time/step_per_second` rides the event of its own step: an iteration
+        # is one event for the logger's writer, not two (--pipeline on drains
+        # the step before, or nothing: the rate then goes alone)
+        rate = {"Time/step_per_second": sps}
         scalars = 1
         for drained, dstep in drains:
             merged = telem.interval(drained, dstep, sps)
-            logger.log_dict(merged, dstep)
             scalars += len(merged)
-        logger.log("Time/step_per_second", sps, global_step)
-        telem.count(scalars=scalars)
+            if dstep == global_step:
+                merged, rate = {**merged, **rate}, {}
+            logger.log_dict(merged, dstep)
+        logger.log_dict(rate, global_step)
+        telem.count(scalars=scalars, backlog=logger.backlog)
         telem.mark(None)
 
         # ---- checkpoint ------------------------------------------------------

@@ -14,7 +14,9 @@ Crash contract: any unhandled exception escaping a main emits a final
 `crash` event to every live telemetry instance and drains the async
 checkpointer BEFORE the process dies, so a crashed run always leaves a
 parseable `telemetry.jsonl` tail and its last committed checkpoint — the
-satellite that previously only clean exits guaranteed.
+satellite that previously only clean exits guaranteed. On every way out
+(return, preemption, crash, SystemExit) the live TensorBoard loggers are
+closed, so the event file holds every scalar the run logged.
 
 Wiring per main (the whole surface):
 
@@ -224,5 +226,19 @@ def crashsafe(fn: Callable[..., Any]) -> Callable[..., Any]:
             raise
         finally:
             RunGuard.uninstall()
+            _close_loggers()
 
     return wrapper
+
+
+def _close_loggers() -> None:
+    """Whatever road led out of the main, the event file holds every scalar
+    it logged: the writer thread's queue is drained here (a no-op after the
+    main's own `logger.close()`)."""
+    from ..utils.logger import live_loggers
+
+    for logger in live_loggers():
+        try:
+            logger.close()
+        except RuntimeError as exc:  # reported: it must not replace the exception on its way out
+            print(f"[resilience] {exc}: {exc.__cause__!r}", file=sys.stderr)

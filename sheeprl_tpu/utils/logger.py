@@ -12,16 +12,33 @@ logger.py:21-34)."""
 from __future__ import annotations
 
 import os
+import queue
+import threading
 import time
 from typing import Any
 
 
+# every logger that still has a writer thread: `resilience.crashsafe` closes
+# them on the ways out of a main that never reach its own `logger.close()`
+_live: list["TensorBoardLogger"] = []
+
+
+def live_loggers() -> list["TensorBoardLogger"]:
+    return list(_live)
+
+
 class TensorBoardLogger:
-    """Thin SummaryWriter wrapper; a no-op on non-zero processes."""
+    """Event-file writer; a no-op on non-zero processes.
+
+    `log_dict` hands ONE event (all its values share the step) to a writer
+    thread and returns: the main thread never waits for the disk. The queue
+    has no bound: an iteration of a main's loop fills one entry of ~40
+    (tag, float) pairs, so an hour behind a dead disk is tens of MB and
+    `backlog` says so. `close()` drains it; a hard kill loses what it holds."""
 
     def __init__(self, log_dir: str, enabled: bool = True):
         self.log_dir = log_dir
-        self._writer = None
+        self._queue: queue.SimpleQueue | None = None
         if enabled:
             # tensorboardX, NOT torch.utils.tensorboard: with tensorflow
             # present, torch's writer makes the `tensorboard` package load
@@ -30,36 +47,82 @@ class TensorBoardLogger:
             # create_logger-then-DMC-render crashed in MjrContext / TF
             # framework; tensorboardX writes identical event files with no
             # TF import)
-            from tensorboardX import SummaryWriter
+            from tensorboardX.event_file_writer import EventsWriter
 
             os.makedirs(log_dir, exist_ok=True)
-            self._writer = SummaryWriter(log_dir)
+            self._file = EventsWriter(os.path.join(log_dir, "events"))
+            self._queue = queue.SimpleQueue()
+            self._handed = self._written = 0  # one writer each: no lock
+            self._error: BaseException | None = None
+            self._thread = threading.Thread(
+                target=self._write_loop, args=(self._queue,), name="tb-writer", daemon=True
+            )
+            self._thread.start()
+            _live.append(self)
+
+    @property
+    def backlog(self) -> int:
+        """Events handed over and not yet in the file's buffer."""
+        return 0 if self._queue is None else self._handed - self._written
+
+    def _write_loop(self, events: queue.SimpleQueue) -> None:
+        from tensorboardX.proto.event_pb2 import Event
+        from tensorboardX.summary import Summary
+
+        while (item := events.get()) is not None:
+            summary, step, wall_time = item
+            try:
+                if not isinstance(summary, Summary):  # a log_dict's (tag, value) pairs
+                    summary = Summary(value=[Summary.Value(tag=k, simple_value=v) for k, v in summary])
+                self._file.write_event(Event(summary=summary, step=step, wall_time=wall_time))
+                if events.empty():
+                    self._file.flush()  # to the OS, once the thread has caught up
+            except Exception as exc:  # kept for close(): a daemon thread's error reaches no one
+                self._error = self._error or exc
+            self._written += 1
+
+    def _put(self, summary, step: int) -> None:
+        self._handed += 1
+        self._queue.put((summary, int(step), time.time()))
 
     def log(self, name: str, value: Any, step: int) -> None:
-        if self._writer is not None:
-            self._writer.add_scalar(name, float(value), step)
+        self.log_dict({name: value}, step)
 
     def log_dict(self, metrics: dict[str, Any], step: int) -> None:
-        for k, v in metrics.items():
-            self.log(k, v, step)
+        if self._queue is not None and metrics:
+            from tensorboardX.summary import _clean_tag
+
+            self._put([(_clean_tag(k), float(v)) for k, v in metrics.items()], step)
 
     def log_hyperparams(self, params: dict[str, Any]) -> None:
         # TensorBoard's text plugin renders markdown: a proper two-column
         # table instead of one run-on text blob (pipes in values would break
         # the row structure, so they are escaped)
-        if self._writer is not None:
+        if self._queue is not None:
+            from tensorboardX.summary import text
+
             escaped = [
                 (k, str(v).replace("|", "\\|")) for k, v in sorted(params.items())
             ]
             rows = "\n".join(f"| {k} | {v} |" for k, v in escaped)
-            self._writer.add_text(
-                "hyperparams", "| key | value |\n| --- | --- |\n" + rows
-            )
+            table = "| key | value |\n| --- | --- |\n" + rows
+            self._put(text("hyperparams", table), 0)
 
     def close(self) -> None:
-        if self._writer is not None:
-            self._writer.flush()
-            self._writer.close()
+        """Drain the queue, flush and close the file; idempotent. Raises the
+        first error the writer thread met."""
+        if self._queue is None:
+            return
+        queue_, self._queue = self._queue, None
+        _live.remove(self)
+        queue_.put(None)
+        self._thread.join()
+        try:
+            self._file.close()
+        except OSError as exc:  # the last flush
+            self._error = self._error or exc
+        if self._error is not None:
+            raise RuntimeError(f"event file {self.log_dir}: a write failed, events are missing") from self._error
 
 
 def _broadcast_run_name(run_name: str) -> str:

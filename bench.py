@@ -704,7 +704,7 @@ def _measure_guarded(fn, args_, state_, *fn_args):
         return 0.0
 
 
-_PALLAS_FAMILIES = ("gru", "two_hot", "symlog", "cnn")
+_PALLAS_FAMILIES = ("gru", "two_hot", "symlog")
 
 
 def _set_kernel_families(enabled: dict | None) -> None:
@@ -1037,7 +1037,7 @@ def bench_dreamer_v3(tiny: bool = False, pipeline_mode: str = "ab") -> None:
         return _off_holder["closure"]
 
     all_fams = tuple(_PALLAS_FAMILIES)
-    waves = [("all",)] if tiny else [("all",), ("gru", "two_hot"), ("symlog", "cnn")]
+    waves = [("all",)] if tiny else [("all",), ("gru", "two_hot"), ("symlog",)]
     # candidate kernel configs: fams-tuple -> (samples, paired off samples,
     # closure-or-None, loaded-from-ledger). Each must beat its own wave's
     # interleaved off baseline by more than the observed spread to be

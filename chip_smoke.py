@@ -56,7 +56,7 @@ DV3_ARGV = [
     "--learning_starts", "320", "--total_steps", "352", "--buffer_size", "4096",
 ]
 MIN_STEADY_STEPS = 3  # train steps whose interval no longer compiled anything big
-DV3_FAMILIES = ("gru", "rssm", "two_hot", "cnn")
+DV3_FAMILIES = ("gru", "rssm", "two_hot")
 
 SERVE_ARGV = [
     "serve", "--algo", "sac", "--model_argv", "--env_id Pendulum-v1",

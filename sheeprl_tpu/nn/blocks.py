@@ -162,34 +162,10 @@ class CNN(Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: [..., H, W, C] — leading batch dims are folded around the convs
         (batch-major for sequence batches, see _fold_rows)."""
-        from ..ops import pallas_cnn
-
         x, unfold = _fold_rows(x)
         act = activation(self.act)
         for i, layer in enumerate(self.layers):
             norm = self.norms[i]
-            block_ok = (
-                norm is not None
-                and norm.scale is not None
-                and layer.bias is None
-                # even spatial dims only: the kernel computes h//2 while the
-                # XLA SAME path computes ceil(h/2) — odd inputs (e.g. the
-                # 21x21 stage of an 84x84 encoder) must stay unfused or the
-                # toggle would change output shapes
-                and x.shape[-3] % 2 == 0
-                and x.shape[-2] % 2 == 0
-            )
-            if pallas_cnn.cnn_stage_supported(
-                layer.kernel.shape, layer.stride, layer.padding, block_ok, self.act,
-                x, layer.kernel,
-            ):
-                # fused Dreamer miniblock: conv + LayerNorm + SiLU in one
-                # Pallas kernel (ops/pallas_cnn.py)
-                x = pallas_cnn.conv_ln_silu(
-                    x, layer.kernel.astype(x.dtype), norm.scale, norm.offset,
-                    norm.eps,
-                )
-                continue
             x = layer(x)
             if norm is not None:
                 x = norm(x)
@@ -253,29 +229,11 @@ class DeCNN(Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: [..., H, W, C] latent grid -> [..., H', W', C'] image
         (leading dims folded batch-major, see _fold_rows)."""
-        from ..ops import pallas_cnn
-
         x, unfold = _fold_rows(x)
         act = activation(self.act)
         last = len(self.layers) - 1
         for i, layer in enumerate(self.layers):
             norm = self.norms[i]
-            block_ok = (
-                norm is not None
-                and norm.scale is not None
-                and layer.bias is None
-                and (i != last or self.act_last)
-            )
-            if pallas_cnn.cnn_stage_supported(
-                layer.kernel.shape, layer.stride, layer.padding, block_ok, self.act,
-                x, layer.kernel,
-            ):
-                # fused subpixel-deconv + LayerNorm + SiLU Pallas stage
-                x = pallas_cnn.deconv_ln_silu(
-                    x, layer.kernel.astype(x.dtype), norm.scale, norm.offset,
-                    norm.eps,
-                )
-                continue
             x = layer(x)
             if norm is not None:
                 x = norm(x)

@@ -4,10 +4,12 @@ The BASELINE.md north star names four kernel targets: the LayerNorm-GRU cell
 (the RSSM scan body, reference /root/reference/sheeprl/models/models.py:330-402),
 symlog/symexp (reference utils/utils.py:125-133), the two-hot log-prob
 (reference utils/distribution.py:220-266), and the CNN encoder/decoder
-stages (ops/pallas_cnn.py — fused conv/deconv + LayerNorm + SiLU,
-per-family switch SHEEPRL_TPU_PALLAS_CNN). ISSUE 9 adds the fifth: the
-whole RSSM dynamic step (pre-MLP + LN-GRU + prior/posterior head stacks)
-as ONE kernel, `fused_rssm_step` below. Each kernel here
+stages. The last lost its chip measurement and is gone (PR 30): XLA lays a
+stage's arrays out with the batch in the lanes and does conv + LayerNorm +
+SiLU 1.6 - 10 x faster than a kernel in Mosaic's row-major layout plus the
+relayouts around it; nn/blocks.py runs the plain layers. ISSUE 9 adds the
+fifth: the whole RSSM dynamic step (pre-MLP + LN-GRU + prior/posterior head
+stacks) as ONE kernel, `fused_rssm_step` below. Each kernel here
 
   - fuses what XLA would otherwise stage through HBM: the GRU kernel keeps the
     [B, 3H] pre-activation entirely in VMEM between the MXU matmul, the
@@ -66,7 +68,10 @@ __all__ = [
 # `<x>_fwd` is the forward as called outside differentiation (a policy step),
 # `<x>_fwd_res` the forward under differentiation, which also writes the
 # residuals its backward reads. Every family's backward is plain XLA: no
-# kernel is a `_bwd`.
+# kernel is a `_bwd`. No kernel carries the `cnn` names since PR 30 (the
+# family is deleted); the row stays because the benchmark's `cnn_kernel_ms`
+# reader is pinned to it (tests/test_benchmark/test_span_readers.py) and goes
+# with that metric.
 KERNEL_NAMES: dict[str, tuple[str, ...]] = {
     "gru": ("gru_fwd", "gru_fwd_res"),
     "rssm": ("rssm_step_fwd",),
@@ -132,7 +137,7 @@ def _env_flag(name: str) -> bool | None:
 
 def use_pallas(kind: str | None = None, *operands) -> bool:
     """Master gate, optionally refined per kernel family via
-    SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SYMLOG|CNN|
+    SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SYMLOG|
     SAC_TRUNK) — the bench uses the per-kernel switches to attribute
     wins/losses and keep only winners.
 

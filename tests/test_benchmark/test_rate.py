@@ -66,3 +66,16 @@ def test_a_traced_run_traces_after_the_close_and_stops_the_main_last(monkeypatch
     assert (w.i_open, w.i_close) == (2, 6) and w.window_seconds == pytest.approx(1.0)
     assert calls == [("start", "somewhere"), ("stop",), ("end",)]
     assert w.traced_iterations == 4 and len(w.iteration_seconds) == 4
+
+
+def test_freed_memory_is_handed_back_once_and_before_the_stamp_that_opens_the_window(monkeypatch):
+    from benchmark.drivers import train_main
+
+    train_main.hand_back_freed_memory()  # the real one runs wherever the tests do
+    w = Window(1.0, 4, stop=lambda: None)
+    seen = []
+    monkeypatch.setattr(train_main, "hand_back_freed_memory", lambda: seen.append((len(w.stamps), w.i_open)))
+    for i in range(30):
+        w.on_step(0.25 * i)
+    # at the fourth boundary, with three stamps taken and the window not yet open: its time is set-up's
+    assert seen == [(3, None)] and w.i_open == 3 and w.window_seconds == pytest.approx(1.0)

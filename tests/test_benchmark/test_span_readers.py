@@ -12,8 +12,8 @@ from sheeprl_tpu.ops import pallas_kernels
 from sheeprl_tpu.telemetry.phase import ITERATION
 
 SPAN_METRICS = ["host_wait_ms", "host_work_ms", "host_refill_ms", "sample_dispatch_ms",
-                "log_write_ms_p50", "log_write_ms_max", "log_scalars_per_iter"]
-KERNEL_METRICS = ["gru_kernel_ms", "cnn_kernel_ms", "two_hot_kernel_ms"]
+                "log_write_ms_p50", "log_write_ms_max", "log_scalars_per_iter", "log_backlog_max"]
+KERNEL_METRICS = ["gru_kernel_ms", "two_hot_kernel_ms"]
 
 # one iteration of the loop, as (name, start ms, length ms) from the top of its
 # body; what no child covers (2 ms between the last child and the end) is self time
@@ -143,13 +143,11 @@ def traced_run(ops, train_steps=2, events=()):
 def test_kernel_time_is_the_train_steps_calls_per_executed_train_step():
     ops = [kernel_op("gru_fwd_res.7", 2e-6)] * 100 + [kernel_op("gru_fwd_res", 3e-6)] + [
         kernel_op("gru_fwd.3", 1e-6),  # the policy step's call, once an iteration: named, not summed
-        kernel_op("cnn_enc_fwd_res.1", 1e-3), kernel_op("cnn_dec_fwd_res.2", 2e-3), kernel_op("cnn_enc_fwd.4", 5e-3),
         {"name": "fusion.12", "shape": "f32[4]", "seconds": 9.0},  # not a kernel: no operands kept
         {"name": "gru_fwd_res.8", "shape": "f32[4]", "seconds": 9.0},  # a name alone is not a custom-call
     ]
     run = traced_run(ops)
     assert read("gru_kernel_ms", run) == pytest.approx(1e3 * (100 * 2e-6 + 3e-6) / 2)
-    assert read("cnn_kernel_ms", run) == pytest.approx(1e3 * 3e-3 / 2)
     assert any(line.startswith("gru kernels over 2 traced train steps: gru_fwd 1 calls, mean 1.00 us (policy step: not in the sum); "
                                "gru_fwd_res 101 calls") for line in run["notes"])
     # the figure does not move with the traffic's train ratio: four policy steps a train step change nothing
@@ -169,7 +167,7 @@ def test_a_family_that_did_not_run_reads_none_with_the_programs_reason():
 def test_without_a_trace_or_a_train_step_there_is_nothing_to_read(metric):
     assert read(metric, {"events": []}) is None
     assert read(metric, traced_run([kernel_op("gru_fwd_res.1", 1e-6)], train_steps=0)) is None
-    assert read(metric, traced_run([kernel_op("gru_fwd.1", 1e-6), kernel_op("cnn_dec_fwd.1", 1e-6)])) is None  # policy steps alone
+    assert read(metric, traced_run([kernel_op("gru_fwd.1", 1e-6)])) is None  # policy steps alone
 
 
 @pytest.mark.parametrize("metric", KERNEL_METRICS)

@@ -89,13 +89,13 @@ def test_recorded_trace_busy_programs_and_ring_copy(recorded):
 
 
 def test_recorded_trace_through_the_metric_readers(recorded):
-    from benchmark.metrics import device_idle, gru_roofline, gru_scan_roofline, replay_device_share, train_mfu, train_step_ms
+    from benchmark.metrics import device_idle, gru_roofline, gru_scan_roofline, train_mfu, train_step_ms
 
     from .conftest import ROOT, load
 
     args = load(f"{ROOT}/benchmark/configs/dv3_s_pixel_bf16.json")["args"]
     run = {
-        "trace": recorded, "chips": 1, "ring_shape": "u8[86016,4,64,64,3]",
+        "trace": recorded, "chips": 1,
         "peaks": load(f"{ROOT}/benchmark/peaks.json")["TPU v5 lite"],
         "model_config": {**args, "actions": 18, "image_channels": 3},
     }
@@ -110,7 +110,6 @@ def test_recorded_trace_through_the_metric_readers(recorded):
     assert len(run["notes"]) == 2 and all("bound by operations" in n for n in run["notes"])
     assert train_step_ms.read(run) == pytest.approx(73.30609)
     assert train_mfu.read(run) == pytest.approx(100 * 0.900458348544e12 / 0.07330609 / 197e12)
-    assert replay_device_share.read(run) == pytest.approx(100 * 0.051880143 / 0.121760915)
     assert device_idle.read(run) == pytest.approx(100 * (1 - 0.054654757 / 0.121760915))
     empty = {"trace": None, "peaks": run["peaks"]}
-    assert all(m.read(empty) is None for m in (device_idle, gru_roofline, gru_scan_roofline, replay_device_share, train_mfu, train_step_ms))
+    assert all(m.read(empty) is None for m in (device_idle, gru_roofline, gru_scan_roofline, train_mfu, train_step_ms))

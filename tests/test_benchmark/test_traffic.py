@@ -71,3 +71,33 @@ def test_traffic_files_are_parameters_the_generator_reads():
         lengths = [length for length, _, _ in load.plan]
         assert min(lengths) == t["env"]["episode_steps"][0] and max(lengths) == t["env"]["episode_steps"][1]
         assert t["learning_starts"] // t["num_envs"] >= 64  # a T=64 window per environment
+
+
+def first_bunch(traffic, seed, steps=3000):
+    """The first step, all environments in lockstep, at which two episodes end together."""
+    load = Traffic(traffic["env"], traffic["num_envs"], seed)
+    envs = [load.make_env() for _ in range(traffic["num_envs"])]
+    for e in envs:
+        e.reset()
+    for step in range(1, steps + 1):
+        done = [e for e in envs if e.step(0)[2]]
+        if len(done) > 1:
+            return step
+        for e in done:
+            e.reset()
+    return None
+
+
+@pytest.mark.parametrize("name,expected", [("ratio64_16env.json", 1286), ("ratio1024_4env.json", None)])
+def test_where_two_episodes_first_end_on_one_step_whatever_the_seed(name, expected):
+    """Vector environments do end episodes together, and the program adds a
+    step's ends in one call shaped by their number: the first pair is a
+    compile (PERF.md 7.1). With 16 environments it falls on step 1,286, window
+    index 1,192: 46 to 49 s into a window at 38 ms an iteration, past the
+    manifest's 35 s until an iteration takes under 29 ms."""
+    with open(os.path.join(ROOT, "benchmark", "traffic", name)) as f:
+        traffic = json.load(f)
+    assert [first_bunch(traffic, seed) for seed in (1, 3_000_000_000)] == [expected, expected]
+    if expected:
+        open_at = traffic["learning_starts"] // traffic["num_envs"] + 2 + traffic["warmup_iterations"]  # drivers/train_main.py
+        assert expected - open_at == 1192

@@ -472,7 +472,6 @@ def main() -> None:
     finally:
         stop_children()
     say("== summary")
-    say("  symlog/symexp Pallas kernels: no dispatch site in any entry point (never selected)")
     hits = sum((r.get("cache") or {}).get("hits") or 0 for r in results.values())
     misses = sum((r.get("cache") or {}).get("misses") or 0 for r in results.values())
     say(f"  compile cache {device['cache_dir']}: hits={hits} misses={misses} over the four phases")

@@ -77,8 +77,8 @@ def main(argv: Sequence[str] | None = None) -> None:
     if args.eval_only:
         # A single-stream greedy evaluation has no player/trainer split to
         # exercise, and decoupled checkpoints share the coupled twin's key
-        # contract (receipted by the cross-task eval, BENCHES.md), so route
-        # through the coupled evaluator natively (VERDICT r3 #7).
+        # contract, so route through the coupled evaluator natively
+        # (VERDICT r3 #7).
         from .dreamer_v3 import main as coupled_main
 
         return coupled_main(argv)

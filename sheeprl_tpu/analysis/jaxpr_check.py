@@ -48,7 +48,7 @@ count, dtype set, donation map, FLOP/byte estimates from XLA's
 `analysis/budget/` ledger. CI re-derives the fingerprints and fails on
 unexplained drift (new dtypes, op-count growth past tolerance, lost
 donations): "did this PR quietly bloat or de-optimize a jit?" becomes a
-gated check instead of a bench regression three rounds later.
+gated check instead of a regression found three rounds later.
 """
 
 from __future__ import annotations

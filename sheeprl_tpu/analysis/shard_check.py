@@ -179,14 +179,15 @@ SHARD_SUPPRESSIONS: dict[tuple[str, str, str], str] = {
         "is the data-parallel design minimum"
     ),
     # Under context parallelism the imagination scan runs over [T*B] rows
-    # sharded across the FULL (data, seq) grid (the replicated-RSSM layout
-    # measured fastest in MULTICHIP_r02), so its per-step actor/head
+    # sharded across the FULL (data, seq) grid (the replicated-RSSM layout,
+    # which spares the backward pass a full rematerialization of the scan
+    # input: parallel/mesh.py `scan_batch_spec`), so its per-step actor/head
     # reductions all-reduce across the grid inside the scan body by
     # construction. The ledger locks the hot histogram: any ADDITIONAL
     # hot-loop collective still fails the comms gate.
     ("dreamer_v3@seq", "train_step", "SC006"): (
         "imagination-scan reductions over the fully-grid-sharded [T*B] "
-        "rows are the chosen context-parallel layout (MULTICHIP_r02)"
+        "rows are the chosen context-parallel layout"
     ),
 }
 

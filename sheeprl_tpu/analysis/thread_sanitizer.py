@@ -19,7 +19,7 @@ Violations never raise and the wrappers preserve full Lock/RLock/
 Condition semantics (`_is_owned`/`_release_save`/`_acquire_restore`
 included, so `Condition.wait` works and correctly un-tracks the backing
 lock while waiting). Overhead is a few dict operations per acquisition —
-acceptable for tests and the chaos bench, not for production serving.
+acceptable for tests, not for production serving.
 
 Lock naming: the allocation site (`path:line`) is matched against the
 ledger's `lock_sites` table, so a lock allocated at
@@ -29,8 +29,7 @@ ledger's `lock_sites` table, so a lock allocated at
 
 Enablement: `install()` directly (tests), `maybe_install_from_env()` off
 `SHEEPRL_TPU_SANITIZE_THREADS=1` (the flock/serve suites, subprocess
-actors, the serve main and the chaos bench export it), or the
-`--sanitize_threads` run flag.
+actors and the serve main export it), or the `--sanitize_threads` run flag.
 """
 
 from __future__ import annotations

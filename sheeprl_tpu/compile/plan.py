@@ -41,8 +41,8 @@ callable — warm start can only lose its head start, never change results.
 Observability: per-executable compile seconds and persistent-cache hit/miss
 counts surface as `Compile/*` gauges (registered with the run's Telemetry)
 plus `compile` events in telemetry.jsonl, and the plan stamps
-`Compile/time_to_first_update_seconds` — the headline `bench.py
---algo warm_compile` prices — when the first `role="update"` call returns.
+`Compile/time_to_first_update_seconds` when the first `role="update"` call
+returns.
 """
 
 from __future__ import annotations

@@ -1143,9 +1143,8 @@ class AsyncReplayBuffer:
         # staged rows flush as ONE batched scatter (one transfer per key
         # per flush) at the next sample/surgery/checkpoint access, instead
         # of one transfer per key per step. OFF by default (stage_rows=0):
-        # measured on the round-3 chip, the batched flush sits on the
-        # sample critical path and loses ~25% e2e vs per-step adds that
-        # overlap with policy-step compute (BENCHES.md "staging receipt").
+        # the batched flush sits on the sample critical path, where per-step
+        # adds overlap with policy-step compute; not measured on the chip.
         # Opt in via stage_rows or SHEEPRL_TPU_REPLAY_STAGE_ROWS.
         if stage_rows is None:
             stage_rows = int(os.environ.get("SHEEPRL_TPU_REPLAY_STAGE_ROWS", "0"))

@@ -33,9 +33,7 @@ class StepLatencyWrapper(gym.Wrapper):
     host time that background work (warm-start compilation, prefetchers)
     can genuinely hide, even on a single-core host.
 
-    Enabled repo-wide by `SHEEPRL_TPU_ENV_LATENCY_MS` (see utils/env.py);
-    `bench.py --algo warm_compile` uses it to put collection in the
-    latency-bound regime its headline models."""
+    Enabled repo-wide by `SHEEPRL_TPU_ENV_LATENCY_MS` (see utils/env.py)."""
 
     def __init__(self, env: gym.Env, latency_ms: float):
         super().__init__(env)

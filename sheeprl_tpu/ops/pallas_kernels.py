@@ -1,22 +1,21 @@
 """Pallas TPU kernels for the framework's hot ops.
 
-The BASELINE.md north star names four kernel targets: the LayerNorm-GRU cell
-(the RSSM scan body, reference /root/reference/sheeprl/models/models.py:330-402),
-symlog/symexp (reference utils/utils.py:125-133), the two-hot log-prob
-(reference utils/distribution.py:220-266), and the CNN encoder/decoder
-stages. The last lost its chip measurement and is gone (PR 30): XLA lays a
-stage's arrays out with the batch in the lanes and does conv + LayerNorm +
-SiLU 1.6 - 10 x faster than a kernel in Mosaic's row-major layout plus the
-relayouts around it; nn/blocks.py runs the plain layers. ISSUE 9 adds the
-fifth: the whole RSSM dynamic step (pre-MLP + LN-GRU + prior/posterior head
-stacks) as ONE kernel, `fused_rssm_step` below. Each kernel here
+The kernel targets: the LayerNorm-GRU cell (the RSSM scan body, reference
+/root/reference/sheeprl/models/models.py:330-402) and the two-hot log-prob
+(reference utils/distribution.py:220-266). The CNN encoder/decoder stages
+lost their chip measurement and are gone (PR 30): XLA lays a stage's arrays
+out with the batch in the lanes and does conv + LayerNorm + SiLU 1.6 - 10 x
+faster than a kernel in Mosaic's row-major layout plus the relayouts around
+it; nn/blocks.py runs the plain layers. ISSUE 9 adds the whole RSSM dynamic
+step (pre-MLP + LN-GRU + prior/posterior head stacks) as ONE kernel,
+`fused_rssm_step` below. Each kernel here
 
   - fuses what XLA would otherwise stage through HBM: the GRU kernel keeps the
     [B, 3H] pre-activation entirely in VMEM between the MXU matmul, the
     layernorm moments, and the gate math; the two-hot kernel never
     materializes the [N, K] two-hot target at all;
   - differentiates: forward runs the kernel, backward is an analytic VJP
-    (two-hot, symlog) or a recompute-in-XLA VJP (GRU) so training numerics
+    (two-hot) or a recompute-in-XLA VJP (GRU) so training numerics
     stay exact;
   - is gated: `use_pallas()` is on when the default backend is a TPU, the
     SHEEPRL_TPU_PALLAS env var forces on/off, and interpret mode runs the
@@ -57,8 +56,6 @@ __all__ = [
     "int8_trunk_reference",
     "fused_int8_trunk_supported",
     "two_hot_log_prob",
-    "symlog",
-    "symexp",
 ]
 
 # The names the kernels carry on the device, by family (the `kind` of
@@ -68,14 +65,10 @@ __all__ = [
 # `<x>_fwd` is the forward as called outside differentiation (a policy step),
 # `<x>_fwd_res` the forward under differentiation, which also writes the
 # residuals its backward reads. Every family's backward is plain XLA: no
-# kernel is a `_bwd`. No kernel carries the `cnn` names since PR 30 (the
-# family is deleted); the row stays because the benchmark's `cnn_kernel_ms`
-# reader is pinned to it (tests/test_benchmark/test_span_readers.py) and goes
-# with that metric.
+# kernel is a `_bwd`.
 KERNEL_NAMES: dict[str, tuple[str, ...]] = {
     "gru": ("gru_fwd", "gru_fwd_res"),
     "rssm": ("rssm_step_fwd",),
-    "cnn": ("cnn_enc_fwd", "cnn_enc_fwd_res", "cnn_dec_fwd", "cnn_dec_fwd_res"),
     "two_hot": ("two_hot_fwd",),
     "sac_trunk": ("int8_trunk_fwd",),
 }
@@ -137,9 +130,8 @@ def _env_flag(name: str) -> bool | None:
 
 def use_pallas(kind: str | None = None, *operands) -> bool:
     """Master gate, optionally refined per kernel family via
-    SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SYMLOG|
-    SAC_TRUNK) — the bench uses the per-kernel switches to attribute
-    wins/losses and keep only winners.
+    SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SAC_TRUNK), so a
+    chip run can set one family's XLA twin against its kernel.
 
     With `kind` (a dispatch site asking for its family) a refusal is
     recorded (:func:`select`): "disabled", or "partitioned" when any leaf
@@ -803,62 +795,3 @@ def _two_hot_bwd(residuals, g):
 
 
 two_hot_log_prob.defvjp(_two_hot_fwd, _two_hot_bwd)
-
-
-# =============================================================================
-# symlog / symexp
-# =============================================================================
-
-
-def _symlog_kernel(x_ref, out_ref):
-    x = x_ref[:]
-    out_ref[:] = jnp.sign(x) * jnp.log1p(jnp.abs(x))
-
-
-def _symexp_kernel(x_ref, out_ref):
-    x = x_ref[:]
-    out_ref[:] = jnp.sign(x) * (jnp.exp(jnp.abs(x)) - 1.0)
-
-
-def _elementwise(kernel, x):
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=_VMEM)],
-        out_specs=pl.BlockSpec(memory_space=_VMEM),
-        interpret=_interpret_mode(),
-    )(x)
-
-
-@jax.custom_vjp
-def symlog(x):
-    """sign(x) * log1p(|x|) with the analytic gradient 1 / (1 + |x|)."""
-    return _elementwise(_symlog_kernel, x)
-
-
-def _symlog_fwd(x):
-    return _elementwise(_symlog_kernel, x), x
-
-
-def _symlog_bwd(x, g):
-    return (g / (1.0 + jnp.abs(x)),)
-
-
-symlog.defvjp(_symlog_fwd, _symlog_bwd)
-
-
-@jax.custom_vjp
-def symexp(x):
-    """sign(x) * (exp(|x|) - 1) with the analytic gradient exp(|x|)."""
-    return _elementwise(_symexp_kernel, x)
-
-
-def _symexp_fwd(x):
-    return _elementwise(_symexp_kernel, x), x
-
-
-def _symexp_bwd(x, g):
-    return (g * jnp.exp(jnp.abs(x)),)
-
-
-symexp.defvjp(_symexp_fwd, _symexp_bwd)

@@ -9,8 +9,8 @@ pytree of `[N, ...]` leaves) accordingly; leaves whose leading dim does not
 divide the mesh (PRNG keys, scalars) are replicated.
 
 `AnakinStats` is the `Anakin/*` gauge source every wired main registers
-with its Telemetry: collection rate, scan span, env batch and device count
-— the numbers `bench.py --algo anakin` prices."""
+with its Telemetry: collection rate, scan span, env batch and device
+count."""
 
 from __future__ import annotations
 

@@ -171,10 +171,11 @@ def constrain_scan_inputs(constrain, scan_spec, *arrays):
     The direct reshard `("seq", "data") <-> (None, ("data", "seq"))` moves a
     mesh sub-axis between tensor axes in one step; GSPMD handles the forward
     but meets its TRANSPOSE in the backward pass with an involuntary full
-    rematerialization (replicate-then-repartition — observed in the dp x sp
-    DV3 backward, MULTICHIP_r02). Stepping through the batch-on-"data"
-    intermediate splits both directions into a single-axis all-gather plus a
-    local slice, which GSPMD partitions efficiently both ways."""
+    rematerialization (replicate-then-repartition, the partitioner's own
+    words for it, in the dp x sp DV3 backward). Stepping through the
+    batch-on-"data" intermediate splits both directions into a single-axis
+    all-gather plus a local slice, which GSPMD partitions efficiently both
+    ways."""
     if scan_spec == _FULL_SCAN_SPEC:
         arrays = tuple(constrain(a, None, "data") for a in arrays)
     out = tuple(constrain(a, *scan_spec) for a in arrays)
@@ -195,10 +196,11 @@ def scan_batch_spec(mesh: Optional[Mesh], batch_size: int) -> tuple:
     FLOPs but its boundary reshard moves a mesh sub-axis between tensor
     axes, which GSPMD's transpose meets with an involuntary full
     rematerialization (replicate + repartition) in EVERY backward pass
-    (MULTICHIP_r02; still present through a two-step reshard). Until the
-    Shardy partitioner handles that pattern, the replicated-scan layout is
-    strictly faster end-to-end; `constrain_scan_inputs` keeps the two-step
-    path for when a fully-sharded spec returns."""
+    (still present through a two-step reshard). The replicated-scan layout
+    avoids that copy of the whole scan input on every device; which of the
+    two is faster end to end is not measured on the chip.
+    `constrain_scan_inputs` keeps the two-step path for when a
+    fully-sharded spec returns."""
     return (None, "data")
 
 

@@ -22,7 +22,7 @@ Wiring, in dependency order:
      with reason="draining", and the process exits rc 75 (the shared
      resumable/preempted code). `--serve_requests` completion stays a
      plain rc 0. An armed `peer.crash@k` fault SIGKILLs the server at
-     loop step k — the chaos harness's server-crash injection.
+     loop step k (the server-crash injection site).
 
 The resolved listen address is printed AND written to
 `<log_dir>/serve_address` so scripted clients never parse stdout.
@@ -244,7 +244,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                         "serve.retier_error",
                         error=f"{type(err).__name__}: {err}",
                     )
-            # the chaos harness's server-crash site: SIGKILL, no drain — the
+            # the server-crash injection site: SIGKILL, no drain — the
             # recovery under test is the CLIENT's (typed ConnectionLost +
             # reconnect/resend under idempotent ids)
             if inject.get_plan().fire_at("peer.crash", step) is not None:

@@ -17,7 +17,7 @@ dispatch path never blocks on a reload) and are answered with a RELOAD
 reply {ok, version, seconds, error}. HELLO/WELCOME carries the serving
 contract: algo, obs keys, ladder rungs, params version. HEALTH frames
 (kind 16) answer {ready, draining, version, queue_depth, completed} —
-the liveness probe for load balancers and the chaos harness.
+the liveness probe for load balancers.
 
 Hardening (ISSUE 16): string request ids are idempotent — a terminal
 answer (RESPONSE/ERROR, never SHED) is cached in a bounded dedupe map,

@@ -29,8 +29,8 @@ TensorBoard pipeline with no extra logger calls), appends the merged dict to
 `<log_dir>/telemetry.jsonl`, runs the non-finite watchdog, and prints the
 heartbeat when due. Everything is host-side bookkeeping — no device syncs,
 no jit retraces — so the instrumented hot loop stays within noise of the
-uninstrumented one (bench.py --telemetry A/B + the overhead smoke test are
-the receipts).
+uninstrumented one (tests/test_utils/test_telemetry.py holds the overhead on
+the CPU; PERF.md has the chip's reading).
 
 Kill switches: SHEEPRL_TPU_TELEMETRY=0 disables the subsystem (interval()
 passes metrics through untouched, no phase opens); SHEEPRL_TPU_TRACE=0 keeps

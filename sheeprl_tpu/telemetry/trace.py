@@ -37,7 +37,7 @@ learner's rank-0 `telemetry.jsonl` existed. sheepscope adds:
 Kill switch: ``SHEEPRL_TPU_TRACE=0`` disables span/clock emission (the
 wire fields simply stay absent; old peers never see a difference).
 Span emission is per-chunk / per-update / per-request — never per env
-step — so the trace plane stays within the bench A/B overhead budget.
+step — so the trace plane stays within the telemetry overhead budget.
 """
 
 from __future__ import annotations

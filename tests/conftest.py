@@ -23,7 +23,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 # importing the package wires the persistent XLA compilation cache (honoring
 # SHEEPRL_TPU_XLA_CACHE=0) and exports JAX_COMPILATION_CACHE_DIR so test
-# SUBPROCESSES — bench smoke, CLI dry runs — share one cache with the pytest
+# SUBPROCESSES (CLI dry runs, flock actors) share one cache with the pytest
 # process; identical-HLO graphs compile once per box, not once per process
 import sheeprl_tpu  # noqa: F401
 

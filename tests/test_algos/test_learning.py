@@ -488,9 +488,9 @@ def test_dreamer_v1_improves_pendulum(tmp_path):
     """DreamerV1 learning receipt (VERDICT r3 #3), in DV1's native regime:
     continuous control with dense rewards (its tanh_normal actor trains by
     pure dynamics backprop — no reinforce term, no entropy bonus — which
-    collapses on discrete tiny-CartPole; see BENCHES.md round-4 DV1
-    investigation). At receipt scale the policy plateaus around -950: a
-    clear, reproducible improvement over the measured same-protocol random
+    collapses on discrete tiny-CartPole). At receipt scale the policy
+    plateaus around -950: a clear, reproducible improvement over the
+    measured same-protocol random
     baseline (-1287 mean, episodes -865..-1713) without reaching the
     SAC/DroQ receipts' -300 (the reference's own DV1 regime is 5M steps /
     ~500k updates; this budget delivers ~2.8k). Validated runs: greedy

@@ -1,7 +1,7 @@
 """DreamerV3 + MinedojoActor end-to-end on the mocked MineDojo backend:
 drives the full pipeline — make_dict_env minedojo dispatch, the wrapper's
 3-head MultiDiscrete actions and mask_* obs, the masked actor at play time —
-through one real training update (BASELINE config 5's CI analog)."""
+through one real training update."""
 
 import os
 

@@ -13,7 +13,6 @@ def test_repo_is_sheeplint_clean():
     targets = [
         os.path.join(REPO, "sheeprl_tpu"),
         os.path.join(REPO, "tools"),
-        os.path.join(REPO, "bench.py"),
     ]
     violations = lint_paths(targets)
     assert not violations, "\n" + "\n".join(v.format() for v in violations)

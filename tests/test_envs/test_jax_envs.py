@@ -373,10 +373,17 @@ def test_sharded_rollout_matches_unsharded():
     # every [N, ...] leaf landed sharded over the data axis
     assert len(sharded.obs["state"].sharding.device_set) == n_dev
     _, traj_shard, ep_shard = collect(agent, sharded, key)
+    # what the policy network computes in floating point is held to a
+    # tolerance: XLA:CPU orders a partitioned reduction differently from the
+    # unpartitioned one (`logprobs`: 12 of 128 off by 1.19e-07, one unit in
+    # the last place). Observations, actions, rewards and dones stay exact.
+    policy_floats = {"logprobs", "values"}
     for k in traj_plain:
-        np.testing.assert_array_equal(
-            np.asarray(traj_plain[k]), np.asarray(traj_shard[k]), err_msg=k
-        )
+        plain, shard = np.asarray(traj_plain[k]), np.asarray(traj_shard[k])
+        if k in policy_floats:
+            np.testing.assert_allclose(plain, shard, rtol=1e-6, err_msg=k)
+        else:
+            np.testing.assert_array_equal(plain, shard, err_msg=k)
     np.testing.assert_allclose(
         float(ep_plain["return_sum"]), float(ep_shard["return_sum"]), rtol=1e-6
     )

@@ -361,10 +361,8 @@ def test_trace_overhead_within_two_percent(tmp_path):
     realistically sized update. The pattern costs ~40us on this box
     (fast-path JSON + cached kill switch + lazy span flush), so the bound
     is checked against a ~5ms workload — well under the smallest real
-    flock update; the tiny CPU bench configs sit below that floor, which
-    is why `bench.py --telemetry ab`'s trace arm reports a larger (noise-
-    dominated) percentage there. Interleaved pairs + min-of-ratios, same
-    methodology as the telemetry overhead bound."""
+    flock update. Interleaved pairs + min-of-ratios, same methodology as
+    the telemetry overhead bound."""
     a = np.random.default_rng(0).normal(size=(450, 450))
 
     def workload():

@@ -10,7 +10,6 @@ import pytest
 from sheeprl_tpu.nn.recurrent import LayerNormGRUCell
 from sheeprl_tpu.ops import pallas_kernels as pk
 from sheeprl_tpu.ops.distributions import TwoHotEncodingDistribution
-from sheeprl_tpu.ops.math import symexp as symexp_ref, symlog as symlog_ref
 from sheeprl_tpu.ops.math import two_hot
 
 
@@ -104,22 +103,6 @@ def test_two_hot_distribution_paths_agree(pallas_interpret):
     pk.set_pallas(False)
     without = d.log_prob(x)
     np.testing.assert_allclose(np.asarray(with_pallas), np.asarray(without), atol=1e-4)
-
-
-def test_symlog_symexp_kernels(pallas_interpret):
-    x = jnp.asarray(np.linspace(-50, 50, 64, dtype=np.float32).reshape(8, 8))
-    np.testing.assert_allclose(
-        np.asarray(pk.symlog(x)), np.asarray(symlog_ref(x)), atol=1e-6
-    )
-    np.testing.assert_allclose(
-        np.asarray(pk.symexp(x)), np.asarray(symexp_ref(x)), rtol=1e-6
-    )
-    g = jax.grad(lambda v: pk.symlog(v).sum())(x)
-    g_ref = jax.grad(lambda v: symlog_ref(v).sum())(x)
-    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-5)
-    g2 = jax.grad(lambda v: pk.symexp(v).sum())(x)
-    g2_ref = jax.grad(lambda v: symexp_ref(v).sum())(x)
-    np.testing.assert_allclose(np.asarray(g2), np.asarray(g2_ref), rtol=1e-5)
 
 
 def test_pallas_disabled_on_cpu_by_default():

@@ -1,7 +1,7 @@
 """SHEEPRL_TPU_SCAN_UNROLL changes scheduling, not numerics: the unrolled
 RSSM dynamic + imagination scans must produce the SAME losses and updated
-parameters as the plain while-loop on the same batch and seeds (the bench
-keep-decision relies on the configs being interchangeable,
+parameters as the plain while-loop on the same batch and seeds (the
+autotuned choice relies on the configs being interchangeable,
 ops/scan.py)."""
 
 import jax

@@ -175,8 +175,7 @@ def test_the_table_is_the_names_the_kernels_compile_to():
 
     below_the_table = inspect.getsource(pk).split("\n}\n", 1)[1]
     literals = set(re.findall(r'"([a-z0-9_]+_fwd(?:_res)?)"', below_the_table))
-    # the `cnn` row names no kernel any more: it waits for the benchmark's `cnn_kernel_ms` to go (the table's comment)
-    assert literals == set(names) - set(pk.KERNEL_NAMES["cnn"])
+    assert literals == set(names)
 
 
 # ------------------------------------------------------------------ scopes

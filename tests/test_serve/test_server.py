@@ -3,6 +3,7 @@ policy: request/response parity, concurrent load, hot reload with zero
 dropped in-flight requests, deadline shedding, typed rejections."""
 
 import threading
+import time
 
 import jax
 import numpy as np
@@ -58,6 +59,14 @@ def _serving(policy, params, loaders=None, rungs=(1, 2, 4), window_ms=1.0,
     server = ServeServer(policy, store, batcher, bind=bind, telem=telem)
     server.start()
     return server, store
+
+
+def _wait_until(settled, timeout_s=5.0):
+    """The server counts a request and ends its span AFTER the answer is on
+    the wire: a client that holds its answer waits for the bookkeeping."""
+    deadline = time.monotonic() + timeout_s
+    while not settled() and time.monotonic() < deadline:
+        time.sleep(0.005)
 
 
 def _obs(rows, seed=0):
@@ -255,6 +264,7 @@ def test_request_span_decomposition_and_echo(sac_policy):
         with ServeClient(server.address) as client:
             res, meta = client.request(_obs(1))
         assert "span" in meta, meta
+        _wait_until(lambda: rec.of("span"))
         spans = rec.of("span")
         assert len(spans) == 1
         span = spans[0]
@@ -322,6 +332,7 @@ def test_gauges_expose_serving_telemetry(sac_policy):
         with ServeClient(server.address) as client:
             for i in range(5):
                 client.request(_obs(1, seed=i))
+        _wait_until(lambda: server.gauges()["Serve/completed_total"] == 5.0)
         g = server.gauges()
         assert g["Serve/served_total"] == 5.0
         assert g["Serve/completed_total"] == 5.0

@@ -1,6 +1,5 @@
-"""Unit receipts for the ISSUE 3 satellite fixes in tools/ and bench.py:
-process matching in the session-end sweep, the bounded --eval-only path,
-and the ledger's code fingerprint + fresh-vs-re-emitted partial fields."""
+"""Unit receipts for the ISSUE 3 satellite fixes in tools/: process matching
+in the session-end sweep and the bounded --eval-only path."""
 
 import json
 import os
@@ -30,7 +29,7 @@ def test_sweep_matches_only_python_runner_processes():
     assert not _is_runner_cmd("less dv3_pixel_learning_run.py")
     # the sweep itself, and unrelated python work
     assert not _is_runner_cmd("python tools/sweep_runners.py --dry-run")
-    assert not _is_runner_cmd("python bench.py --tiny")
+    assert not _is_runner_cmd("python -m benchmark.run --workload x")
     assert not _is_runner_cmd("python -m pytest tests/")
     assert not _is_runner_cmd("")
 
@@ -85,43 +84,3 @@ def test_run_eval_bounded_crash_lands_stub(tmp_path):
     )
     assert result["status"] == "stub_no_eval"
     assert "no checkpoint" in result["eval_error"]
-
-
-# ---------------------------------------------------------------------------
-# bench ledger: code fingerprint + fresh/re-emitted partial fields
-# ---------------------------------------------------------------------------
-
-
-def test_ledger_meta_carries_code_fingerprint(tmp_path, monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-
-    fp = bench._code_fingerprint()
-    assert fp and fp != "unknown"
-
-    path = str(tmp_path / "ledger.json")
-    led = bench.PhaseLedger(path, {"algo": "t"})
-    assert led.meta["code"] == fp
-    led.complete("A", {"on": [1.0]}, {"value": 1.0})
-    assert led.measured_this_run == ["A"]
-    assert led.headline["phases_measured_this_run"] == ["A"]
-    assert led.headline["resumed_from_sidecar"] is False
-
-    # same code: resume loads the phase, flags the sidecar origin
-    led2 = bench.PhaseLedger(path, {"algo": "t"})
-    assert led2.done("A")
-    assert led2.resumed_from_sidecar is True
-    led2.set_headline({"value": 1.0})
-    assert led2.headline["resumed_from_sidecar"] is True
-    assert led2.headline["phases_measured_this_run"] == []
-
-    # stale code: a sidecar written under a different fingerprint is
-    # discarded (ADVICE r5 — no SHEEPRL_TPU_BENCH_FRESH needed)
-    with open(path) as fh:
-        data = json.load(fh)
-    data["meta"]["code"] = "deadbeef0000"
-    with open(path, "w") as fh:
-        json.dump(data, fh)
-    led3 = bench.PhaseLedger(path, {"algo": "t"})
-    assert not led3.done("A")
-    assert led3.resumed_from_sidecar is False

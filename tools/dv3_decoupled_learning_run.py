@@ -155,7 +155,7 @@ def _evaluate(root: Path) -> dict:
         "returns": returns,
         "mean_return": float(np.mean(returns)),
         "global_step_restored": int(restored["global_step"]),
-        "coupled_twin_result": "greedy mean 408.5 (same recipe, BENCHES.md)",
+        "coupled_twin_result": "greedy mean 408.5 (same recipe)",
     }
 
 

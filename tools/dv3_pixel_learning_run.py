@@ -2,8 +2,8 @@
 
 Every prior return receipt is vector-obs; the north star is pixel IQM
 parity, so this runner trains tiny DreamerV3 on **dmc_cartpole_balance
-pixels** (64x64 rgb through the real DMC wrapper + conv encoder/decoder —
-BASELINE config 4's shape at CartPole scale) long enough to beat the
+pixels** (64x64 rgb through the real DMC wrapper + conv encoder/decoder,
+the DMC pixel recipe's shape at CartPole scale) long enough to beat the
 random policy by a wide margin, then greedily evaluates the checkpoint.
 
 Env choice (revised after the balance attempts): **swingup**, not balance.

@@ -2,7 +2,7 @@
 """sheeplint — static JAX/TPU hazard linter for this repo (ISSUE 3).
 
 Usage:
-    python tools/sheeplint.py sheeprl_tpu/ tools/ bench.py
+    python tools/sheeplint.py sheeprl_tpu/ tools/
     python tools/sheeplint.py --list-rules
     python tools/sheeplint.py --select SL001,SL002 sheeprl_tpu/
     python tools/sheeplint.py --format json sheeprl_tpu/ | jq .
@@ -11,8 +11,8 @@ Exit codes: 0 clean, 1 violations found, 2 usage/parse error.
 
 The rule catalog, severities, and suppression syntax
 (`# sheeplint: disable=SL002 — why`) live in sheeprl_tpu/analysis/rules.py
-and howto/static_analysis.md. CI runs this over `sheeprl_tpu/ tools/
-bench.py` and fails the build on any new violation.
+and howto/static_analysis.md. CI runs this over `sheeprl_tpu/ tools/` and
+fails the build on any new violation.
 
 Pure AST analysis: no jax import, no module execution — safe to run
 anywhere, including pre-commit.

@@ -61,7 +61,7 @@ from sheeprl_tpu.analysis import jaxpr_check as jc  # noqa: E402
 from sheeprl_tpu.analysis import shard_check as sc  # noqa: E402
 
 DEFAULT_BUDGET = str(_REPO / "analysis" / "budget.json")
-SOURCE_PATHS = ("sheeprl_tpu", "tools", "bench.py")
+SOURCE_PATHS = ("sheeprl_tpu", "tools")
 
 
 def main(argv: list[str] | None = None) -> int:

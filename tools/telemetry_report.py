@@ -846,7 +846,7 @@ def render(summary: dict) -> str:
                     widths,
                 ))
         # Staleness distribution across every logged interval, not just the
-        # last gauge value — the number the bench round cares about.
+        # last gauge value.
         all_stale = [v for vs in summary["flock_staleness"].values() for v in vs]
         if all_stale:
             s = sorted(all_stale)

@@ -844,7 +844,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             telem.mark("train/dispatch")
             for i in range(n_samples):
                 tau = 1.0 if gradient_steps % args.critic_target_network_update_freq == 0 else 0.0
-                sample = {k: v[i] for k, v in staged.items()}
+                sample = staged[i]
                 if n_dev > 1:
                     sample = shard_time_batch(sample, mesh, time_axis=0, batch_axis=1)
                 key, train_key = jax.random.split(key)

@@ -1291,13 +1291,13 @@ def main(argv: Sequence[str] | None = None) -> None:
                     tau = 1.0 if gradient_steps == 0 else args.critic_tau
                 else:
                     tau = 0.0
-                # two spans a train step: the staged block's row (`v[i]`: a
-                # dozen tiny programs) apart from the step's own enqueue. With
-                # several steps an iteration the runtime holds the host back
-                # inside a later row's dispatches until a program ahead of
-                # them ends; one span over the loop hid that as dispatch time
+                # two spans a train step: what is left to do on its row apart
+                # from the step's own enqueue. `stage_batch` cut every row on
+                # the device in one program (inside `buffer/stage`), so this
+                # one enqueues nothing on one device and holds the row's
+                # re-sharding on several
                 telem.mark("train/slice", phase="train/dispatch")
-                sample = {k: v[i] for k, v in staged.items()}
+                sample = staged[i]
                 if n_dev > 1:
                     sample = shard_time_batch(sample, mesh, time_axis=0, batch_axis=1)
                 telem.mark("train/dispatch")

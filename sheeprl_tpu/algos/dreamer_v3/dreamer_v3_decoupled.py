@@ -461,16 +461,16 @@ def main(argv: Sequence[str] | None = None) -> None:
             )
             staged = stage_batch(local_data, to_host=jax.process_count() > 1)
             telem.mark("host_to_device")
-            # ship the whole [n_samples, T, B] block to the trainer mesh,
+            # ship the block's n_samples [T, B] rows to the trainer mesh,
             # batch axis sharded (the data path — ICI, typed pytree)
-            staged = meshes.to_trainers(staged, axis=2)
+            staged = meshes.to_trainers(staged, axis=1)
             telem.mark("train/dispatch")
             for i in range(n_samples):
                 if gradient_steps % args.critic_target_network_update_freq == 0:
                     tau = 1.0 if gradient_steps == 0 else args.critic_tau
                 else:
                     tau = 0.0
-                sample = {k: v[i] for k, v in staged.items()}
+                sample = staged[i]
                 key, train_key = jax.random.split(key)
                 sample = resilience.poison_batch(sample, global_step)  # nan.* sites
                 state, metrics = train_step(state, sample, train_key, jnp.float32(tau))

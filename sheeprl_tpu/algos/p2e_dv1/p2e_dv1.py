@@ -835,7 +835,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             staged = stage_batch(local_data, to_host=jax.process_count() > 1)
             telem.mark("train/dispatch")
             for i in range(n_samples):
-                sample = {k: v[i] for k, v in staged.items()}
+                sample = staged[i]
                 if n_dev > 1:
                     sample = shard_time_batch(sample, mesh, time_axis=0, batch_axis=1)
                 key, train_key = jax.random.split(key)

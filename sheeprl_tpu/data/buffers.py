@@ -66,38 +66,55 @@ Batch = dict[str, np.ndarray]
 DeviceBatch = dict[str, jax.Array]
 
 
+def _rows(block: Mapping[str, "np.ndarray | jax.Array"]) -> list[dict]:
+    """`block[k][i]` for every row `i` of a `[n_samples, ...]` block."""
+    n_samples = next(iter(block.values())).shape[0]
+    return [{k: v[i] for k, v in block.items()} for i in range(n_samples)]
+
+
+@jax.jit
+def _cut_rows(block: DeviceBatch) -> list[DeviceBatch]:
+    return _rows(
+        {
+            k: v if v.dtype == jnp.uint8 else v.astype(jnp.float32)
+            for k, v in block.items()
+        }
+    )
+
+
 def stage_batch(
     local_data: Mapping[str, "np.ndarray | jax.Array"], *, to_host: bool = False
-) -> "Batch | DeviceBatch":
-    """Stage a sampled `[n_samples, ...]` block for the gradient loop in ONE
-    conversion per key: uint8 preserved (pixels normalize on device inside
-    the train step), everything else cast to f32.
+) -> "list[Batch] | list[DeviceBatch]":
+    """Cut a sampled `[n_samples, ...]` block into the gradient loop's
+    `n_samples` rows, one dict a train step: uint8 preserved (pixels
+    normalize on device inside the train step), everything else cast to f32.
 
-    Default (`to_host=False`): the block lands on device, the Dreamer mains
-    index it per gradient step (`v[i]`), so the row slice happens on device
-    and the host->device DMA overlaps the in-flight update via JAX async
-    dispatch — replacing a per-row transfer that serialized host staging
-    with device compute (the reference moves rows eagerly per step,
-    dreamer_v3.py:635-646). The whole block lives in HBM for the duration
-    of the loop — the same arrays a device-storage buffer already gathered.
+    Default (`to_host=False`): ONE compiled program per block casts and cuts
+    every row on device (`_cut_rows`; `n_samples` is the block's leading
+    dimension, so a block shape compiles once and no index travels). The
+    gradient loop then only picks `rows[i]`: no program is enqueued between
+    two train steps. Cutting the rows eagerly, a step at a time (`v[i]`), is
+    a `slice` and a `squeeze` per key: ten enqueues of ~0.7 ms a train step
+    with the chip idle (7.3 ms of a 38.6 ms iteration on the v5e's machine,
+    PERF.md PR 34). A host block (host/memmap storage) rides the same call:
+    its host->device DMA overlaps the in-flight update via JAX async
+    dispatch. Block and rows live in HBM together until the caller drops
+    the block; the rows are copies, as `v[i]`'s results were.
 
     `to_host=True` is for multi-process runs: `shard_batch`'s
     `make_array_from_process_local_data` path needs host numpy per row, so
     staging pulls the block to host once (one d2h for device-storage
-    buffers) instead of paying a synchronous per-row device round-trip."""
-    if to_host:
-        return {
+    buffers) and the rows are numpy views of it."""
+    if not to_host:
+        return _cut_rows(dict(local_data))
+    return _rows(
+        {
             k: np.asarray(v).astype(
                 np.float32 if v.dtype != np.uint8 else np.uint8, copy=False
             )
             for k, v in local_data.items()
         }
-    return {
-        k: jnp.asarray(v).astype(
-            jnp.float32 if v.dtype != np.uint8 else jnp.uint8
-        )
-        for k, v in local_data.items()
-    }
+    )
 
 
 def _as_time_env(data: Mapping[str, np.ndarray]) -> Batch:

@@ -3,6 +3,7 @@
 oversized inserts, sample-validity windows, memmap variants — for both the
 HBM (device) and host storage backends."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +13,9 @@ from sheeprl_tpu.data import (
     EpisodeBuffer,
     ReplayBuffer,
     SequentialReplayBuffer,
+    stage_batch,
 )
+from sheeprl_tpu.telemetry.compile_tracker import CompileTracker
 
 STORAGES = ["device", "host"]
 
@@ -856,3 +859,70 @@ ENTRY %main.7 (store.1: {ring}, idx.1: s32[32]) -> {ring} {{
     assert len(found) == 2 and "copy.8" in found[0] and "temporaries" in found[1]
     rep["add"].update(aliased_parameters=[], ring_sized=[], temp_bytes=0)
     assert "aliases 0 of 1" in store_check.faults(rep)[0]
+
+
+# ---- stage_batch: the sampled block cut into the gradient loop's rows --------
+
+@pytest.fixture
+def compiles():
+    """The package's compile counter (`jax.monitoring`'s backend-compile
+    events, a cache load among them): `flush()["compiles"]` since the last."""
+    tracker = CompileTracker().attach()
+    yield tracker
+    tracker.detach()
+
+
+def sampled_block(n_samples, width):
+    """A `[n_samples, T, B, ...]` block with every dtype a ring or a host
+    buffer hands over: uint8 pixels, float32, and a float64 and an int64
+    leaf that staging casts. `width` makes the shapes the case's own, so the
+    first call meets a cold jit cache."""
+    rng = np.random.default_rng(n_samples * 100 + width)
+    lead = (n_samples, 3, 2)
+    return {
+        "rgb": rng.integers(0, 256, (*lead, width, 4, 3), dtype=np.uint8),
+        "actions": rng.standard_normal((*lead, width)).astype(np.float32),
+        "rewards": rng.standard_normal((*lead, 1)),  # float64
+        "dones": rng.integers(0, 2, (*lead, 1)),  # int64
+    }
+
+
+@pytest.mark.parametrize("n_samples", [1, 4])
+@pytest.mark.parametrize("on_device", [False, True], ids=["numpy", "jax_array"])
+@pytest.mark.parametrize("to_host", [False, True], ids=["device_rows", "host_rows"])
+def test_stage_batch_rows_equal_the_blocks_rows(n_samples, on_device, to_host):
+    block = sampled_block(n_samples, width=5)
+    given = {k: jnp.asarray(v) for k, v in block.items()} if on_device else block
+    rows = stage_batch(given, to_host=to_host)
+    assert len(rows) == n_samples
+    for i, row in enumerate(rows):
+        assert set(row) == set(block)
+        for k, v in row.items():
+            assert isinstance(v, np.ndarray if to_host else jax.Array), (k, type(v))
+            want = np.uint8 if k == "rgb" else np.float32
+            assert v.dtype == want and v.shape == block[k].shape[1:]
+            np.testing.assert_array_equal(np.asarray(v), block[k][i].astype(want))
+    if to_host and not on_device:
+        # a host block's rows are views: nothing is copied a train step
+        assert all(np.shares_memory(rows[i][k], block[k]) for i in range(n_samples) for k in ("rgb", "actions"))
+
+
+@pytest.mark.parametrize("n_samples", [1, 4])
+@pytest.mark.parametrize("on_device", [False, True], ids=["numpy", "jax_array"])
+def test_stage_batch_is_one_program_compiled_once(n_samples, on_device, compiles):
+    """Cut eagerly, a row is a `slice` and a `squeeze` per key: 2 x keys
+    programs a train step. One program cuts the whole block, and a second
+    block of the same shapes compiles nothing."""
+    width = 7 + 2 * on_device  # shapes no other case has compiled
+    block = sampled_block(n_samples, width)
+    given = {k: jnp.asarray(v) for k, v in block.items()} if on_device else block
+    compiles.flush()
+    rows = stage_batch(given)
+    jax.block_until_ready(rows)
+    assert compiles.flush()["compiles"] == 1
+    for _ in range(3):
+        rows = stage_batch(given)
+        _ = [rows[i] for i in range(n_samples)]  # what the gradient loop does
+    jax.block_until_ready(rows)
+    assert compiles.flush()["compiles"] == 0
+    np.testing.assert_array_equal(np.asarray(rows[-1]["rgb"]), block["rgb"][-1])

@@ -19,6 +19,12 @@ children's own records (`telemetry*.jsonl`), never from its own beliefs:
                            own compiler at the benchmark cells' shapes
                            (3.9 GiB of pixels, nothing allocated): no
                            ring-sized copy, temporaries under 1 % of the ring
+  phase E  expert product  the routed expert layer (`nn/moe.py`) at a published
+                           width (hidden 2048, 16 of 128 experts of 768 held,
+                           top-8, 16,384 tokens in bfloat16): the grouped
+                           product as the chip's compiler lowers
+                           `jax.lax.ragged_dot` agrees with every held expert
+                           multiplied densely, and drops no token
   phase C  server          `python -m sheeprl_tpu serve --algo sac` at the
                            default SAC widths answers sequential, multi-row
                            and concurrent requests from a `ServeClient` here;
@@ -306,6 +312,49 @@ def phase_replay_programs() -> None:
         check(not rep["faults"], f"replay programs, {what}: {rep['faults']}")
 
 
+# --------------------------------------------------------------------------- phase E
+
+
+EXPERT_PRODUCT = """
+import json, time, jax, jax.numpy as jnp
+from sheeprl_tpu.nn.moe import RoutedExperts
+layer = RoutedExperts.init(jax.random.PRNGKey(0), 2048, 768, 128, 8, held=16)
+x = jax.random.normal(jax.random.PRNGKey(1), (16384, 2048), jnp.bfloat16)
+run = jax.jit(lambda m, v: m(v))
+y, counts = jax.block_until_ready(run(layer, x))
+t0 = time.perf_counter()
+for _ in range(10):
+    out = run(layer, x)
+jax.block_until_ready(out)
+ms = 1e3 * (time.perf_counter() - t0) / 10
+weights, picks = layer.route(x[:512])
+w = jnp.zeros((512, 128)).at[jnp.arange(512)[:, None], picks].set(weights)[:, :16]
+up = lambda m: m.astype(jnp.bfloat16).astype(jnp.float32)
+with jax.default_matmul_precision("highest"):
+    r = x[:512].astype(jnp.float32)
+    hidden = jax.nn.silu(jnp.einsum("th,ehf->etf", r, up(layer.w_gate))) * jnp.einsum("th,ehf->etf", r, up(layer.w_up))
+    want = jnp.einsum("te,etf,efh->th", w, hidden, up(layer.w_down))
+gap = float(jnp.sqrt(jnp.mean((y[:512].astype(jnp.float32) - want) ** 2)) / jnp.sqrt(jnp.mean(want ** 2)))
+print("EXPERT_PRODUCT " + json.dumps({"ms": ms, "assignments": int(counts.sum()), "fullest": int(counts.max()), "gap": gap,
+                                     "picked_held": int(((picks >= 0) & (picks < 16)).sum()), "counted": int(run(layer, x[:512])[1].sum())}))
+"""
+
+
+def phase_expert_product() -> None:
+    """One child runs the routed expert layer's grouped product on the chip."""
+    say("== phase E: the routed expert layer's grouped product")
+    proc = subprocess.run([sys.executable, "-c", EXPERT_PRODUCT], cwd=ROOT, env=child_env(), capture_output=True, text=True, timeout=600)
+    lines = [json.loads(l[len("EXPERT_PRODUCT "):]) for l in proc.stdout.splitlines() if l.startswith("EXPERT_PRODUCT ")]
+    if not check(proc.returncode == 0 and len(lines) == 1, f"expert product: child rc={proc.returncode}\n{proc.stderr[-2000:]}"):
+        return
+    rep = lines[0]
+    say(f"  16 of 128 experts held, 16384 tokens x top-8: {rep['assignments']} assignments held (fullest expert {rep['fullest']}), "
+        f"{rep['ms']:.3f} ms a call by jax.lax.ragged_dot (no kernel family of this repo's: no kernel.select verdict); "
+        f"gap to the dense product {rep['gap']:.4f}")
+    check(rep["gap"] < 0.02, f"expert product: gap to the dense product {rep['gap']}")
+    check(rep["counted"] == rep["picked_held"], f"expert product: {rep['picked_held']} assignments picked held experts, {rep['counted']} were multiplied")
+
+
 # --------------------------------------------------------------------------- phase C
 
 
@@ -465,6 +514,7 @@ def main() -> None:
         results["dv3_f32"] = phase_trainer("dv3_f32", [], device)
         results["dv3_bf16"] = phase_trainer("dv3_bf16", ["--precision", "bfloat16"], device)
         phase_replay_programs()
+        phase_expert_product()
         results["serve_f32"] = phase_server("serve_f32", [], device, None)
         results["serve_int8"] = phase_server(
             "serve_int8", ["--quant", "int8"], device, results["serve_f32"].get("reference"),

@@ -7,6 +7,7 @@ _ALGO_MODULES = [
     "sheeprl_tpu.algos.ppo.ppo",
     "sheeprl_tpu.algos.ppo.ppo_decoupled",
     "sheeprl_tpu.algos.ppo_recurrent.ppo_recurrent",
+    "sheeprl_tpu.algos.ppo_bd.ppo_bd",
     "sheeprl_tpu.algos.sac.sac",
     "sheeprl_tpu.algos.sac.sac_decoupled",
     "sheeprl_tpu.algos.droq.droq",

@@ -836,6 +836,16 @@ CAPTURE_ARGV: dict[str, list[str]] = {
         "--dense_units", "8",
         "--mlp_layers", "1",
     ],
+    "ppo_bd": [
+        "--env_id", "TokenTask-v0",
+        "--num_envs", "4",
+        "--sync_env",
+        "--dry_run",
+        "--hidden_size", "32",
+        "--head_dim", "8",
+        "--num_hidden_layers", "1",
+        "--moe_intermediate_size", "16",
+    ],
     "sac": ["--num_devices", "1", *_SAC_TINY],
     "sac_decoupled": ["--num_devices", "2", *_SAC_TINY],
     "droq": ["--num_devices", "1", *_SAC_TINY],
@@ -923,6 +933,7 @@ CAPTURE_VARIANTS: dict[str, tuple[str, list[str]]] = {
         "ppo",
         "ppo_decoupled",
         "ppo_recurrent",
+        "ppo_bd",
         "sac",
         "sac_decoupled",
         "droq",

@@ -1,6 +1,8 @@
 from .core import Activation, Module, activation, field, static
 from .layers import Conv2d, ConvTranspose2d, LayerNorm, Linear, dropout
 from .blocks import CNN, DeCNN, MLP, MultiDecoder, MultiEncoder, NatureCNN
+from .attention import Attention, RMSNorm, block_causal_mask, rope
+from .moe import RoutedExperts
 from .recurrent import GRUCell, LayerNormGRUCell, LSTMCell, scan_cell
 from .inits import init_kaiming_normal, init_orthogonal, map_layers
 
@@ -21,6 +23,11 @@ __all__ = [
     "NatureCNN",
     "MultiEncoder",
     "MultiDecoder",
+    "RMSNorm",
+    "Attention",
+    "rope",
+    "block_causal_mask",
+    "RoutedExperts",
     "GRUCell",
     "LayerNormGRUCell",
     "LSTMCell",

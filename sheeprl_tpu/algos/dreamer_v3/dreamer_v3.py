@@ -80,7 +80,7 @@ from ...utils.evaluation import (
 )
 from ...utils.env import make_dict_env
 from ...utils.logger import create_logger
-from ...utils.metric import MetricAggregator
+from ...utils.metric import MetricAggregator, packed_metrics
 from ...utils.profiler import StepProfiler
 from ...utils.parser import DataclassArgumentParser
 from ...utils.registry import register_algorithm
@@ -483,6 +483,10 @@ def make_train_step(
     # --on_nonfinite skip/rollback: donation-safe nonfinite select around
     # the unjitted body (default 'warn' is identity - zero jaxpr drift)
     train_step = resilience.guard_nonfinite(train_step, args.on_nonfinite)
+    # the metric scalars leave the program as ONE f32 vector: the host pulls
+    # one array a train step, not one per metric (utils/metric.py); the skip
+    # flag stays its own output for `update_skipped`'s lagged read
+    train_step = packed_metrics(train_step, loose=(resilience.SKIP_FLAG,))
     return donating_jit(train_step, donate_argnums=(0,))
 
 
@@ -1248,6 +1252,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                 # (reference dreamer_v3.py:609-628)
                 telem.mark("rollout/reset", phase="rollout")
                 n_reset = len(dones_idxes)
+                telem.count(rows=n_reset)
                 reset_data = {k: real_next_obs[k][dones_idxes][None] for k in obs_keys}
                 reset_data["dones"] = np.ones((1, n_reset, 1), np.float32)
                 reset_data["actions"] = np.zeros(
@@ -1344,6 +1349,7 @@ def main(argv: Sequence[str] | None = None) -> None:
         # which blocks on the iteration's last train step
         telem.mark("log/pull", phase="log")
         drains = pipe.drain_metrics(aggregator, global_step)
+        telem.count(arrays=aggregator.arrays)
         telem.mark("log/write", phase="log")
         # `Time/step_per_second` rides the event of its own step: an iteration
         # is one event for the logger's writer, not two (--pipeline on drains

@@ -185,6 +185,13 @@ def _unpack_values(direct, packed, layout):
     return data
 
 
+def _pad_columns(value: np.ndarray, pad: int) -> np.ndarray:
+    """`[T, k, *item]` -> `[T, k + pad, *item]`, the added columns zero."""
+    value = np.asarray(value)
+    zeros = np.zeros((value.shape[0], pad, *value.shape[2:]), value.dtype)
+    return np.concatenate([value, zeros], axis=1)
+
+
 def _encode_sample_state(state) -> np.ndarray:
     """Sampler-PRNG snapshot as a JSON byte buffer for `.npz` embedding
     (ISSUE 12): a resumed run continues the EXACT sample stream the
@@ -1417,7 +1424,17 @@ class AsyncReplayBuffer:
         if self._store is None:
             self._allocate_store(data)
         starts = self._upos[cols]
-        self._store = self._packed_scatter(data, starts, cols, data_len)
+        if cols.size < self._n_envs:
+            # one program for a reset add of any width: widen it to every
+            # column; the added columns name the env index one past the ring,
+            # so the scatter drops them (jax's default out-of-bounds mode)
+            pad = self._n_envs - cols.size
+            data = {k: _pad_columns(v, pad) for k, v in data.items()}
+            scatter_starts = np.concatenate([starts, np.zeros(pad, np.int64)])
+            scatter_cols = np.concatenate([cols, np.full(pad, self._n_envs, np.int64)])
+        else:
+            scatter_starts, scatter_cols = starts, cols
+        self._store = self._packed_scatter(data, scatter_starts, scatter_cols, data_len)
         self._ufull[cols] |= starts + data_len >= self._buffer_size
         self._upos[cols] = (starts + data_len) % self._buffer_size
         self._epoch += 1

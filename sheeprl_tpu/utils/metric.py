@@ -4,32 +4,150 @@ updated every step and computed/reset once per logging interval, plus a
 windowed moving-average metric. Values may be jax scalars — they are pulled
 to host lazily at compute() time, so updating inside the hot loop never
 forces a device sync; compute() first issues ONE overlapping async
-device->host copy per pending device value, so the N pulls of a compute
-over N train metrics overlap instead of running one after another."""
+device->host copy per pending device array, so the N pulls of a compute
+over N train metrics overlap instead of running one after another.
+
+A compiled step may hand its scalars over as ONE vector (`PackedScalars`,
+built inside the jit by `packed_metrics`): its names are `_Lane`s that
+share the vector's one copy and one conversion, so an interval's pull is
+one array a train step, not one per metric."""
 
 from __future__ import annotations
 
+import functools
 from collections import deque
-from typing import Any
+from collections.abc import Mapping
+from typing import Any, Callable, Iterable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["MetricAggregator", "MovingAverageMetric", "PendingMetrics"]
+__all__ = [
+    "MetricAggregator",
+    "MovingAverageMetric",
+    "PackedScalars",
+    "PendingMetrics",
+    "packed_metrics",
+]
 
 
-def _prefetch(values) -> None:
-    """Start async device->host copies for any jax arrays so the subsequent
-    float() conversions find the transfer already in flight: issuing all
-    copies first overlaps the blocking pulls."""
+@jax.tree_util.register_pytree_node_class
+class PackedScalars(Mapping):
+    """The named scalar metrics of one compiled step as ONE float32 vector
+    (`values[i]` is `names[i]`, names sorted as a jit's dict output sorts
+    them), beside the arrays that stay their own (`loose`: resilience's skip
+    flag, which `update_skipped` pops and reads one update lagged). A pytree
+    whose leaves are the vector and the loose arrays and whose names are
+    static, so a jit returns it; on the host `self[name]` is a `_Lane`."""
+
+    __slots__ = ("names", "values", "loose", "_host")
+
+    def __init__(self, names: tuple[str, ...], values, loose: dict | None = None) -> None:
+        self.names = names
+        self.values = values
+        self.loose = dict(loose or {})
+        self._host: np.ndarray | None = None
+
+    @classmethod
+    def pack(cls, metrics: Mapping[str, Any], loose: Iterable[str] = ()) -> "PackedScalars":
+        """Inside the jit: every scalar of `metrics` but the `loose` keys
+        into one float32 vector."""
+        kept = {k: metrics[k] for k in loose if k in metrics}
+        names = tuple(sorted(k for k in metrics if k not in kept))
+        for k in names:
+            if jnp.ndim(metrics[k]) != 0:
+                raise ValueError(f"metric {k!r} is not a scalar: shape {jnp.shape(metrics[k])}")
+        values = jnp.stack([jnp.asarray(metrics[k], jnp.float32) for k in names])
+        return cls(names, values, kept)
+
+    def tree_flatten(self):
+        return (self.values, self.loose), self.names
+
+    @classmethod
+    def tree_unflatten(cls, names, children):
+        return cls(names, *children)
+
+    def copy_to_host_async(self) -> None:
+        if self._host is None:
+            self.values.copy_to_host_async()
+
+    def host(self) -> np.ndarray:
+        """The vector on the host: converted once, on first use."""
+        if self._host is None:
+            self._host = np.asarray(self.values)
+        return self._host
+
+    def __getitem__(self, name: str):
+        if name in self.loose:
+            return self.loose[name]
+        try:
+            return _Lane(self, self.names.index(name))
+        except ValueError:
+            raise KeyError(name) from None
+
+    def __iter__(self):
+        yield from self.names
+        yield from self.loose
+
+    def __len__(self) -> int:
+        return len(self.names) + len(self.loose)
+
+    def pop(self, name: str, *default):
+        """Take a loose array out (`resilience.update_skipped`); the packed
+        names stay in their vector."""
+        return self.loose.pop(name, *default)
+
+
+class _Lane:
+    """One named scalar of a `PackedScalars` on the host: `float()` and
+    `np.asarray()` read it off the vector's one conversion."""
+
+    __slots__ = ("packed", "index")
+
+    def __init__(self, packed: PackedScalars, index: int) -> None:
+        self.packed = packed
+        self.index = index
+
+    def __float__(self) -> float:
+        return float(self.packed.host()[self.index])
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        return np.asarray(self.packed.host()[self.index], dtype=dtype)
+
+
+def packed_metrics(body: Callable[..., tuple], loose: Iterable[str] = ()) -> Callable[..., tuple]:
+    """Wrap an unjitted step `(state, *args) -> (state, metrics)` so that
+    its metrics leave the compiled program as one `PackedScalars` (the
+    `loose` keys apart). Keeps the body's name: the jit's module name is
+    what the trace readers match."""
+    loose = tuple(loose)
+
+    @functools.wraps(body)
+    def packed(*args):
+        state, metrics = body(*args)
+        return state, PackedScalars.pack(metrics, loose)
+
+    return packed
+
+
+def _prefetch(values) -> int:
+    """Start one async device->host copy per device array among `values`
+    (the lanes of one vector share its copy) so that the conversions after
+    find the transfers in flight. Returns the number of arrays."""
+    arrays = {}
     for v in values:
-        copy_async = getattr(v, "copy_to_host_async", None)
-        if copy_async is not None:
-            try:
-                copy_async()
-            # sheeplint: disable=SL012 — prefetch-only path; compute()'s
-            # blocking pull is the correctness path and raises for real
-            except Exception:
-                pass  # fall back to the blocking pull in compute
+        src = v.packed if isinstance(v, _Lane) else v
+        if hasattr(src, "copy_to_host_async"):
+            arrays[id(src)] = src
+    for a in arrays.values():
+        try:
+            a.copy_to_host_async()
+        # sheeplint: disable=SL012 — prefetch-only path; compute()'s
+        # blocking pull is the correctness path and raises for real
+        except Exception:
+            pass  # fall back to the blocking pull in compute
+    return len(arrays)
 
 
 class _Snapshot:
@@ -122,6 +240,9 @@ class MovingAverageMetric:
 class MetricAggregator:
     def __init__(self, metrics: dict[str, Any] | None = None) -> None:
         self.metrics: dict[str, Any] = metrics if metrics is not None else {}
+        # device arrays the last compute() / snapshot() pulled (the mains'
+        # `log/pull` counter): one a train step for a packed step's metrics
+        self.arrays = 0
 
     def add(self, name: str, metric: Any | None = None) -> None:
         if name in self.metrics:
@@ -148,7 +269,7 @@ class MetricAggregator:
 
     def compute(self) -> dict[str, float]:
         # overlap all pending device pulls before the blocking conversions
-        _prefetch(
+        self.arrays = _prefetch(
             v
             for metric in self.metrics.values()
             for v in getattr(metric, "pending", list)()
@@ -173,7 +294,7 @@ class MetricAggregator:
                 snaps[name] = snap_fn()
             else:
                 self._flatten(name, metric.compute(), eager)
-        _prefetch(v for s in snaps.values() for v in s.values)
+        self.arrays = _prefetch(v for s in snaps.values() for v in s.values)
         return PendingMetrics(snaps, eager)
 
     def reset(self, force: bool = False) -> None:

@@ -926,3 +926,48 @@ def test_stage_batch_is_one_program_compiled_once(n_samples, on_device, compiles
     jax.block_until_ready(rows)
     assert compiles.flush()["compiles"] == 0
     np.testing.assert_array_equal(np.asarray(rows[-1]["rgb"]), block["rgb"][-1])
+
+
+# ---- a reset add: k of n_envs columns, one program for every k ---------------
+
+def reset_rows(width, base):
+    """`width` columns of one step: pixels stored lane-dense, and a float."""
+    item = (4, 4, 3)
+    rgb = (np.arange(width * int(np.prod(item))).reshape(1, width, *item) + base) % 251
+    return {"rgb": rgb.astype(np.uint8), "rewards": np.full((1, width, 1), base, np.float32)}
+
+
+@pytest.mark.parametrize("k", [1, 2, 16])
+def test_a_reset_add_writes_the_old_scatters_bytes_with_one_program(k, compiles):
+    """An add of the k environments that ended an episode is widened to all
+    16 columns, the added ones dropped by the scatter: the ring, `_upos` and
+    `_ufull` equal what the k-column scatter left, and no width compiles a
+    program of its own."""
+    n_envs, capacity = 16, 5
+    new, old = (AsyncReplayBuffer(capacity, n_envs=n_envs) for _ in range(2))
+    for t in range(4):  # a ring about to wrap
+        for rb in (new, old):
+            rb.add(reset_rows(n_envs, 10 * t))
+    cols = np.sort(np.random.default_rng(k).choice(n_envs, k, replace=False))
+    for rb in (new, old):
+        rb._upos[cols[: k // 2]] = capacity - 1  # some columns wrap, some fill
+    data = reset_rows(k, 200)
+    jax.block_until_ready(new._store)
+    compiles.flush()
+    new.add(data, cols.tolist())
+    jax.block_until_ready(new._store)
+    assert compiles.flush()["compiles"] == 0  # the full-width add's program
+    # the k-column scatter the add was before
+    starts = old._upos[cols]
+    old._store = old._packed_scatter(data, starts, cols, 1)
+    old._ufull[cols] |= starts + 1 >= capacity
+    old._upos[cols] = (starts + 1) % capacity
+    for key in old._store:
+        np.testing.assert_array_equal(np.asarray(new._store[key]), np.asarray(old._store[key]), err_msg=key)
+    np.testing.assert_array_equal(new._upos, old._upos)
+    np.testing.assert_array_equal(new._ufull, old._ufull)
+    compiles.flush()  # the k-column scatter compiled its own
+    for width in (1, 2, 7, 15):  # every other width meets the same program
+        new.add(reset_rows(width, width), list(range(width)))
+    jax.block_until_ready(new._store)
+    assert compiles.flush()["compiles"] == 0

@@ -22,7 +22,9 @@ picked per token. The pick is an index: the gate gets no gradient through it
     which keeps every loop's keys and values), each (loop, layer) recomputed
     in the backward pass;
   - `decode`: one token an environment against the R x N caches (a policy step),
-    writing that token's keys and values into every one of them.
+    writing that token's keys and values into every one of them, and reading
+    each row's cache only up to its own position where the `decode_attn`
+    kernel is selected (`ops/pallas_kernels.py`; the XLA twin reads every slot).
 """
 
 from __future__ import annotations
@@ -32,8 +34,9 @@ import jax.numpy as jnp
 
 from ... import nn
 from ...nn.attention import NEG, Attention, RMSNorm
+from ...ops import pallas_kernels as pk
 
-__all__ = ["Layer", "LoopedLM", "LMState", "build_policy", "player_copy", "exit_loop"]
+__all__ = ["Layer", "LoopedLM", "LMState", "build_policy", "player_copy", "exit_loop", "decode_attend", "read_block"]
 
 
 def _dense(key, n_in: int, n_out: int) -> jax.Array:
@@ -77,6 +80,30 @@ def causal_attend(attn: Attention, q, k, v) -> jax.Array:
     causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
     weights = jax.nn.softmax(jnp.where(causal, attn.scores(q, k), NEG), axis=-1)
     return attn.mix(weights, v)
+
+
+def decode_attend(attn: Attention, q, cache_k, cache_v, pos, kernel: bool) -> jax.Array:
+    """One token a row over its own cache's slots 0..pos: q [envs, 1, heads, D]
+    turned, cache_k and cache_v [envs, S_max, kv heads, D] -> [envs, 1, heads * D].
+    The kernel reads only the blocks that hold those slots; the XLA twin reads
+    every slot and masks the ones past `pos`."""
+    if kernel:
+        return pk.decode_attention(q[:, 0], cache_k, cache_v, pos)[:, None]
+    seen = jnp.arange(cache_k.shape[1])[None, :] <= pos[:, None]  # [envs, S_max]
+    s = jnp.where(seen[:, None, None, None, :], attn.scores(q, cache_k), NEG)
+    return attn.mix(jax.nn.softmax(s, axis=-1), cache_v)
+
+
+def _refusal(attn: Attention, s_max: int) -> str | None:
+    return pk.decode_attention_refusal(attn.num_heads, attn.num_kv_heads, s_max, attn.head_dim)
+
+
+def read_block(attn: Attention, s_max: int) -> int:
+    """The slots a policy step reads a row's cache in: the kernel's block where
+    the gate and the shape admit it, else all S_max (the twin reads every
+    slot). Records nothing: the host's count of what `decode` traces, on one
+    device (where no partitioned jit refuses the kernel)."""
+    return pk.DECODE_BLOCK if pk.pallas_enabled("decode_attn") and _refusal(attn, s_max) is None else s_max
 
 
 def exit_loop(lams: jax.Array, threshold: float) -> jax.Array:
@@ -170,7 +197,8 @@ class LoopedLM(nn.Module):
         n = state.pos.shape[0]
         h = self.embed.astype(dtype)[state.last][:, None]  # [envs, 1, hidden]
         positions = state.pos[:, None]
-        seen = jnp.arange(state.cache_k[0].shape[1])[None, :] <= state.pos[:, None]  # [envs, S_max]
+        why = _refusal(self.layers[0].attn, state.cache_k[0].shape[1])
+        kernel = pk.use_pallas("decode_attn", self, state) and pk.select("decode_attn", why is None, detail=why, block=pk.DECODE_BLOCK)
         rows = jnp.arange(n)
         cache_k, cache_v = list(state.cache_k), list(state.cache_v)
         hs, lams = [], []
@@ -182,8 +210,7 @@ class LoopedLM(nn.Module):
                         q, k, v = layer.attn.qkv(layer.input_norm(h), positions)
                         cache_k[c] = cache_k[c].at[rows, state.pos].set(k[:, 0], mode="drop")
                         cache_v[c] = cache_v[c].at[rows, state.pos].set(v[:, 0], mode="drop")
-                        s = jnp.where(seen[:, None, None, None, :], layer.attn.scores(q, cache_k[c]), NEG)
-                        a = layer.attn.mix(jax.nn.softmax(s, axis=-1), cache_v[c])
+                        a = decode_attend(layer.attn, q, cache_k[c], cache_v[c], state.pos, kernel)
                         h = h + layer.attn_out_norm(layer.attn.out(a))
                     h = layer.mlp(h)
                 h, lam = self.loop_end(h)

@@ -50,7 +50,7 @@ from ...utils.parser import DataclassArgumentParser
 from ...utils.registry import register_algorithm
 from ..ppo.loss import policy_loss
 from ..ppo_bd.ppo_bd import TrainState, make_optimizer
-from .agent import LoopedLM, build_policy, player_copy
+from .agent import LoopedLM, build_policy, player_copy, read_block
 from .args import PPOLMArgs
 from .layout import Dims, Record, build_batch
 
@@ -199,6 +199,7 @@ def main(argv: Sequence[str] | None = None) -> None:
     record = Record(args.num_envs, args.group_size, dims)
     aggregator = MetricAggregator()
     R, N = args.total_ut_steps, args.num_hidden_layers
+    block = read_block(player.layers[0].attn, dims.s_max)  # the cache slots a policy step reads a row's cache in
 
     def prefill(pstate, obs, which: np.ndarray):
         """Write the prompts of the slots `which`, a group's prompts a call."""
@@ -234,8 +235,11 @@ def main(argv: Sequence[str] | None = None) -> None:
         packed = np.asarray(handle.get())  # the ONLY per-step d2h pull: the token, its log-probability and exit loop
         telem.mark("rollout/pack", phase="rollout")
         tokens = packed[:, 0]
+        fed = record.prompt_len - 1 + record.written  # the position each row's token was fed at
+        read = np.minimum(-(-(fed + 1) // block) * block, dims.s_max)
         record.add(tokens, np.ascontiguousarray(packed[:, 1]).view(np.float32))
-        telem.count(tokens_committed=len(tokens), loop_applications=len(tokens) * R * N, exit_loop=np.bincount(packed[:, 2], minlength=R).tolist())
+        telem.count(tokens_committed=len(tokens), loop_applications=len(tokens) * R * N, exit_loop=np.bincount(packed[:, 2], minlength=R).tolist(),
+                    attn_slots_read=int(read.sum()), attn_slots_held=args.num_envs * dims.s_max)
         telem.mark("rollout/env_step", phase="rollout")
         obs, rewards, terms, truncs, infos = envs.step(list(tokens[:, None]))
         dones = np.logical_or(terms, truncs)

@@ -8,7 +8,9 @@ out with the batch in the lanes and does conv + LayerNorm + SiLU 1.6 - 10 x
 faster than a kernel in Mosaic's row-major layout plus the relayouts around
 it; nn/blocks.py runs the plain layers. ISSUE 9 adds the whole RSSM dynamic
 step (pre-MLP + LN-GRU + prior/posterior head stacks) as ONE kernel,
-`fused_rssm_step` below. Each kernel here
+`fused_rssm_step` below; `decode_attention` reads a language model's
+key/value caches one query token a row, only up to each row's position.
+Each kernel here
 
   - fuses what XLA would otherwise stage through HBM: the GRU kernel keeps the
     [B, 3H] pre-activation entirely in VMEM between the MXU matmul, the
@@ -47,6 +49,7 @@ _VMEM = pltpu.VMEM
 __all__ = [
     "KERNEL_NAMES",
     "use_pallas",
+    "pallas_enabled",
     "set_pallas",
     "select",
     "layernorm_gru_cell",
@@ -56,6 +59,9 @@ __all__ = [
     "int8_trunk_reference",
     "fused_int8_trunk_supported",
     "two_hot_log_prob",
+    "DECODE_BLOCK",
+    "decode_attention",
+    "decode_attention_refusal",
 ]
 
 # The names the kernels carry on the device, by family (the `kind` of
@@ -71,6 +77,7 @@ KERNEL_NAMES: dict[str, tuple[str, ...]] = {
     "rssm": ("rssm_step_fwd",),
     "two_hot": ("two_hot_fwd",),
     "sac_trunk": ("int8_trunk_fwd",),
+    "decode_attn": ("decode_attn_fwd",),
 }
 
 
@@ -128,10 +135,26 @@ def _env_flag(name: str) -> bool | None:
     return None
 
 
+def pallas_enabled(kind: str | None = None) -> bool:
+    """The gate's switches alone, recording nothing: the master
+    (`set_pallas`, SHEEPRL_TPU_PALLAS, else on a TPU backend) refined per
+    kernel family via SHEEPRL_TPU_PALLAS_<KIND>."""
+    if _FORCED is not None:
+        enabled = _FORCED
+    else:
+        master = _env_flag("SHEEPRL_TPU_PALLAS")
+        enabled = _backend_is_tpu() if master is None else master
+    if enabled and kind is not None:
+        per_kind = _env_flag(f"SHEEPRL_TPU_PALLAS_{kind.upper()}")
+        if per_kind is not None:
+            enabled = per_kind
+    return enabled
+
+
 def use_pallas(kind: str | None = None, *operands) -> bool:
     """Master gate, optionally refined per kernel family via
-    SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SAC_TRUNK), so a
-    chip run can set one family's XLA twin against its kernel.
+    SHEEPRL_TPU_PALLAS_<KIND> (KIND in GRU|RSSM|TWO_HOT|SAC_TRUNK|DECODE_ATTN),
+    so a chip run can set one family's XLA twin against its kernel.
 
     With `kind` (a dispatch site asking for its family) a refusal is
     recorded (:func:`select`): "disabled", or "partitioned" when any leaf
@@ -145,18 +168,9 @@ def use_pallas(kind: str | None = None, *operands) -> bool:
     v5e 2x2 host, PR 21). Until the kernels are wrapped in shard_map
     (ROADMAP S4), a jit partitioned over several devices takes the XLA
     twins; the interpreter lowers to plain XLA ops and is not affected."""
-    if _FORCED is not None:
-        enabled = _FORCED
-    else:
-        master = _env_flag("SHEEPRL_TPU_PALLAS")
-        enabled = _backend_is_tpu() if master is None else master
     if kind is None:
-        return enabled
-    if enabled:
-        per_kind = _env_flag(f"SHEEPRL_TPU_PALLAS_{kind.upper()}")
-        if per_kind is not None:
-            enabled = per_kind
-    if not enabled:
+        return pallas_enabled()
+    if not pallas_enabled(kind):
         return select(kind, False, "disabled")
     if not _INTERPRET:
         devices = max(
@@ -795,3 +809,133 @@ def _two_hot_bwd(residuals, g):
 
 
 two_hot_log_prob.defvjp(_two_hot_fwd, _two_hot_bwd)
+
+
+# =============================================================================
+# Decode attention over a contiguous key/value cache
+# =============================================================================
+#
+# One query token a row against the row's cache [S_max, kv heads, D], where
+# only the slots up to the row's own position `pos` hold its sequence. The
+# XLA twin (`algos/ppo_lm/agent.py`) scores all S_max slots and masks the
+# ones past `pos` after reading them; at a mean context of ~40 % of S_max
+# that streams 2.5 x the bytes a row holds. The kernel walks the rows in
+# turn and, for each, only the blocks of DECODE_BLOCK slots that hold a slot
+# <= pos: manual double-buffered DMAs from HBM, the next block's copy (the
+# next row's first, at a row's last) started before the current one is
+# waited on, so no grid step is spent on a block nobody reads. Per block:
+# the scores on the VPU in float32 (q * k summed over D), slots past `pos`
+# set to NEG as the twin does, and an online softmax with float32 running
+# max, sum and accumulator. Inference only: no VJP (the update reads whole
+# sequences through `LoopedLM.hidden`).
+
+# cache slots a DMA brings in: 512 KiB of K and of V at 16 heads x 128 in bf16.
+# 256 streams a full cache 5 % faster on a v5e but reads 64 more slots of
+# each row's last block, a loss at contexts of ~40 % of S_max (PERF.md)
+DECODE_BLOCK = 128
+NEG = -1e30  # a masked score, as `nn.attention.NEG`
+
+
+def decode_attention_refusal(num_heads: int, num_kv_heads: int, s_max: int, head_dim: int) -> str | None:
+    """Why a cache of this shape is outside the kernel's contract; None where it is inside."""
+    if s_max % DECODE_BLOCK:
+        return f"S_max {s_max} is not a multiple of {DECODE_BLOCK}"
+    if head_dim % 128:
+        return f"head_dim {head_dim} is not a multiple of 128"
+    if num_heads % num_kv_heads:
+        return f"{num_heads} heads do not share {num_kv_heads} key/value heads evenly"
+    return None
+
+
+def _decode_attn_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, *, scale):
+    """Grid step b: row b's attention over its valid blocks. q_ref / o_ref
+    [1, g, kv heads, D] (query head kv * g + i at [i, kv]), k_buf / v_buf
+    [2, DECODE_BLOCK, kv heads, D], slot_ref the buffer the next wait finds."""
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    s_max = k_hbm.shape[1]
+    g = q_ref.shape[1]
+
+    def copies(row, j, slot):
+        span = pl.ds(pl.multiple_of(j * DECODE_BLOCK, DECODE_BLOCK), DECODE_BLOCK)
+        return (pltpu.make_async_copy(k_hbm.at[row, span], k_buf.at[slot], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[row, span], v_buf.at[slot], sems.at[1, slot]))
+
+    @pl.when(b == 0)
+    def _():
+        slot_ref[0] = 0
+        for c in copies(0, 0, 0):
+            c.start()
+
+    pos = pos_ref[b]
+    blocks = jnp.minimum(pos, s_max - 1) // DECODE_BLOCK + 1  # pos >= S_max: a dropped write, every slot seen
+    q = q_ref[0].astype(jnp.float32) * scale  # [g, kv heads, D]
+
+    def block(j, carry):
+        slot = slot_ref[0]
+
+        @pl.when(j + 1 < blocks)
+        def _():
+            for c in copies(b, j + 1, 1 - slot):
+                c.start()
+
+        @pl.when(jnp.logical_and(j + 1 == blocks, b + 1 < rows))
+        def _():
+            for c in copies(jnp.minimum(b + 1, rows - 1), 0, 1 - slot):
+                c.start()
+
+        for c in copies(b, j, slot):
+            c.wait()
+        slot_ref[0] = 1 - slot
+        k = k_buf[slot].astype(jnp.float32)  # [DECODE_BLOCK, kv heads, D]
+        v = v_buf[slot].astype(jnp.float32)
+        out = []
+        for i in range(g):
+            m, l, acc = carry[i]
+            s = jnp.sum(k * q[i][None], axis=-1, keepdims=True)  # [DECODE_BLOCK, kv heads, 1]
+            seen = j * DECODE_BLOCK + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) <= pos
+            s = jnp.where(seen, s, NEG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))
+            alpha = jnp.exp(m - m_new)
+            e = jnp.exp(s - m_new[None])
+            out.append((m_new, alpha * l + jnp.sum(e, axis=0), alpha * acc + jnp.sum(e * v, axis=0)))
+        return tuple(out)
+
+    kv_heads, d = q.shape[1:]
+    start = (jnp.full((kv_heads, 1), -jnp.inf, jnp.float32), jnp.zeros((kv_heads, 1), jnp.float32), jnp.zeros((kv_heads, d), jnp.float32))
+    done = jax.lax.fori_loop(0, blocks, block, (start,) * g)
+    for i, (_, l, acc) in enumerate(done):
+        o_ref[0, i] = (acc / l).astype(o_ref.dtype)
+
+
+
+def decode_attention(q, cache_k, cache_v, pos):
+    """q [B, heads, D] (turned), cache_k / cache_v [B, S_max, kv heads, D],
+    pos [B] int32 -> [B, heads * D] in q's dtype: each row's softmax(q k^T /
+    sqrt(D)) v over its slots 0..pos, reading only the DECODE_BLOCK-slot
+    blocks that hold them. The shape must pass `decode_attention_refusal`."""
+    B, heads, d = q.shape
+    s_max, kv_heads = cache_k.shape[1], cache_k.shape[2]
+    g = heads // kv_heads
+    q = q.reshape(B, kv_heads, g, d).transpose(0, 2, 1, 3)  # [B, g, kv heads, D]
+    row = pl.BlockSpec((1, g, kv_heads, d), lambda b, pos_ref: (b, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_decode_attn_kernel, scale=1.0 / float(d) ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, DECODE_BLOCK, kv_heads, d), cache_k.dtype),
+                pltpu.VMEM((2, DECODE_BLOCK, kv_heads, d), cache_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, g, kv_heads, d), q.dtype),
+        # sequential: a row's last block starts the next row's first copy
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=_interpret_mode(),
+        name="decode_attn_fwd",
+    )(pos.astype(jnp.int32), q, cache_k, cache_v)
+    return out.transpose(0, 2, 1, 3).reshape(B, heads * d)

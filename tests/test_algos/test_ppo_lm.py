@@ -19,6 +19,8 @@ from sheeprl_tpu.algos.ppo_lm.agent import exit_loop
 from sheeprl_tpu.algos.ppo_lm.args import PPOLMArgs
 from sheeprl_tpu.algos.ppo_lm.layout import Dims, Record, build_batch
 from sheeprl_tpu.cli import run as cli_run
+from sheeprl_tpu.envs.token_task import TokenTask
+from sheeprl_tpu.ops import pallas_kernels as pk
 from sheeprl_tpu.utils.registry import tasks
 
 MODEL = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=4, head_dim=16, intermediate_size=96, rope_theta=1e6,
@@ -180,3 +182,113 @@ def test_the_main_refuses_what_it_cannot_run(tmp_path):
         ppo_lm.main(["--dry_run", "--num_envs=6", f"--root_dir={tmp_path}", "--run_name=b"])
     with pytest.raises(ValueError, match="ppo_lm gives one"):
         ppo_lm.main(["--dry_run", "--num_envs=4", "--sync_env", "--env_id=TokenTask-v0", f"--root_dir={tmp_path}", "--run_name=c"])
+
+
+# ---- the cache read at each row's own position: the `decode_attn` kernel (interpret mode) against the XLA twin
+WIDE = dict(hidden_size=64, num_attention_heads=2, num_key_value_heads=2, head_dim=128)  # inside the kernel's contract
+LONG = Dims(p_max=2 * pk.DECODE_BLOCK - 8, r_max=8)  # 2 blocks of cache slots
+
+
+@pytest.fixture
+def kernel_interpret():
+    pk.set_pallas(True, interpret=True)
+    yield
+    pk.set_pallas(None, interpret=False)
+
+
+def test_prefill_and_decoding_read_through_the_kernel_equal_the_full_forward(kernel_interpret):
+    c = config(**WIDE)
+    _, model, params = program(c)
+    rng = np.random.default_rng(7)
+    lengths, steps = np.array([3, LONG.p_max, pk.DECODE_BLOCK, pk.DECODE_BLOCK + 1]), 3  # positions either side of a block's end
+    seqs = rng.integers(0, V, (4, LONG.s_max)).astype(np.int32)
+    state = model.init_states(4, LONG.s_max, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        state = model.prefill(state, jnp.asarray(seqs[:, : LONG.p_max]), jnp.asarray(lengths), jnp.arange(4))
+        got = []
+        for k in range(steps):
+            state, logits, _ = model.decode(state)
+            got.append(np.asarray(logits))
+            state = state.replace(last=jnp.asarray(seqs[np.arange(4), lengths + k]))
+        for j in range(4):
+            want, _ = ref.followed_logits(params, seqs[j, : LONG.p_max + steps], int(lengths[j]), steps, c)
+            np.testing.assert_allclose(np.stack(got)[:, j], np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+def test_decoding_through_the_kernel_samples_what_the_twin_samples(kernel_interpret):
+    """Several policy steps at one key, a group's prefill between them: the
+    kernel and the twin sample the same tokens, give the same logits and leave
+    the same caches."""
+    c = config(**WIDE)
+    args, model, _ = program(c)
+    rng = np.random.default_rng(11)
+    prompts = rng.integers(0, V, (2, 4, LONG.p_max)).astype(np.int32)
+    lengths = np.array([[5, LONG.p_max, 130, 127], [200, 9, 128, 64]])
+
+    def rollout(kernel: bool):
+        pk.set_pallas(kernel, interpret=kernel)
+        step = ppo_lm.make_policy_step(args)
+        state = model.init_states(4, LONG.s_max, jnp.float32)
+        state = model.prefill(state, jnp.asarray(prompts[0]), jnp.asarray(lengths[0]), jnp.arange(4))
+        key, out = jax.random.PRNGKey(3), []
+        for t in range(5):
+            if t == 3:  # the group of slots 2 and 3 ends and takes new prompts
+                state = model.prefill(state, jnp.asarray(prompts[1, 2:]), jnp.asarray(lengths[1, 2:]), jnp.asarray([2, 3]))
+            key, k = jax.random.split(key)
+            state, packed, logits = step(model, state, k)
+            out.append((np.asarray(packed[:, 0]), np.asarray(logits)))
+        return out, state
+
+    with jax.default_matmul_precision("highest"):
+        twin, twin_state = rollout(False)
+        kern, kern_state = rollout(True)
+    for (ids_t, logits_t), (ids_k, logits_k) in zip(twin, kern):
+        assert (ids_t == ids_k).all()
+        np.testing.assert_allclose(logits_k, logits_t, rtol=1e-4, atol=1e-4)
+    assert np.asarray(kern_state.pos).tolist() == np.asarray(twin_state.pos).tolist() == [lengths[0, 0] + 4, lengths[0, 1] + 4, lengths[1, 2] + 1, lengths[1, 3] + 1]
+    for a, b in zip(kern_state.cache_k + kern_state.cache_v, twin_state.cache_k + twin_state.cache_v):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "twin"])
+def test_the_policy_steps_count_the_cache_slots_they_read(tmp_path, monkeypatch, kernel):
+    """A dry run, four groups on prompts of 8 to 248 ids (caches of 2 blocks): every
+    `rollout/pack` counts the slots read, sum of ceil((pos + 1) / block) x block
+    over the rows at the positions the step was fed, against all it holds; the
+    twin reads every slot. The decision is one `kernel.select` event."""
+    import gymnasium as gym
+
+    env_id = "TokenTaskARLong-v0"
+    if env_id not in gym.registry:
+        gym.register(env_id, entry_point=TokenTask, kwargs={"block_length": 1, "max_prompt": LONG.p_max, "max_response": LONG.r_max})
+    fed, real = [], ppo_lm.make_policy_step
+
+    def recording(*a, **kw):
+        step = real(*a, **kw)
+
+        def call(player, state, key):
+            fed.append(np.asarray(state.pos))
+            return step(player, state, key)
+
+        return call
+
+    monkeypatch.setattr(ppo_lm, "make_policy_step", recording)
+    pk.set_pallas(kernel, interpret=kernel)
+    try:
+        cli_run(["ppo_lm", "--dry_run", "--num_envs=8", "--group_size=2", "--sync_env", f"--env_id={env_id}", f"--root_dir={tmp_path}", "--run_name=test",
+                 "--num_hidden_layers=1", "--hidden_size=32", "--num_attention_heads=1", "--num_key_value_heads=1", "--head_dim=128",
+                 "--intermediate_size=64", "--total_ut_steps=2"])
+    finally:
+        pk.set_pallas(None, interpret=False)
+    with open(os.path.join(tmp_path, "test", "telemetry.jsonl")) as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    packs = [e for e in events if e.get("event") == "span" and e["name"] == "rollout/pack"]
+    block = pk.DECODE_BLOCK if kernel else LONG.s_max
+    assert len(packs) == len(fed) >= LONG.r_max
+    for span, pos in zip(packs, fed):
+        assert span["attn_slots_held"] == 8 * LONG.s_max
+        assert span["attn_slots_read"] == int(np.minimum(-(-(pos + 1) // block) * block, LONG.s_max).sum())
+    if kernel:
+        assert max(s["attn_slots_read"] for s in packs) < 8 * LONG.s_max  # some row's cache read short of its end
+    picks = [(e["selected"], e["reason"]) for e in events if e.get("event") == "kernel.select" and e["family"] == "decode_attn"]
+    assert picks == [(True, "ok") if kernel else (False, "disabled")]

@@ -322,3 +322,56 @@ def test_partitioned_jit_takes_the_xla_twin_and_says_so(tmp_path):
     assert [(e["family"], e["selected"], e["reason"], e["devices"]) for e in picks] == [
         ("gru", False, "partitioned", 4)
     ] * 2
+
+
+# =============================================================================
+# Decode attention over a contiguous key/value cache (the looped LM's policy step)
+# =============================================================================
+
+_S_MAX = 2 * pk.DECODE_BLOCK
+_DECODE_POSITIONS = {
+    "first_slot": [0, 0],
+    "block_end": [pk.DECODE_BLOCK - 1] * 2,
+    "next_block": [pk.DECODE_BLOCK] * 2,
+    "last_slot": [_S_MAX - 1] * 2,
+    "write_dropped": [_S_MAX] * 2,  # a row past its cache: every slot seen
+    "mixed": [0, 5, pk.DECODE_BLOCK - 1, pk.DECODE_BLOCK, 200, _S_MAX - 1, _S_MAX, 130],
+}
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["heads_per_kv_1", "heads_per_kv_2"])
+@pytest.mark.parametrize("where", list(_DECODE_POSITIONS), ids=list(_DECODE_POSITIONS))
+def test_decode_attention_matches_the_twin_and_reads_nothing_past_pos(pallas_interpret, where, kv_heads):
+    """The kernel against `decode_attend`'s XLA twin on bf16 caches: slots
+    past a row's position hold large garbage in the kernel's caches and zeros
+    in the twin's, so an output that agrees has read nothing there."""
+    from sheeprl_tpu.algos.ppo_lm.agent import decode_attend
+    from sheeprl_tpu.nn.attention import Attention
+
+    heads, d = 4, 128
+    pos = np.asarray(_DECODE_POSITIONS[where], np.int32)
+    b = len(pos)
+    rng = np.random.default_rng(len(where) + kv_heads)
+    attn = Attention.init(jax.random.PRNGKey(0), 64, heads, kv_heads, d, qk_norm=False)
+    q = jnp.asarray(rng.normal(size=(b, 1, heads, d)), jnp.bfloat16)
+    past = (np.arange(_S_MAX)[None, :] > pos[:, None])[..., None, None]
+    ck, cv = (rng.normal(size=(b, _S_MAX, kv_heads, d)) for _ in range(2))
+    clean = [jnp.asarray(np.where(past, 0.0, c), jnp.bfloat16) for c in (ck, cv)]
+    garbage = [jnp.asarray(np.where(past, fill, c), jnp.bfloat16) for c, fill in ((ck, 3e4), (cv, -3e4))]
+    want = decode_attend(attn, q, *clean, jnp.asarray(pos), kernel=False)
+    got = decode_attend(attn, q, *garbage, jnp.asarray(pos), kernel=True)
+    assert got.shape == want.shape == (b, 1, heads * d) and got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=1e-2, rtol=1e-2)
+    twin_on_garbage = decode_attend(attn, q, *garbage, jnp.asarray(pos), kernel=False)
+    np.testing.assert_allclose(np.asarray(twin_on_garbage, np.float32), np.asarray(want, np.float32), atol=1e-2, rtol=1e-2)
+
+
+@pytest.mark.parametrize("shape,reason", [
+    ((4, 4, 256, 128), None),
+    ((4, 4, 200, 128), "S_max 200"),
+    ((4, 4, 256, 64), "head_dim 64"),
+    ((6, 4, 256, 128), "6 heads"),
+], ids=["inside", "cache_not_whole_blocks", "narrow_head", "uneven_groups"])
+def test_decode_attention_contract(shape, reason):
+    why = pk.decode_attention_refusal(*shape)
+    assert why is None if reason is None else why.startswith(reason)

@@ -178,6 +178,29 @@ def test_the_table_is_the_names_the_kernels_compile_to():
     assert literals == set(names)
 
 
+def test_the_looped_lms_policy_step_reads_its_caches_through_the_kernel_on_the_chip(one_chip, no_compile_cache, real_kernels):
+    """`ouro_2p6b_pp12`'s policy step at the cell's widths but one layer and two
+    loops (32 rows, 16 heads of 128, 1,024 slots), compiled for the described
+    v5e: one `decode_attn_fwd` a (loop, layer), inside the step's scope, and
+    within the chip's memory."""
+    from sheeprl_tpu.algos.ppo_lm import ppo_lm
+    from sheeprl_tpu.algos.ppo_lm.args import PPOLMArgs
+
+    args = PPOLMArgs(hidden_size=2048, num_hidden_layers=1, num_attention_heads=16, num_key_value_heads=16, head_dim=128,
+                     intermediate_size=5632, total_ut_steps=2, precision="bfloat16")
+    model = jax.eval_shape(lambda: ppo_lm.build_policy(jax.random.PRNGKey(0), args, 49152))
+    player = jax.eval_shape(lambda m: ppo_lm.player_copy(m, jnp.bfloat16), model)
+    state = jax.eval_shape(lambda p: p.init_states(32, 1024, jnp.bfloat16), player)
+    placed = lambda tree: jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = ppo_lm.make_policy_step(args).lower(placed(player), placed(state), key).compile()
+    text = compiled.as_text()
+    assert kernel_instructions(compiled) == {"decode_attn_fwd"}
+    assert len(re.findall(r"= [^\n]*custom_call_target=\"tpu_custom_call\"", text)) == 2
+    assert re.search(r"jit\(lm_policy_step\)/lm/policy_step/lm/loop1/lm/attn/decode_attn_fwd", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
 # ------------------------------------------------------------------ scopes
 def _tiny_args():
     from sheeprl_tpu.algos.dreamer_v3.args import DreamerV3Args

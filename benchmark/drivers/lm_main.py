@@ -35,7 +35,7 @@ from .. import compare
 from ..envs.token_episodes import Traffic, response_of
 from ..reduce import spans as reduce_spans
 from . import ouro_names
-from .token_main import Window, _adam_mu, _compile_note, _flag, _rel_rms, delta_norms
+from .token_main import _adam_mu, _compile_note, _flag, _rel_rms, delta_norms, make_window
 from .train_main import CONTROL_OPERANDS, RC_PREEMPTED, Compiles
 
 ENV_ID = "SheepBenchTokensAR-v0"
@@ -100,6 +100,12 @@ def model_config(config: dict, traffic: dict) -> dict:
     return c
 
 
+def make_traffic(config: dict, traffic: dict, seed: int, **hooks) -> Traffic:
+    """The generator of this driver's cells, from a traffic file's parameters: one id a step."""
+    params = {**traffic["env"], "vocab_size": config["vocab_size"], "group_size": traffic["args"]["group_size"]}
+    return Traffic(params, traffic["num_envs"], 1, seed, **hooks)
+
+
 def run(cell: dict, seed: int, seconds: float, trace: bool, root: str, control: bool = False, fault=None) -> dict:
     """One run of one cell. `fault`, for the tests, breaks the timed step."""
     config, traffic = cell["config"], cell["traffic"]
@@ -110,16 +116,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, root: str, control: 
     c = model_config(config, traffic)
     spec = reference.param_spec(c)
     num_envs = traffic["num_envs"]
-    steps_per_update = traffic["args"]["update_sequences"] // traffic["args"]["per_rank_batch_size"]
 
     out_dir = os.path.join(root, "benchmark_out", f"{cell['name']}-{seed}")
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
     trace_cfg = {"dir": os.path.join(out_dir, "trace"), "iterations": traffic["trace_iterations"]} if trace else None
     recorders: dict = {}
-    window = Window(seconds, traffic["warmup_updates"], traffic["learning_starts"] // num_envs, lambda: recorders["train"].calls if "train" in recorders else 0, steps_per_update, trace_cfg)
-    load = Traffic({**traffic["env"], "vocab_size": config["vocab_size"], "group_size": traffic["args"]["group_size"]}, num_envs, 1, seed,
-                   on_step=window.on_step, annotate=jax.profiler.TraceAnnotation if trace else None)
+    window = make_window(traffic, seconds, lambda: recorders["train"].calls if "train" in recorders else 0, trace_cfg)
+    load = make_traffic(config, traffic, seed, on_step=window.on_step, annotate=jax.profiler.TraceAnnotation if trace else None)
     window.counters = lambda: (load.host_seconds, load.resets, load.context_tokens, load.tokens_committed)
     compiles = Compiles()
 

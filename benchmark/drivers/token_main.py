@@ -111,6 +111,19 @@ class Window:
         return [dt for dt, a, b in zip(self.iteration_seconds, n, n[1:]) if b > a]
 
 
+def make_window(traffic: dict, seconds: float, train_steps, trace: dict | None = None) -> Window:
+    """The window as a traffic file sets it: `warmup_updates`, and
+    `warmup_iterations`, the boundaries that pass before it may open."""
+    steps_per_update = traffic["args"]["update_sequences"] // traffic["args"]["per_rank_batch_size"]
+    return Window(seconds, traffic["warmup_updates"], traffic["warmup_iterations"], train_steps, steps_per_update, trace)
+
+
+def make_traffic(config: dict, traffic: dict, seed: int, **hooks) -> Traffic:
+    """The generator of this driver's cells, from a traffic file's parameters."""
+    params = {**traffic["env"], "vocab_size": config["vocab_size"], "group_size": traffic["args"]["group_size"]}
+    return Traffic(params, traffic["num_envs"], traffic["args"]["block_length"], seed, **hooks)
+
+
 def _adam_mu(opt_state):
     found = [x for x in jax.tree_util.tree_leaves(opt_state, is_leaf=lambda x: hasattr(x, "mu")) if hasattr(x, "mu")]
     if len(found) != 1:
@@ -197,16 +210,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, root: str, control: 
     c = model_config(config, traffic)
     spec = reference.param_spec(c)
     num_envs = traffic["num_envs"]
-    steps_per_update = traffic["args"]["update_sequences"] // traffic["args"]["per_rank_batch_size"]
 
     out_dir = os.path.join(root, "benchmark_out", f"{cell['name']}-{seed}")
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
     trace_cfg = {"dir": os.path.join(out_dir, "trace"), "iterations": traffic["trace_iterations"]} if trace else None
     recorders: dict = {}
-    window = Window(seconds, traffic["warmup_updates"], traffic["learning_starts"] // num_envs, lambda: recorders["train"].calls if "train" in recorders else 0, steps_per_update, trace_cfg)
-    load = Traffic({**traffic["env"], "vocab_size": config["vocab_size"], "group_size": traffic["args"]["group_size"]}, num_envs,
-                   traffic["args"]["block_length"], seed, on_step=window.on_step, annotate=jax.profiler.TraceAnnotation if trace else None)
+    window = make_window(traffic, seconds, lambda: recorders["train"].calls if "train" in recorders else 0, trace_cfg)
+    load = make_traffic(config, traffic, seed, on_step=window.on_step, annotate=jax.profiler.TraceAnnotation if trace else None)
     window.counters = lambda: (load.host_seconds, load.resets, load.context_tokens, load.tokens_committed)
     compiles = Compiles()
 

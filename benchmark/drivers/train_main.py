@@ -184,6 +184,11 @@ def _model_config(config: dict, traffic: dict) -> dict:
     return {**config["args"], "actions": traffic["env"]["actions"], "image_channels": 3}
 
 
+def make_traffic(config: dict, traffic: dict, seed: int, **hooks) -> Traffic:
+    """The generator of this driver's cells, from a traffic file's parameters."""
+    return Traffic(traffic["env"], traffic["num_envs"], seed, **hooks)
+
+
 def run(cell: dict, seed: int, seconds: float, trace: bool, root: str, control: bool = False, fault=None) -> dict:
     """One run of one cell. `fault`, for the tests, breaks the timed step."""
     config, traffic = cell["config"], cell["traffic"]
@@ -207,7 +212,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, root: str, control: 
     if trace:
         trace_cfg = {"dir": os.path.join(out_dir, "trace"), "iterations": traffic["trace_iterations"]}
     window = Window(seconds, open_at, trace_cfg, policy_from=policy_from)
-    load = Traffic(traffic["env"], num_envs, seed, on_step=window.on_step, annotate=jax.profiler.TraceAnnotation if trace else None)
+    load = make_traffic(config, traffic, seed, on_step=window.on_step, annotate=jax.profiler.TraceAnnotation if trace else None)
     window.counters = lambda: (load.host_seconds, load.resets)
     compiles = Compiles()
     recorder: list[Recorder] = []

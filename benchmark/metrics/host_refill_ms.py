@@ -8,16 +8,19 @@ import statistics
 
 from ..reduce import spans
 
+PULL, DISPATCH = "log/pull", "rollout/policy_dispatch"
+SPANS = (PULL, DISPATCH)
+
 
 def read(run: dict):
     w = spans.window(run)
     if not w:
         return None
-    dispatched = {s["step"]: s["p0"] + s["dur_ms"] / 1e3 for s in w.named("rollout/policy_dispatch")}
+    dispatched = {s["step"]: s["p0"] + s["dur_ms"] / 1e3 for s in w.named(DISPATCH)}
     steps = sorted(dispatched)
     following = dict(zip(steps, steps[1:]))  # the step that comes after each
     gaps = [
         1e3 * (dispatched[following[s["step"]]] - (s["p0"] + s["dur_ms"] / 1e3))
-        for s in w.named("log/pull") if s["step"] in following
+        for s in w.named(PULL) if s["step"] in following
     ]
     return statistics.median(gaps) if gaps else None

@@ -2,12 +2,13 @@
 `rollout/action_wait` (the pull of the action indices, which waits for the
 policy step) and `log/pull` (the pull of the train metrics, which with
 `--pipeline off` waits for the iteration's last train step), and what the
-runtime holds the host back inside `train/slice`. That span, a train step's
-row of the staged block, dispatches the same dozen tiny programs every train
-step (7 ms of host work on the chip's machine); the iteration's first finds
-the device's queue short, a later one may wait in the runtime for a program
-ahead of it to end. So the held time is what an iteration's slices take beyond
-as many times its fastest. Median over the window's iterations."""
+runtime holds the host back inside `train/slice`. That span, once a train
+step, picks the step's row of the staged block and enqueues nothing on one
+device (on several it holds the row's re-sharding); a later slice of an
+iteration that takes longer than its fastest was held in the runtime. So the
+held time is what an iteration's slices take beyond as many times its
+fastest: 0 where the main has no `train/slice`, as the token mains have not.
+Median over the window's iterations."""
 
 import statistics
 
@@ -15,6 +16,7 @@ from ..reduce import spans
 
 WAITS = ("rollout/action_wait", "log/pull")
 HELD = "train/slice"
+SPANS = WAITS  # the spans the main has to open for this reader; HELD may be absent
 
 
 def per_iteration(w: spans.Window) -> list[float]:

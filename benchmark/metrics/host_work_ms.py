@@ -7,6 +7,8 @@ import statistics
 from ..reduce import spans
 from . import host_wait_ms
 
+SPANS = host_wait_ms.SPANS
+
 
 def read(run: dict):
     w = spans.window(run)

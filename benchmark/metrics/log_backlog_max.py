@@ -5,7 +5,7 @@ one that falls behind reads a number that grows from the window's first half
 to its second, which the note line shows."""
 
 from ..reduce import spans
-from .log_write_ms_p50 import SPAN
+from .log_write_ms_p50 import SPAN, SPANS  # noqa: F401
 
 
 def read(run: dict):

@@ -2,7 +2,7 @@
 records on its `log/write` span, mean over the window."""
 
 from ..reduce import spans
-from .log_write_ms_p50 import SPAN
+from .log_write_ms_p50 import SPAN, SPANS  # noqa: F401
 
 
 def read(run: dict):

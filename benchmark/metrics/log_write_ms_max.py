@@ -3,7 +3,7 @@ logger's queue, where a run has one. Which of the harness's iterations holds
 it (the index `slowest iterations` prints) goes on an earlier line."""
 
 from ..reduce import spans
-from .log_write_ms_p50 import SPAN
+from .log_write_ms_p50 import SPAN, SPANS  # noqa: F401
 
 
 def read(run: dict):

@@ -6,6 +6,7 @@ import statistics
 from ..reduce import spans
 
 SPAN = "log/write"
+SPANS = (SPAN,)
 
 
 def read(run: dict):

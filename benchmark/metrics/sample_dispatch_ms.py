@@ -4,6 +4,8 @@
 
 from ..reduce import spans
 
+SPANS = ("buffer/sample", "buffer/stage")
+
 
 def read(run: dict):
-    return spans.median_ms(run, "buffer/sample", "buffer/stage")
+    return spans.median_ms(run, *SPANS)

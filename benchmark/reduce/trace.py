@@ -9,7 +9,13 @@ its line `XLA Ops` holds one event per executed HLO operation, named by the
 operation's whole text (`%copy.7 = u8[86016,4,64,64,3]{...} copy(...)`: kept
 as `detail`, the name cut to `copy.7`), its line `XLA Modules` one per
 executed program (`jit_train_step(<fingerprint>)`). The host is the plane `/host:CPU`, one line per
-thread, with the `TraceAnnotation` spans the benchmark wrote.
+thread, with the `TraceAnnotation` spans the benchmark wrote (`env.step`) and those
+the program wrote, `sheeprl/<span>` (sheeprl_tpu/telemetry/phase.py): its loop's
+thread is the line that holds the `sheeprl/iteration` step annotations.
+
+Every idle stretch of a device is put down to the innermost `sheeprl/` span of
+that thread which covers it, piece by piece where a gap crosses from one span
+into the next; what no `sheeprl/iteration` covers is outside the loop.
 """
 
 from __future__ import annotations
@@ -24,6 +30,8 @@ HOST_PLANE = "/host:CPU"
 OP_NAME = re.compile(r"^%?([\w.\-]+)")
 MAX_DETAIL = 700
 TOP = 10
+SPAN_PREFIX = "sheeprl/"
+ITERATION = SPAN_PREFIX + "iteration"
 
 
 # ------------------------------------------------------------------ loading
@@ -103,19 +111,76 @@ def result_shape(detail: str) -> str:
     return m.group(1) if m else ""
 
 
-def name_gaps(idle_gaps: list[tuple[int, int]], host_events: list, t0: int) -> list[list]:
-    """Each idle gap by what the host was doing when it opened: the shortest
-    host span that covers that instant. -> the time lost by name, then the
-    longest single gaps, at most TOP rows."""
-    spans = sorted((e[1], e[1] + e[2], e[0]) for e in host_events if e[2] > 0)
-    named: dict[str, float] = {}
-    longest, active, nxt = [], [], 0
-    for a, b in idle_gaps:
-        while nxt < len(spans) and spans[nxt][0] <= a:
-            active.append(spans[nxt])
+def program_spans(host_lines: list[dict]) -> list[tuple[int, int, str]]:
+    """(start, end, name) of the program's `sheeprl/` spans on the thread
+    whose line holds its `sheeprl/iteration` annotations; [] where none does."""
+    def iterations(line):
+        return sum(e[0] == ITERATION for e in line["events"])
+
+    main = max(host_lines, key=iterations, default=None)
+    if main is None or not iterations(main):
+        return []
+    return [(e[1], e[1] + e[2], e[0]) for e in main["events"] if e[0].startswith(SPAN_PREFIX) and e[2] > 0]
+
+
+def innermost(spans: list[tuple[int, int, str]]) -> list[tuple[int, int, str, bool]]:
+    """The stretches between the spans' ends, each with the innermost span that
+    covers it (the latest to open; of two, the shorter) and whether an
+    iteration covers it. Stretches no span covers are left out."""
+    points = sorted({t for s in spans for t in s[:2]})
+    opening = sorted(spans)
+    out, active, nxt = [], [], 0
+    for a, b in zip(points, points[1:]):
+        while nxt < len(opening) and opening[nxt][0] <= a:
+            active.append(opening[nxt])
             nxt += 1
         active = [s for s in active if s[1] > a]
-        what = min(active, key=lambda s: s[1] - s[0])[2] if active else "no host span"
+        if active:
+            inner = max(active, key=lambda s: (s[0], s[0] - s[1]))
+            out.append((a, b, inner[2], any(s[2] == ITERATION for s in active)))
+    return out
+
+
+def split_idle(idle: list[tuple[int, int]], pieces: list[tuple[int, int, str, bool]]) -> tuple[dict[str, int], int]:
+    """Idle nanoseconds inside an iteration by the innermost span (its name
+    without the prefix; `iteration` is the iteration's self time), and those
+    outside any iteration. `idle` and `pieces` are sorted and disjoint."""
+    by_span: dict[str, int] = {}
+    j = 0
+    for a, b in idle:
+        while j < len(pieces) and pieces[j][1] <= a:
+            j += 1
+        k = j
+        while k < len(pieces) and pieces[k][0] < b:
+            lo, hi, name, in_loop = pieces[k]
+            part = min(b, hi) - max(a, lo)
+            if in_loop and part > 0:
+                key = name[len(SPAN_PREFIX):]
+                by_span[key] = by_span.get(key, 0) + part
+            k += 1
+    return by_span, total(idle) - sum(by_span.values())
+
+
+def name_gaps(idle_gaps: list[tuple[int, int]], host_events: list, t0: int, spans=()) -> list[list]:
+    """Each idle gap by what the host was doing when it opened: the program's
+    innermost span there (of `spans`, from `program_spans`), then the shortest
+    other host event of any thread that covers that instant:
+    `sheeprl/rollout/action_wait > np.asarray(jax.Array)`. -> the time lost
+    by name, then the longest single gaps, at most TOP rows."""
+    own, pieces = set(spans), innermost(list(spans))
+    events = sorted(s for s in ((e[1], e[1] + e[2], e[0]) for e in host_events if e[2] > 0) if s not in own)
+    named: dict[str, float] = {}
+    longest, active, nxt, j = [], [], 0, 0
+    for a, b in idle_gaps:
+        while nxt < len(events) and events[nxt][0] <= a:
+            active.append(events[nxt])
+            nxt += 1
+        active = [s for s in active if s[1] > a]
+        while j < len(pieces) and pieces[j][1] <= a:
+            j += 1
+        span = pieces[j][2] if j < len(pieces) and pieces[j][0] <= a else None
+        event = min(active, key=lambda s: s[1] - s[0])[2] if active else None
+        what = " > ".join(x for x in (span, event) if x) or "no host span"
         named[what] = named.get(what, 0.0) + (b - a) / 1e9
         longest.append(((b - a) / 1e9, what, (a - t0) / 1e6))
     rows = [[f"all gaps during: {k}", v] for k, v in sorted(named.items(), key=lambda kv: -kv[1])[: TOP // 2]]
@@ -136,15 +201,25 @@ def reduce(planes: list[dict], chips: int) -> dict:
     t0 = min(e[1] for e in every)
     t1 = max(e[1] + e[2] for e in every)
 
+    host = next((p for p in planes if p["name"] == HOST_PLANE), {"lines": []})
+    program = program_spans(host["lines"])
+    pieces = innermost(program)
+
     busy_ns, idle_gaps = [], []
+    idle_by_span: dict[str, int] = {}
+    idle_outside = 0
     op_seconds: dict[str, float] = {}
     ops: list[dict] = []
     for d, events in enumerate(ops_by_device):
-        spans = union([(e[1], e[1] + e[2]) for e in events])
-        busy_ns.append(total(spans))
+        busy = union([(e[1], e[1] + e[2]) for e in events])
+        busy_ns.append(total(busy))
+        idle = subtract([(t0, t1)], busy)
+        by_span, outside = split_idle(idle, pieces)
+        for name, ns in by_span.items():
+            idle_by_span[name] = idle_by_span.get(name, 0) + ns
+        idle_outside += outside
         if d == 0:
-            idle_gaps = subtract([(t0, t1)], spans)
-        if d == 0:
+            idle_gaps = idle
             for name, _, dur, detail in events:
                 shape = result_shape(detail)
                 key = f"{name} {shape}".strip()
@@ -158,8 +233,7 @@ def reduce(planes: list[dict], chips: int) -> dict:
     for name, _, dur, _ in _line(devices[0][1], MODULES_LINE):
         modules.setdefault(re.sub(r"\(\d+\)$", "", name), []).append(dur / 1e9)
 
-    host = next((p for p in planes if p["name"] == HOST_PLANE), {"lines": []})
-    gaps = name_gaps(idle_gaps, [e for l in host["lines"] for e in l["events"]], t0)
+    gaps = name_gaps(idle_gaps, [e for l in host["lines"] for e in l["events"]], t0, program)
 
     n = len(devices)
     return {
@@ -168,6 +242,11 @@ def reduce(planes: list[dict], chips: int) -> dict:
         "busy_s_by_device": [b / 1e9 for b in busy_ns],
         "ops": ops,
         "modules": modules,
+        # the idle time by the program's span, the mean over the devices: these and
+        # the outside part add up to window_s - busy_s
+        "idle_by_span_s": {k: v / n / 1e9 for k, v in sorted(idle_by_span.items(), key=lambda kv: -kv[1])},
+        "idle_outside_s": idle_outside / n / 1e9,
+        "iterations_traced": sum(s[2] == ITERATION for s in program),
         "breakdown": {
             "device_ops": [[k, v] for k, v in sorted(op_seconds.items(), key=lambda kv: -kv[1])[:TOP]],
             "idle_gaps": gaps,

@@ -17,6 +17,18 @@ def manifest():
     return load(os.path.join(ROOT, "BENCHMARK.json"))
 
 
+@pytest.fixture(params=["as_committed", "with_a_made_up_metric"])
+def any_manifest(request, manifest, monkeypatch):
+    """The manifest, and a copy with a made-up span metric that lists every
+    cell: what holds for one holds for the other, so no test counts or lists
+    the metrics a cell has."""
+    if request.param == "as_committed":
+        return manifest
+    from .keyed import with_made_up_metric
+
+    return with_made_up_metric(manifest, monkeypatch)
+
+
 @pytest.fixture()
 def tiny_cell():
     return {

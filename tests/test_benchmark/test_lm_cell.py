@@ -14,6 +14,7 @@ import pytest
 from benchmark import flops_ouro, run as bench_run
 from benchmark.envs.token_episodes import Traffic
 
+from . import keyed
 from .conftest import DATA, ROOT, load
 
 CELL = "ouro_2p6b_pp12.rl_ar32"
@@ -24,7 +25,8 @@ PUBLISHED = dict(hidden_size=2048, num_attention_heads=16, num_key_value_heads=1
                  tie_word_embeddings=False, model_type="ouro", hidden_act="silu")
 
 
-def test_the_manifests_new_entries_resolve(manifest):
+def test_the_manifests_new_entries_resolve(any_manifest):
+    manifest = any_manifest
     cell = bench_run.load_cell(manifest, CELL)
     config, traffic = cell["config"], cell["traffic"]
     assert cell["chips"] == 1 and config["name"] == "ouro_2p6b_pp12" and traffic["name"] == "rl_ar_32env"
@@ -35,21 +37,22 @@ def test_the_manifests_new_entries_resolve(manifest):
                                                              "clip_coef": 0.2, "lr": 1e-06}
     assert traffic["env"]["prompt_len"] == {"median": 192, "sigma": 0.6, "clip": [64, 512]}
     assert traffic["env"]["response_len"] == {"median": 256, "sigma": 0.6, "clip": [64, 512]} and traffic["env"]["zipf_exponent"] == 1.0
-    mine = [m for m in manifest["per_layer"] if CELL in m.get("workloads", ())]
-    assert [m["name"] for m in mine] == list(NEW_METRICS)
-    assert all(m["workloads"] == [CELL] and m["moves"] == "env_steps_per_s" and m["source"] in ("device_trace", "host_clock") for m in mine)
+    mine = {m["name"]: m for m in bench_run.metrics_for(manifest, "per_layer", CELL)}
+    assert set(NEW_METRICS) <= set(mine)
+    assert all(mine[name]["moves"] == "env_steps_per_s" and mine[name]["source"] in ("device_trace", "host_clock") for name in NEW_METRICS)
+    keyed.check_spans(manifest, CELL)  # which other readers list the cell: keyed by its main, not by a list
     assert {m["name"] for m in bench_run.metrics_for(manifest, "end_to_end", CELL)} == {"env_steps_per_s", "setup_s"}
-    assert [w["name"] for w in manifest["workloads"][-2:]] == [CELL, PIPELINE] and not any(w["chips"] == 4 for w in manifest["workloads"])
+    assert all(w["chips"] == 1 for w in manifest["workloads"] if w["name"] in (CELL, PIPELINE))
 
 
-def test_the_pipeline_cell_is_ratio64_with_the_pipeline_on(manifest):
+def test_the_pipeline_cell_is_ratio64_with_the_pipeline_on(any_manifest):
+    manifest = any_manifest
     cell = bench_run.load_cell(manifest, PIPELINE)
     base = load(os.path.join(ROOT, "benchmark", "traffic", "ratio64_16env.json"))
     assert cell["config"]["name"] == "dv3_s_pixel_bf16" and cell["chips"] == 1
     assert cell["traffic"]["argv"] == base["argv"] + ["--pipeline", "on"]
     assert {k: v for k, v in cell["traffic"].items() if k not in ("name", "argv")} == {k: v for k, v in base.items() if k not in ("name", "argv")}
-    mine = [m["name"] for m in manifest["per_layer"] if PIPELINE in m.get("workloads", ())]
-    assert mine == ["train_step_ms", "train_mfu", "gru_roofline", "gru_scan_roofline", "replay_programs_ms"]
+    keyed.check_spans(manifest, PIPELINE)  # its readers: keyed by the main it runs (its rehearsal: test_run_cpu_spans.py)
 
 
 def updates_of(traffic: dict, seed: int, steps: int = 20000) -> list[int]:
@@ -75,7 +78,7 @@ def test_the_schedule_is_the_same_for_every_seed_and_a_traced_stretch_holds_an_u
     assert schedules[0] == schedules[1]  # the seed permutes which group runs which lengths, not when updates come
     gaps = np.diff(schedules[0])
     assert gaps.max() < traffic["trace_iterations"]  # the window closes after an update: the next one falls inside the trace
-    assert 130 <= gaps.mean() <= 150 and schedules[0][traffic["warmup_updates"] - 1] > traffic["learning_starts"] // traffic["num_envs"]
+    assert 130 <= gaps.mean() <= 150 and schedules[0][traffic["warmup_updates"] - 1] > traffic["warmup_iterations"]
     a, b = Traffic({**traffic["env"], "vocab_size": 49152, "group_size": 4}, 32, 1, 7), Traffic({**traffic["env"], "vocab_size": 49152, "group_size": 4}, 32, 1, 8)
     assert not np.array_equal(a.pose(0, 0)[0][:16], b.pose(0, 0)[0][:16])  # the ids are the seed's
 
@@ -106,25 +109,33 @@ def test_the_analytic_counts_at_the_published_widths(manifest):
 
 @pytest.fixture(scope="module")
 def rehearsal(manifest):
+    """-> (the result line, the cell, the run as the readers saw it): traced, so the per-layer readers run."""
     cell = {"name": "tiny_lm", "chips": 1, "config": load(f"{DATA}/ouro_tiny.json"), "traffic": load(f"{DATA}/ouro_tiny_traffic.json")}
-    m = json.loads(json.dumps(manifest))
-    for metric in m["end_to_end"] + m["per_layer"]:
-        if "workloads" in metric and CELL in metric["workloads"]:
-            metric["workloads"].append("tiny_lm")
-    return bench_run.run_cell(m, cell, 2**31 + 301, 1.0, True, control=True, require_chip=False), cell  # traced: the per-layer readers run
+    result, run = keyed.rehearse(manifest, cell, "lm_main", 2**31 + 301, True, control=True)
+    return result, cell, run
 
 
 def test_the_rehearsal_is_correct_and_holds_whole_cycles(rehearsal):
-    result, cell = rehearsal
+    result, cell, _ = rehearsal
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
     assert result["metrics"] == {} and result["device"]["platform"] == "cpu"  # no CPU number under a device metric's name
     held = {k for k, v in cell["config"]["limits"].items() if v is not None}
     assert held == {"logits_policy", "exit_mismatch", "logprob_old", "grad_med_policy", "delta_policy", "rows_mismatch"}
     assert all(result["compared"][k]["value"] <= result["compared"][k]["limit"] for k in held)
     assert 0 < result["cpu_rehearsal"]["lm_update_share"]["value"] < 100  # the harness's own clock; the other four read the device
+    assert result["cpu_rehearsal"]["host_wait_ms"]["value"] > 0 and result["cpu_rehearsal"]["log_scalars_per_iter"]["value"] > 0
     assert result["detail"]["exit_loops"] == [0, 0, 0, result["detail"]["logits_rows"]]  # threshold 1: the last of 4 loops, every token
     assert result["attempted"] % cell["traffic"]["num_envs"] == 0
     json.dumps(result)
+
+
+def test_every_metric_of_the_drivers_cells_reads_on_its_rehearsal(rehearsal, any_manifest):
+    """Keyed by the driver (keyed.py): whatever lists a cell of `lm_main` reads this rehearsal."""
+    cells = keyed.cells_of(any_manifest, "lm_main")
+    assert CELL in cells
+    for cell in cells:
+        keyed.check_spans(any_manifest, cell)
+        keyed.check_rehearsal(any_manifest, cell, rehearsal[2])
 
 
 @pytest.mark.parametrize("stand_in", ["control", "drop_loop", "one_cache", "shifted_mask"])

@@ -8,42 +8,24 @@ number (under `cpu_rehearsal`, never under a device metric's name), and each
 iteration is made up of its children and its self time. No time is judged.
 """
 
-import json
-
 import pytest
 
-from benchmark import run as bench_run
-from benchmark.drivers import train_main
 from benchmark.reduce import spans
 
+from . import keyed
 from .conftest import DATA, load
-
-SPAN_SOURCES = ("program_span", "program_counter")
 
 
 @pytest.fixture(scope="module")
 def traced(manifest):
-    """-> (the result line, the run as the readers saw it, the manifest it ran under)."""
-    m = json.loads(json.dumps(manifest))
-    for metric in m["end_to_end"] + m["per_layer"]:
-        if "workloads" in metric:
-            metric["workloads"].append("tiny")
+    """-> (the result line, the run as the readers saw it): the tiny cell read by
+    every metric that lists a cell of its driver."""
     cell = {"name": "tiny", "chips": 1, "config": load(f"{DATA}/dv3_tiny.json"), "traffic": load(f"{DATA}/tiny_traffic.json")}
-    seen = {}
-
-    def keeping(*a, **kw):
-        seen["run"] = real(*a, **kw)
-        return seen["run"]
-
-    with pytest.MonkeyPatch.context() as patch:
-        real = train_main.run
-        patch.setattr(train_main, "run", keeping)
-        result = bench_run.run_cell(m, cell, 2**31 + 23, 1.0, True, require_chip=False)
-    return result, seen["run"], m
+    return keyed.rehearse(manifest, cell, "train_main", 2**31 + 23, True)
 
 
 def test_the_spans_survive_the_preempted_exit_and_count_the_harnesss_iterations(traced):
-    result, run, _ = traced
+    result, run = traced
     assert result["correct"] is True
     w = spans.window(run)
     assert w is not None and w.ok
@@ -53,21 +35,23 @@ def test_the_spans_survive_the_preempted_exit_and_count_the_harnesss_iterations(
     assert any(line.startswith(f"spans: {run['iterations']} iteration spans start in the window") for line in run["notes"])
 
 
-def test_every_span_metric_reads_a_number_kept_apart_from_the_device_metrics(traced):
-    result, _, manifest = traced
+def test_every_span_metric_reads_a_number_kept_apart_from_the_device_metrics(traced, any_manifest):
+    """Every metric that lists a cell of this driver, keyed by the main's source
+    and this rehearsal (keyed.py); no count of them is held."""
+    result, run = traced
     assert result["device"]["platform"] == "cpu" and result["metrics"] == {}
-    names = [m["name"] for m in manifest["per_layer"] if m["source"] in SPAN_SOURCES and m["layer"] != "device"
-             and m["name"] != "compile_s"]
-    assert len(names) == 8
-    for name in names:
-        assert result["cpu_rehearsal"][name]["value"] >= 0.0, name
+    cells = keyed.cells_of(any_manifest, "train_main")
+    assert cells
+    for cell in cells:
+        keyed.check_spans(any_manifest, cell)
+        keyed.check_rehearsal(any_manifest, cell, run)
     # off a TPU there is no device trace: a kernel reader has nothing to read
     assert not {"gru_kernel_ms", "two_hot_kernel_ms"} & set(result["cpu_rehearsal"])
     assert result["cpu_rehearsal"]["log_scalars_per_iter"]["value"] > 20
 
 
 def test_an_iteration_is_its_children_and_its_self_time(traced):
-    _, run, _ = traced
+    _, run = traced
     w = spans.window(run)
     expected = {"rollout/pack", "rollout/policy_dispatch", "rollout/add_dispatch", "rollout/action_wait",
                 "rollout/env_step", "log/pull", "log/write"}

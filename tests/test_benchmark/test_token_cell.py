@@ -15,6 +15,7 @@ from benchmark import flops_sdar, run as bench_run
 from benchmark.envs.token_episodes import Traffic, length_pool, response_of
 from benchmark.reduce import by_module
 
+from . import keyed
 from .conftest import DATA, ROOT, load
 
 CELL = "sdar_30b_a3b_ep8.rl_bd4"
@@ -22,11 +23,10 @@ NEW_METRICS = (
     "bd_train_step_ms", "bd_train_mfu", "bd_policy_step_ms", "bd_policy_roofline", "moe_experts_ms", "moe_experts_roofline",
     "bd_tokens_per_env_step", "bd_update_share",
 )
-# readers the benchmark had whose inputs this cell's driver supplies too: the cell is appended to their lists
-SHARED_METRICS = ("compile_s", "env_host_share", "iter_ms_p50", "iter_ms_p95", "device_idle", "peak_hbm_gib")
 
 
-def test_the_manifests_new_entries_resolve(manifest):
+def test_the_manifests_new_entries_resolve(any_manifest):
+    manifest = any_manifest
     cell = bench_run.load_cell(manifest, CELL)
     config, traffic = cell["config"], cell["traffic"]
     assert cell["chips"] == 1 and config["name"] == "sdar_30b_a3b_ep8" and traffic["name"] == "rl_bd4_64env"
@@ -47,10 +47,9 @@ def test_the_manifests_new_entries_resolve(manifest):
     for spec in (traffic["env"]["prompt_len"], traffic["env"]["response_len"]):
         assert spec == {"median": 192, "sigma": 0.6, "clip": [64, 512]}
     assert traffic["env"]["zipf_exponent"] == 1.0
-    mine = [m for m in manifest["per_layer"] if CELL in m.get("workloads", ())]
-    assert [m["name"] for m in mine] == [*SHARED_METRICS, *NEW_METRICS]
-    assert all(m["workloads"] == [CELL] and m["moves"] == "env_steps_per_s" for m in mine if m["name"] in NEW_METRICS)
-    assert all(m["workloads"][-1] == CELL and len(m["workloads"]) == 3 for m in mine if m["name"] in SHARED_METRICS)
+    mine = {m["name"]: m for m in bench_run.metrics_for(manifest, "per_layer", CELL)}
+    assert set(NEW_METRICS) <= set(mine) and all(mine[name]["moves"] == "env_steps_per_s" for name in NEW_METRICS)
+    keyed.check_spans(manifest, CELL)  # which other readers list the cell: keyed by its main, not by a list
     assert {m["name"] for m in bench_run.metrics_for(manifest, "end_to_end", CELL)} == {"env_steps_per_s", "setup_s"}
 
 
@@ -160,16 +159,14 @@ def test_the_analytic_counts_at_the_published_widths(manifest):
 
 @pytest.fixture(scope="module")
 def rehearsal(manifest):
+    """-> (the result line, the cell, the run as the readers saw it): traced, so the per-layer readers run."""
     cell = {"name": "tiny_tokens", "chips": 1, "config": load(f"{DATA}/sdar_tiny.json"), "traffic": load(f"{DATA}/sdar_tiny_traffic.json")}
-    m = json.loads(json.dumps(manifest))
-    for metric in m["end_to_end"] + m["per_layer"]:
-        if "workloads" in metric and (CELL in metric["workloads"]):
-            metric["workloads"].append("tiny_tokens")
-    return bench_run.run_cell(m, cell, 2**31 + 301, 1.0, True, control=True, require_chip=False), cell  # traced: the per-layer readers run
+    result, run = keyed.rehearse(manifest, cell, "token_main", 2**31 + 301, True, control=True)
+    return result, cell, run
 
 
 def test_the_rehearsal_is_correct_and_holds_whole_cycles(rehearsal):
-    result, cell = rehearsal
+    result, cell, _ = rehearsal
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
     assert result["metrics"] == {} and result["device"]["platform"] == "cpu"  # no CPU number under a device metric's name
     held = {k for k, v in cell["config"]["limits"].items() if v is not None}
@@ -178,10 +175,20 @@ def test_the_rehearsal_is_correct_and_holds_whole_cycles(rehearsal):
     got = result["cpu_rehearsal"]
     assert got["bd_tokens_per_env_step"]["value"] == 2.0  # what an environment step means
     assert 0 < got["bd_update_share"]["value"] < 100
-    # the shared readers find their inputs in this driver's run (the two that read the device find none on a CPU)
+    # the readers the DreamerV3 cells had find their inputs in this driver's run (those that read the device find none on a CPU)
     assert got["iter_ms_p50"]["value"] > 0 and got["compile_s"]["value"] > 0 and 0 < got["env_host_share"]["value"] < 100
+    assert got["host_wait_ms"]["value"] > 0 and got["log_scalars_per_iter"]["value"] > 0
     assert result["attempted"] % cell["traffic"]["num_envs"] == 0
     json.dumps(result)
+
+
+def test_every_metric_of_the_drivers_cells_reads_on_its_rehearsal(rehearsal, any_manifest):
+    """Keyed by the driver (keyed.py): whatever lists a cell of `token_main` reads this rehearsal."""
+    cells = keyed.cells_of(any_manifest, "token_main")
+    assert CELL in cells
+    for cell in cells:
+        keyed.check_spans(any_manifest, cell)
+        keyed.check_rehearsal(any_manifest, cell, rehearsal[2])
 
 
 @pytest.mark.parametrize("stand_in", ["control", "drop_expert", "causal"])

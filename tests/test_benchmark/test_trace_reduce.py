@@ -65,6 +65,80 @@ def test_busy_idle_programs_and_ring_on_known_planes():
     assert four["busy_s_by_device"] == [pytest.approx(0.014), pytest.approx(0.012)]
 
 
+def ev(name, a_ms, b_ms):
+    return [name, int(a_ms * MS), int((b_ms - a_ms) * MS), ""]
+
+
+def looped(main, other=(), ops=((0, 1), (9, 10))):
+    """One device busy over `ops` (ms), idle between; the loop's thread `main`
+    and another thread `other`, as (name, start ms, end ms)."""
+    return [
+        plane("/device:TPU:0", XLA_Ops=[ev(f"fusion.{i}", a, b) for i, (a, b) in enumerate(ops)]),
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": [ev(*e) for e in main]},
+                                        {"name": "runtime", "events": [ev(*e) for e in other]}]},
+    ]
+
+
+IT = ("sheeprl/iteration", 0, 10)
+SPLITS = {
+    # the innermost span wins: update [2, 8] inside the iteration, train/dispatch [4, 6] inside update
+    "nested": ([IT, ("sheeprl/update", 2, 8), ("sheeprl/train/dispatch", 4, 6)], (),
+               {"iteration": 2, "update": 4, "train/dispatch": 2}, 0, "sheeprl/iteration"),
+    # a gap that crosses from the action pull into the pack is split between them
+    "crossing": ([IT, ("sheeprl/rollout/action_wait", 1, 5), ("np.asarray(jax.Array)", 1, 5), ("sheeprl/rollout/pack", 5, 9)], (),
+                 {"rollout/action_wait": 4, "rollout/pack": 4}, 0, "sheeprl/rollout/action_wait > np.asarray(jax.Array)"),
+    # before the first iteration the traced stretch is outside the loop
+    "before_the_first_iteration": ([("sheeprl/iteration", 5, 10), ("sheeprl/rollout/env_step", 6, 9)], (),
+                                   {"iteration": 1, "rollout/env_step": 3}, 4, "no host span"),
+    # another thread's events are never the span, but its shortest one names the gap after it
+    "another_thread": ([IT, ("sheeprl/log/pull", 1, 9)], [("ReadSyncFlag", 0.5, 2), ("sheeprl/rollout/pack", 0.5, 9.5)],
+                       {"log/pull": 8}, 0, "sheeprl/log/pull > ReadSyncFlag"),
+    # the benchmark's own annotation is no span of the program's
+    "benchmark_annotation": ([IT, ("sheeprl/rollout/env_step", 1, 9), ("env.step", 1, 8)], (),
+                             {"rollout/env_step": 8}, 0, "sheeprl/rollout/env_step > env.step"),
+}
+
+
+@pytest.mark.parametrize("case", list(SPLITS))
+def test_each_idle_gap_goes_to_the_programs_innermost_span(case):
+    main, other, by_span, outside, named = SPLITS[case]
+    got = T.reduce(looped(main, other), chips=1)
+    assert got["idle_by_span_s"] == {k: pytest.approx(v / 1e3) for k, v in by_span.items()}
+    assert got["idle_outside_s"] == pytest.approx(outside / 1e3)
+    assert got["iterations_traced"] == 1
+    assert got["breakdown"]["idle_gaps"][0] == [f"all gaps during: {named}", pytest.approx(0.008)]
+    assert got["breakdown"]["idle_gaps"][1] == [f"one gap at 1.0 ms during: {named}", pytest.approx(0.008)]
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_device_idle_is_in_pull_plus_in_host_plus_outside_the_loop(chips):
+    from benchmark.metrics import device_idle, device_idle_in_host, device_idle_in_pull
+
+    main = [("sheeprl/iteration", 3, 30), ("sheeprl/rollout/action_wait", 4, 9), ("sheeprl/rollout/env_step", 9, 12),
+            ("sheeprl/log/pull", 14, 22), ("sheeprl/log/write", 22, 23), ("sheeprl/iteration", 30, 38)]
+    planes = looped(main, ops=((0, 1), (6, 7), (12, 15), (24, 31), (37, 40)))
+    planes.insert(1, plane("/device:TPU:1", XLA_Ops=[ev("fusion.9", 0, 20), ev("fusion.10", 39, 40)]))
+    run = {"trace": T.reduce(planes, chips=chips)}
+    pull, host = device_idle_in_pull.read(run), device_idle_in_host.read(run)
+    outside = 100 * run["trace"]["idle_outside_s"] / run["trace"]["window_s"]
+    assert device_idle.read(run) == pytest.approx(pull + host + outside, abs=1e-9)
+    if chips == 1:  # idle [1, 6] [7, 12] [15, 24] [31, 37] of a 40 ms window
+        assert pull == pytest.approx(100 * (2 + 2 + 7) / 40)  # action_wait [4, 6] [7, 9], log/pull [15, 22]
+        assert host == pytest.approx(100 * (1 + 3 + 1 + 1 + 6) / 40)  # self [3, 4] [23, 24] [31, 37], env_step [9, 12], log/write [22, 23]
+        assert outside == pytest.approx(100 * 2 / 40)  # [1, 3]
+    assert any(line.startswith("device_idle = in_pull + in_host + outside the loop: ") for line in run["notes"])
+    assert run["trace"]["iterations_traced"] == 2
+
+
+def test_without_the_programs_iterations_the_split_reads_nothing():
+    from benchmark.metrics import device_idle_in_host, device_idle_in_pull
+
+    run = {"trace": T.reduce(synthetic(), chips=1)}  # host events, none of them the program's loop
+    assert run["trace"]["iterations_traced"] == 0 and run["trace"]["idle_by_span_s"] == {}
+    assert device_idle_in_pull.read(run) is None and device_idle_in_host.read(run) is None
+    assert device_idle_in_pull.read({"trace": None}) is None
+
+
 def test_a_trace_with_nothing_on_the_device_is_an_error():
     with pytest.raises(ValueError):
         T.reduce([plane("/host:CPU", python=[["x", 0, 1, ""]])], chips=1)

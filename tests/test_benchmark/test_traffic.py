@@ -1,15 +1,18 @@
 """The traffic generator is a pure function of the seed, and the seed changes
 the order of the work, not its amount."""
 
+import importlib
 import json
 import os
 
 import numpy as np
 import pytest
 
+from benchmark import run as bench_run
 from benchmark.envs.pixel_episodes import Traffic
 
-from .conftest import ROOT
+from . import keyed
+from .conftest import ROOT, load
 
 PARAMS = {"frame_pool": 8, "actions": 18, "episode_steps": [5, 11], "reward_prob": 0.3}
 
@@ -62,15 +65,31 @@ def test_every_row_is_stamped_differently_and_rebuilt_from_the_log():
     assert not load.expected_rows(tampered)[1][3, 1]
 
 
-def test_traffic_files_are_parameters_the_generator_reads():
+def test_traffic_files_are_parameters_the_generator_reads(manifest):
+    """Each file goes to the generator of the driver its cells run (the driver's
+    own `make_traffic`), and every file is some cell's."""
     folder = os.path.join(ROOT, "benchmark", "traffic")
-    for name in os.listdir(folder):
-        with open(os.path.join(folder, name)) as f:
-            t = json.load(f)
-        load = Traffic(t["env"], t["num_envs"], 1)
-        lengths = [length for length, _, _ in load.plan]
-        assert min(lengths) == t["env"]["episode_steps"][0] and max(lengths) == t["env"]["episode_steps"][1]
-        assert t["learning_starts"] // t["num_envs"] >= 64  # a T=64 window per environment
+    used = {w["traffic"] for w in manifest["workloads"]}
+    assert {name[: -len(".json")] for name in os.listdir(folder)} == used
+    for w in manifest["workloads"]:
+        t = load(os.path.join(folder, w["traffic"] + ".json"))
+        config = keyed.config_of(manifest, w["name"])
+        driver = importlib.import_module(f"benchmark.drivers.{config['driver']}")
+        generator = driver.make_traffic(config, t, 1)  # a token file's episode_steps is checked as it is built
+        if isinstance(generator, Traffic):  # the pixel episodes
+            lengths = [length for length, _, _ in generator.plan]
+            assert min(lengths) == t["env"]["episode_steps"][0] and max(lengths) == t["env"]["episode_steps"][1]
+            assert t["learning_starts"] // t["num_envs"] >= 64  # a T=64 window per environment
+
+
+@pytest.mark.parametrize("cell,warmup_iterations", [("sdar_30b_a3b_ep8.rl_bd4", 4096 // 64), ("ouro_2p6b_pp12.rl_ar32", 2048 // 32)])
+def test_the_token_drivers_window_gets_the_numbers_it_had(manifest, cell, warmup_iterations):
+    """`warmup_iterations` is the floor the token drivers read as
+    `learning_starts // num_envs` before the key had its own name."""
+    found = bench_run.load_cell(manifest, cell)
+    driver = importlib.import_module(f"benchmark.drivers.{found['config']['driver']}")
+    w = driver.make_window(found["traffic"], 35.0, lambda: 0)
+    assert (w.seconds, w.warmup_updates, w.warmup_iterations, w.steps_per_update, w.trace) == (35.0, 2, warmup_iterations, 2, None)
 
 
 def first_bunch(traffic, seed, steps=3000):

@@ -1349,7 +1349,6 @@ def main(argv: Sequence[str] | None = None) -> None:
         # which blocks on the iteration's last train step
         telem.mark("log/pull", phase="log")
         drains = pipe.drain_metrics(aggregator, global_step)
-        telem.count(arrays=aggregator.arrays)
         telem.mark("log/write", phase="log")
         # `Time/step_per_second` rides the event of its own step: an iteration
         # is one event for the logger's writer, not two (--pipeline on drains

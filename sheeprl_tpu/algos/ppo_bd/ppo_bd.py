@@ -41,7 +41,7 @@ from ...compile import CompilePlan, sds
 from ...envs import make_vector_env
 from ...envs import token_task  # noqa: F401  (registers the token-task ids)
 from ...ops import precision
-from ...parallel import Pipeline, distributed_setup, process_index
+from ...parallel import Pipeline, distributed_setup, process_index, pull
 from ...telemetry import Telemetry
 from ...utils.checkpoint import load_checkpoint, load_checkpoint_args, save_checkpoint
 from ...utils.env import make_dict_env
@@ -321,7 +321,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                     for name, val in metrics.items():
                         aggregator.update(name, val)
                 telem.mark("log/pull", phase="log")
-                counts = [np.asarray(c) for c in jax.device_get(counts)]  # waits for the update's last train step
+                counts = pull(counts, telem)  # waits for the update's last train step
                 telem.mark(None)
                 telem.count(
                     sequences_trained=trained, pad_positions=pad, positions=len(counts) * args.per_rank_batch_size * dims.layout,

@@ -40,17 +40,20 @@ Every primitive has an `enabled=False` mode that IS the synchronous path
 `--pipeline on|off` and the equivalence receipts in
 tests/test_parallel/test_pipeline.py compare the two modes directly.
 
-Telemetry: construct via :meth:`Pipeline.from_args` and the per-primitive
-stall/overlap gauges (`Pipeline/action_wait_ms`, `Pipeline/sample_hit_rate`,
-...) ride the existing interval merge.
+Every blocking read of the hot loop goes through :func:`pull`, in both
+modes: inside the read's own span (`rollout/action_wait`, `log/pull`) it
+times waiting for the device (`ready`) apart from copying the ready arrays
+(`copy`) and counts the read's `arrays` and `bytes` (howto/pipelining.md).
+Construct via :meth:`Pipeline.from_args`; the prefetcher's gauges
+(`Pipeline/sample_hit_rate`, `Pipeline/sample_prefetches`) ride the
+existing interval merge.
 """
 
 from __future__ import annotations
 
+import functools
 import os
-import time
 from collections import deque
-from typing import Any
 
 import numpy as np
 
@@ -60,21 +63,26 @@ __all__ = [
     "Pipeline",
     "PipelineStats",
     "SamplePrefetcher",
+    "pull",
+    "start_copies",
 ]
 
 
-def _start_copy(leaf: Any) -> Any:
-    """Issue a non-blocking device->host copy for a jax array leaf; host
-    values (numpy, python scalars) pass through untouched."""
-    copy_async = getattr(leaf, "copy_to_host_async", None)
-    if copy_async is not None:
+def start_copies(tree) -> list:
+    """Issue a non-blocking device->host copy for every device array among
+    `tree`'s leaves (host values pass untouched) and return those arrays.
+    Issuing a copy that is in flight or done again is a no-op."""
+    import jax
+
+    arrays = [a for a in jax.tree_util.tree_leaves(tree) if hasattr(a, "copy_to_host_async")]
+    for a in arrays:
         try:
-            copy_async()
+            a.copy_to_host_async()
         # sheeplint: disable=SL012 — prefetch is a pure optimization; the
-        # blocking read below is the correctness path and surfaces real errors
+        # blocking read after it is the correctness path and surfaces real errors
         except Exception:
-            pass  # the blocking read in Handle.get still works
-    return leaf
+            pass  # the blocking read still works
+    return arrays
 
 
 def _tree_map(fn, tree):
@@ -83,25 +91,52 @@ def _tree_map(fn, tree):
     return jax.tree_util.tree_map(fn, tree)
 
 
+def pull(tree, telem=None, convert=None, started: bool = False):
+    """The hot loop's one blocking device->host read: `convert()` (by
+    default every leaf of `tree` as a numpy array) once the device arrays
+    among `tree`'s leaves are defined. Every array's copy is in flight
+    before anything waits, as a plain conversion issues it: `pull` issues
+    them first, all of them together, unless the caller has (`started`).
+
+    With the run's spans on, the read's span (the innermost open one) gets
+    two children: `ready`, issuing those copies and waiting for the
+    arrays' definition, and `copy`, turning the ready arrays into host
+    values; and the counters `arrays` and `bytes` (the arrays' `nbytes`).
+    With them off (no `telem`, or a kill switch) it is the conversion
+    alone, after the copies."""
+    import jax
+
+    if convert is None:
+        convert = functools.partial(_tree_map, np.asarray, tree)
+    if telem is None or not telem.spans:
+        if not started:
+            start_copies(tree)
+        return convert()
+    arrays = [a for a in jax.tree_util.tree_leaves(tree) if hasattr(a, "copy_to_host_async")]
+    telem.count(arrays=len(arrays), bytes=sum(a.nbytes for a in arrays))
+    telem.child("ready")
+    try:
+        if not started:
+            start_copies(arrays)
+        jax.block_until_ready(arrays)
+        telem.child("copy")
+        return convert()
+    finally:
+        telem.child(None)
+
+
 class PipelineStats:
-    """Shared counters behind the `Pipeline/*` telemetry gauges. `flush()`
-    returns the per-interval gauge dict and zeroes the window."""
+    """Shared counters behind the prefetcher's `Pipeline/*` telemetry
+    gauges. `flush()` returns the per-interval gauge dict and zeroes the
+    window."""
 
     def __init__(self) -> None:
-        self.action_wait_s = 0.0
-        self.action_fetches = 0
         self.sample_hits = 0
         self.sample_misses = 0
         self.sample_prefetches = 0
-        self.metric_wait_s = 0.0
-        self.metric_drains = 0
 
     def flush(self) -> dict[str, float]:
-        out: dict[str, float] = {
-            "Pipeline/action_wait_ms": 1e3 * self.action_wait_s,
-            "Pipeline/action_fetches": float(self.action_fetches),
-            "Pipeline/metric_drain_wait_ms": 1e3 * self.metric_wait_s,
-        }
+        out: dict[str, float] = {}
         served = self.sample_hits + self.sample_misses
         if served:
             out["Pipeline/sample_hit_rate"] = self.sample_hits / served
@@ -111,21 +146,17 @@ class PipelineStats:
 
 
 class _Handle:
-    """One dispatched d2h copy; `get()` is the (accounted) blocking read."""
+    """One dispatched d2h copy; `get()` is the blocking read."""
 
-    __slots__ = ("_tree", "_stats")
+    __slots__ = ("_tree", "_telem", "_started")
 
-    def __init__(self, tree, stats: PipelineStats | None):
+    def __init__(self, tree, telem, started: bool):
         self._tree = tree
-        self._stats = stats
+        self._telem = telem
+        self._started = started
 
     def get(self):
-        t0 = time.perf_counter()
-        out = _tree_map(np.asarray, self._tree)
-        if self._stats is not None:
-            self._stats.action_wait_s += time.perf_counter() - t0
-            self._stats.action_fetches += 1
-        return out
+        return pull(self._tree, self._telem, started=self._started)
 
 
 class ActionPipeline:
@@ -134,14 +165,12 @@ class ActionPipeline:
     between. Disabled mode performs the same blocking conversion the
     synchronous loops always did — call sites are mode-agnostic."""
 
-    def __init__(
-        self, enabled: bool = True, lag: int = 0, stats: PipelineStats | None = None
-    ):
+    def __init__(self, enabled: bool = True, lag: int = 0, telem=None):
         if lag < 0:
             raise ValueError(f"lag must be >= 0, got {lag}")
         self.enabled = enabled
         self.lag = lag
-        self._stats = stats if stats is not None else PipelineStats()
+        self._telem = telem
         self._fifo: deque[_Handle] = deque()
 
     def dispatch(self, tree) -> _Handle:
@@ -149,8 +178,8 @@ class ActionPipeline:
         return a handle whose `get()` blocks on the (by then usually
         landed) transfer."""
         if self.enabled:
-            _tree_map(_start_copy, tree)
-        return _Handle(tree, self._stats if self.enabled else None)
+            start_copies(tree)
+        return _Handle(tree, self._telem, started=self.enabled)
 
     def fetch(self, tree):
         """dispatch + read in one call, honoring `lag`: with `lag=0` the
@@ -159,7 +188,7 @@ class ActionPipeline:
         and the first k calls return None (the caller primes with k extra
         policy steps, or falls back to a random action)."""
         if not self.enabled:
-            return _tree_map(np.asarray, tree)
+            return pull(tree, self._telem)
         handle = self.dispatch(tree)
         if self.lag == 0:
             return handle.get()
@@ -277,25 +306,23 @@ class MetricDrain:
     interval's `(metrics, step)` pairs (whose d2h copies were issued one
     interval ago and have landed) and snapshots + resets the current one.
     Disabled mode computes eagerly — identical to the pre-pipeline loops.
-    Call `flush()` after the training loop to resolve the final snapshot."""
+    Call `flush()` after the training loop to resolve the final snapshot.
+    Either way the blocking read is `pull`'s, split under `telem`."""
 
-    def __init__(self, enabled: bool = True, stats: PipelineStats | None = None):
+    def __init__(self, enabled: bool = True, telem=None):
         self.enabled = enabled
-        self._stats = stats if stats is not None else PipelineStats()
+        self._telem = telem
         self._pending: tuple | None = None  # (PendingMetrics, step)
 
     def drain(self, aggregator, step: int) -> list[tuple[dict, int]]:
         if not self.enabled:
-            out = [(aggregator.compute(), step)]
+            out = [(aggregator.compute(self._telem), step)]
             aggregator.reset()
             return out
         out = []
         if self._pending is not None:
             snap, s = self._pending
-            t0 = time.perf_counter()
-            out.append((snap.resolve(), s))
-            self._stats.metric_wait_s += time.perf_counter() - t0
-            self._stats.metric_drains += 1
+            out.append((snap.resolve(self._telem), s))
         self._pending = (aggregator.snapshot(), step)
         aggregator.reset()
         return out
@@ -305,7 +332,7 @@ class MetricDrain:
             return []
         snap, s = self._pending
         self._pending = None
-        return [(snap.resolve(), s)]
+        return [(snap.resolve(self._telem), s)]
 
 
 class Pipeline:
@@ -316,13 +343,13 @@ class Pipeline:
     mains carry ONE code path for both modes."""
 
     def __init__(
-        self, enabled: bool = False, lag: int = 0, max_staleness: int = 0
+        self, enabled: bool = False, lag: int = 0, max_staleness: int = 0, telem=None
     ):
         self.enabled = enabled
         self.max_staleness = max_staleness
         self.stats = PipelineStats()
-        self.action = ActionPipeline(enabled, lag=lag, stats=self.stats)
-        self._drain = MetricDrain(enabled, stats=self.stats)
+        self.action = ActionPipeline(enabled, lag=lag, telem=telem)
+        self._drain = MetricDrain(enabled, telem=telem)
         self._samplers: dict[int, SamplePrefetcher] = {}
 
     @classmethod
@@ -330,11 +357,12 @@ class Pipeline:
         """The mains' shared construction helper: `--pipeline on` enables
         all three primitives (bit-exact defaults: lag=0, strict epoch
         guard); SHEEPRL_TPU_PIPELINE_STALENESS opts into the off-policy
-        staleness relaxation. Registers the `Pipeline/*` gauges on the
-        run's Telemetry when enabled."""
+        staleness relaxation. The reads split their spans under `telem` in
+        both modes; the prefetcher's `Pipeline/*` gauges are registered on
+        it when enabled."""
         enabled = str(getattr(args, "pipeline", "off")) == "on"
         staleness = int(os.environ.get("SHEEPRL_TPU_PIPELINE_STALENESS", "0"))
-        pipe = cls(enabled=enabled, max_staleness=staleness)
+        pipe = cls(enabled=enabled, max_staleness=staleness, telem=telem)
         if telem is not None and enabled:
             telem.add_gauges(pipe.gauges)
         return pipe

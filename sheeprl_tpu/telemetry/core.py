@@ -21,7 +21,9 @@ one-line console heartbeat. A main wires it in ~3 calls:
 `mark` / `phase` are the only two ways a main opens a phase; each is at once
 a `Time/<phase>_seconds` sum, a `sheeprl/<phase>` annotation in any open
 profiler session, and a span kept in memory (phase.py) that `close()` and
-`abort()` write out as `span` events.
+`abort()` write out as `span` events. `child` opens a part of the innermost
+open span that is a span alone, with no sum and no annotation: the one
+blocking read (`parallel/pipeline.py`'s `pull`) splits itself with it.
 
 `interval()` merges everything the subsystem measured since the last call
 into the metric dict (so the phase/compile/memory series ride the existing
@@ -280,9 +282,17 @@ class Telemetry:
         if self.enabled:
             self.timers.iteration(step)
 
+    def child(self, name: str | None) -> None:
+        self.timers.child(name)  # no spans where telemetry is off: one flag test
+
     def count(self, **counters: Any) -> None:
         if self.enabled:
             self.timers.count(**counters)
+
+    @property
+    def spans(self) -> bool:
+        """Whether the loop's spans are kept (off under either kill switch)."""
+        return self.timers.spans
 
     # ---- gauges / events --------------------------------------------------
     def add_gauges(self, source: Callable[[], dict[str, float]]) -> None:

@@ -19,8 +19,14 @@ Three usage styles over one accumulator:
   - `timers.iteration(step)` — a mark of the same linear kind for the loop
     body itself: it ends the open mark and the previous iteration and opens
     the next; `iteration(None)` just ends.
+  - `timers.child("ready")` — a linear section inside the innermost open
+    span (the one opened last), for a part of it that no sum should split:
+    the span is `<parent>/ready`, it adds to no `Time/*` sum (the parent's
+    sum covers it) and opens no annotation, so a profiler's reader still
+    puts what happens inside it down to the parent. `child(None)` just
+    ends. With the spans off it is a flag test.
 
-Every transition does three things in this one place:
+Every transition of the first three does three things in this one place:
 
   1. accumulates seconds per phase (`Time/<phase>_seconds`); `flush()`
      returns them since the last flush and restarts any phase that is still
@@ -71,9 +77,9 @@ class _Open:
 
     __slots__ = ("name", "phase", "t_acc", "t0", "seq", "parent", "step", "annotation", "counters")
 
-    def __init__(self, name: str, phase: str, now: float, seq: int, parent: "_Open | None", step: int | None):
+    def __init__(self, name: str, phase: str | None, now: float, seq: int, parent: "_Open | None", step: int | None):
         self.name = name
-        self.phase = phase  # the sum its seconds go to
+        self.phase = phase  # the sum its seconds go to (a child's: none)
         self.t_acc = self.t0 = now
         self.seq = seq
         self.parent = None if parent is None else parent.seq
@@ -85,12 +91,13 @@ class _Open:
 class PhaseTimers:
     def __init__(self, spans: bool = True, ring: int = RING_SPANS) -> None:
         """`spans=False` (the tracing kill switch) keeps the accumulators
-        alone: no annotation, nothing in the ring."""
+        alone: no annotation, nothing in the ring, no child."""
         self._acc: dict[str, float] = {}
         self._stack: list[_Open] = []  # context-manager nesting
         self._mark: _Open | None = None  # linear mark() section
         self._iter: _Open | None = None  # linear iteration() section
-        self._spans = spans
+        self._child: _Open | None = None  # linear child() section
+        self.spans = spans
         self._seq = 0
         self._id_base = int(new_span_id(), 16)
         # perf_counter orders the spans and is the clock a profiler-side
@@ -103,7 +110,7 @@ class PhaseTimers:
     def _open(self, name: str, now: float, parent: _Open | None, step: int | None = None, phase: str | None = None) -> _Open:
         self._seq += 1
         span = _Open(name, phase or name, now, self._seq, parent, step)
-        if self._spans and TraceAnnotation.is_enabled():  # a profiler session is open
+        if self.spans and TraceAnnotation.is_enabled():  # a profiler session is open
             if name == ITERATION:
                 span.annotation = StepTraceAnnotation(ANNOTATION_PREFIX + name, step_num=step)
             else:
@@ -112,17 +119,26 @@ class PhaseTimers:
         return span
 
     def _close(self, span: _Open, now: float) -> None:
+        if self._child is not None and self._child.parent == span.seq:  # no child outlives its span
+            self._keep(self._child, now)
+            self._child = None
         if span.name != ITERATION:
             self._acc[span.phase] = self._acc.get(span.phase, 0.0) + (now - span.t_acc)
         if span.annotation is not None:
             span.annotation.__exit__(None, None, None)
-        if self._spans:
-            self.ring.append(
-                (span.name, span.t0, now, span.seq, span.parent, span.step, span.counters)
-            )
+        if self.spans:
+            self._keep(span, now)
+
+    def _keep(self, span: _Open, now: float) -> None:
+        self.ring.append((span.name, span.t0, now, span.seq, span.parent, span.step, span.counters))
 
     def _innermost(self) -> _Open | None:
-        return self._stack[-1] if self._stack else (self._mark or self._iter)
+        """The open span opened last: a mark opened inside a live phase is
+        inside it, a phase opened inside a mark is inside the mark."""
+        top = self._stack[-1] if self._stack else None
+        if self._mark is not None and (top is None or self._mark.seq > top.seq):
+            return self._mark
+        return top or self._iter
 
     # ---- context-manager style -------------------------------------------
     @contextmanager
@@ -155,11 +171,26 @@ class PhaseTimers:
             self._close(self._iter, now)
         self._iter = None if step is None else self._open(ITERATION, now, None, step)
 
+    def child(self, name: str | None) -> None:
+        """End the open child (if any) and open `name` inside the innermost
+        open span, as `<parent>/<name>`: a span of the ring alone, with no
+        sum and no annotation."""
+        if not self.spans:
+            return
+        now = time.perf_counter()
+        if self._child is not None:
+            self._keep(self._child, now)
+            self._child = None
+        parent = None if name is None else self._innermost()
+        if parent is not None:
+            self._seq += 1
+            self._child = _Open(f"{parent.name}/{name}", None, now, self._seq, parent, None)
+
     def count(self, **counters: Any) -> None:
         """Attach counters to the innermost open span: what the work between
         its two ends did, recorded where the work happens."""
         span = self._innermost()
-        if span is not None and self._spans:
+        if span is not None and self.spans:
             if span.counters is None:
                 span.counters = counters
             else:
@@ -184,6 +215,7 @@ class PhaseTimers:
         records in `Tracer.end`'s schema (`name`, `span`, `parent`, wall-clock
         `t0` / `t1`, `dur_ms`) plus `step`, the start on the `perf_counter`
         clock (`p0`), and the span's counters."""
+        self.child(None)
         self.iteration(None)
         while self.ring:
             name, t0, t1, seq, parent, step, counters = self.ring.popleft()

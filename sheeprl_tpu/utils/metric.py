@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..parallel.pipeline import pull, start_copies
+
 __all__ = [
     "MetricAggregator",
     "MovingAverageMetric",
@@ -67,10 +69,6 @@ class PackedScalars(Mapping):
     @classmethod
     def tree_unflatten(cls, names, children):
         return cls(names, *children)
-
-    def copy_to_host_async(self) -> None:
-        if self._host is None:
-            self.values.copy_to_host_async()
 
     def host(self) -> np.ndarray:
         """The vector on the host: converted once, on first use."""
@@ -131,23 +129,15 @@ def packed_metrics(body: Callable[..., tuple], loose: Iterable[str] = ()) -> Cal
     return packed
 
 
-def _prefetch(values) -> int:
-    """Start one async device->host copy per device array among `values`
-    (the lanes of one vector share its copy) so that the conversions after
-    find the transfers in flight. Returns the number of arrays."""
+def _device_arrays(values) -> list:
+    """The device arrays among `values`, each once: the lanes of one packed
+    vector share its one array."""
     arrays = {}
     for v in values:
-        src = v.packed if isinstance(v, _Lane) else v
+        src = v.packed.values if isinstance(v, _Lane) else v
         if hasattr(src, "copy_to_host_async"):
             arrays[id(src)] = src
-    for a in arrays.values():
-        try:
-            a.copy_to_host_async()
-        # sheeplint: disable=SL012 — prefetch-only path; compute()'s
-        # blocking pull is the correctness path and raises for real
-        except Exception:
-            pass  # fall back to the blocking pull in compute
-    return len(arrays)
+    return list(arrays.values())
 
 
 class _Snapshot:
@@ -240,9 +230,6 @@ class MovingAverageMetric:
 class MetricAggregator:
     def __init__(self, metrics: dict[str, Any] | None = None) -> None:
         self.metrics: dict[str, Any] = metrics if metrics is not None else {}
-        # device arrays the last compute() / snapshot() pulled (the mains'
-        # `log/pull` counter): one a train step for a packed step's metrics
-        self.arrays = 0
 
     def add(self, name: str, metric: Any | None = None) -> None:
         if name in self.metrics:
@@ -267,13 +254,18 @@ class MetricAggregator:
         else:
             out[name] = val
 
-    def compute(self) -> dict[str, float]:
-        # overlap all pending device pulls before the blocking conversions
-        self.arrays = _prefetch(
+    def compute(self, telem=None) -> dict[str, float]:
+        """The flattened metric dict, read by `parallel.pipeline.pull`
+        (split under `telem`): every pending device array's copy is issued
+        before the first conversion, so that the pulls overlap."""
+        arrays = _device_arrays(
             v
             for metric in self.metrics.values()
             for v in getattr(metric, "pending", list)()
         )
+        return pull(arrays, telem, self._convert)
+
+    def _convert(self) -> dict[str, float]:
         out: dict = {}
         for name, metric in self.metrics.items():
             self._flatten(name, metric.compute(), out)
@@ -294,8 +286,7 @@ class MetricAggregator:
                 snaps[name] = snap_fn()
             else:
                 self._flatten(name, metric.compute(), eager)
-        self.arrays = _prefetch(v for s in snaps.values() for v in s.values)
-        return PendingMetrics(snaps, eager)
+        return PendingMetrics(snaps, eager, start_copies(_device_arrays(v for s in snaps.values() for v in s.values)))
 
     def reset(self, force: bool = False) -> None:
         """Per-logging-interval reset. Metrics that declare
@@ -310,15 +301,19 @@ class MetricAggregator:
 class PendingMetrics:
     """An interval's metric values captured by `MetricAggregator.snapshot()`
     with their d2h copies in flight; `resolve()` performs the (by then
-    cheap) blocking conversions and returns the flattened metric dict."""
+    cheap) blocking read and returns the flattened metric dict."""
 
-    __slots__ = ("_snaps", "_eager")
+    __slots__ = ("_snaps", "_eager", "_arrays")
 
-    def __init__(self, snaps: dict[str, _Snapshot], eager: dict) -> None:
+    def __init__(self, snaps: dict[str, _Snapshot], eager: dict, arrays: list) -> None:
         self._snaps = snaps
         self._eager = eager
+        self._arrays = arrays  # the device arrays whose copies snapshot() issued
 
-    def resolve(self) -> dict:
+    def resolve(self, telem=None) -> dict:
+        return pull(self._arrays, telem, self._convert, started=True)
+
+    def _convert(self) -> dict:
         out = dict(self._eager)
         for name, snap in self._snaps.items():
             MetricAggregator._flatten(name, snap.resolve(), out)

@@ -20,7 +20,9 @@ from sheeprl_tpu.parallel.pipeline import (
     Pipeline,
     PipelineStats,
     SamplePrefetcher,
+    pull,
 )
+from sheeprl_tpu.telemetry import PhaseTimers, Telemetry
 from sheeprl_tpu.utils.metric import MetricAggregator, MovingAverageMetric
 
 
@@ -42,23 +44,49 @@ def test_action_pipeline_fetch_matches_sync_pull():
     np.testing.assert_array_equal(host["b"], np.full(4, 7.0))
 
 
+def _read_and_parts(spans, name):
+    """The read spans of that name and, for each, its children in order."""
+    reads = [s for s in spans if s["name"] == name]
+    return [(r, [c for c in spans if c["parent"] == r["span"]]) for r in reads]
+
+
 def test_action_pipeline_disabled_is_sync():
-    pipe = ActionPipeline(enabled=False, lag=0)
-    out = pipe.fetch(jnp.arange(3))
+    """Disabled mode is the synchronous pull, split as the enabled one is:
+    inside the read's span it waits for the array (`ready`), then copies it
+    (`copy`), and counts its one array and its bytes on the read."""
+    t = PhaseTimers()
+    pipe = ActionPipeline(enabled=False, lag=0, telem=t)
+    dev = jnp.arange(3)
+    t.iteration(0)
+    t.mark("rollout/action_wait")
+    out = pipe.fetch(dev)
+    t.mark(None)
     np.testing.assert_array_equal(out, np.arange(3))
-    assert pipe._stats.action_fetches == 0  # disabled mode is unaccounted
+    ((read, parts),) = _read_and_parts(list(t.drain()), "rollout/action_wait")
+    assert [p["name"] for p in parts] == ["rollout/action_wait/ready", "rollout/action_wait/copy"]
+    assert (read["arrays"], read["bytes"]) == (1, dev.nbytes)
+    assert set(t.flush()) == {"rollout/action_wait"}  # the parts have no sum of their own
 
 
 def test_action_pipeline_ordering_dispatch_then_read():
     """Action t is consumed (read) before obs t+1 would be dispatched: the
     handle returned for step t resolves to step t's values regardless of
-    how many later dispatches were issued in between."""
-    pipe = ActionPipeline(enabled=True)
-    handles = [pipe.dispatch(jnp.full((2,), t, jnp.int32)) for t in range(5)]
-    for t, h in enumerate(handles):
-        np.testing.assert_array_equal(h.get(), np.full((2,), t, np.int32))
-    assert pipe._stats.action_fetches == 5
-    assert pipe._stats.action_wait_s >= 0.0
+    how many later dispatches were issued in between. Each read is split
+    inside its own span, its parts tiling one stretch of it."""
+    t = PhaseTimers()
+    pipe = ActionPipeline(enabled=True, telem=t)
+    t.iteration(0)
+    handles = [pipe.dispatch(jnp.full((2,), s, jnp.int32)) for s in range(5)]
+    for s, h in enumerate(handles):
+        t.mark("rollout/action_wait")
+        np.testing.assert_array_equal(h.get(), np.full((2,), s, np.int32))
+    t.mark(None)
+    reads = _read_and_parts(list(t.drain()), "rollout/action_wait")
+    assert len(reads) == 5
+    for read, (ready, copy) in reads:
+        assert (ready["name"], copy["name"]) == ("rollout/action_wait/ready", "rollout/action_wait/copy")
+        assert read["p0"] <= ready["p0"] and ready["t1"] == copy["t0"] and copy["t1"] <= read["t1"]
+        assert (read["arrays"], read["bytes"]) == (1, 8)
 
 
 def test_action_pipeline_one_step_lag_fifo():
@@ -236,22 +264,139 @@ def test_metric_drain_disabled_is_eager():
     assert drain.flush() == []
 
 
-def test_pipeline_facade_gauges_and_modes():
+def test_pipeline_facade_gauges_and_modes(tmp_path):
+    """`from_args` gives both modes the run's telemetry: their reads are
+    split in the read's span, and the on mode's gauges are the prefetcher's
+    alone; the reads are timed by the spans, not again as gauges."""
+
     class _Args:
         pipeline = "on"
-
-    pipe = Pipeline.from_args(_Args())
-    assert pipe.enabled
-    pipe.action.fetch(jnp.arange(2))
-    g = pipe.gauges()
-    assert "Pipeline/action_wait_ms" in g and g["Pipeline/action_fetches"] == 1.0
-    # flush zeroes the window
-    assert pipe.gauges()["Pipeline/action_fetches"] == 0.0
 
     class _Off:
         pipeline = "off"
 
-    assert not Pipeline.from_args(_Off()).enabled
+    telem = Telemetry(str(tmp_path), rank=0, algo="unit")
+    try:
+        pipe = Pipeline.from_args(_Args(), telem)
+        assert pipe.enabled
+        off = Pipeline.from_args(_Off(), telem)
+        assert not off.enabled
+        telem.iteration(0)
+        for p in (pipe, off):
+            telem.mark("rollout/action_wait", phase="rollout")
+            p.action.fetch(jnp.arange(2))
+        telem.mark(None)
+        assert pipe.gauges() == {"Pipeline/sample_prefetches": 0.0}
+        merged = telem.interval({}, step=0)
+        assert not [k for k in merged if k.startswith("Pipeline/") and k != "Pipeline/sample_prefetches"]
+        assert [k for k in merged if k.startswith("Time/")] == ["Time/rollout_seconds"]
+        reads = _read_and_parts(list(telem.timers.drain()), "rollout/action_wait")
+        assert [[p["name"].rsplit("/", 1)[1] for p in parts] for _, parts in reads] == [["ready", "copy"]] * 2
+    finally:
+        telem.close()
+
+
+@pytest.mark.parametrize("mode", ["off", "on"])
+def test_pull_splits_every_read_and_changes_no_value(mode):
+    """Both reads of a loop, in both modes: the host values are those of a
+    plain `np.asarray`, each read's span holds `ready` then `copy`, and its
+    `bytes` are the device arrays' `nbytes`."""
+    t = PhaseTimers()
+    pipe = Pipeline(enabled=mode == "on", telem=t)
+    agg = MetricAggregator()
+    tree = {"idx": jnp.arange(6, dtype=jnp.int32).reshape(2, 3), "logp": jnp.linspace(-1.0, 0.0, 4, dtype=jnp.bfloat16)}
+    want = {k: np.asarray(v) for k, v in tree.items()}
+    logged = []
+    for step in range(3):
+        t.iteration(step)
+        t.mark("rollout/action_wait")
+        got = pipe.action.fetch(tree)
+        for k in tree:
+            assert got[k].dtype == want[k].dtype
+            np.testing.assert_array_equal(got[k], want[k])
+        _feed(agg)
+        t.mark("log/pull")
+        logged += pipe.drain_metrics(agg, step)
+        t.mark(None)
+    t.iteration(None)
+    logged += pipe.flush_metrics()
+    assert logged == [({"Loss/a": 2.0, "Loss/b": 3.0}, s) for s in range(3)]
+    spans = list(t.drain())
+    actions = _read_and_parts(spans, "rollout/action_wait")
+    assert [(r["arrays"], r["bytes"]) for r, _ in actions] == [(2, 6 * 4 + 4 * 2)] * 3
+    pulls = _read_and_parts(spans, "log/pull")
+    # the deferred drain reads the snapshot an interval old: nothing in the first interval
+    split = pulls if mode == "off" else pulls[1:]
+    assert mode == "off" or (pulls[0][1] == [] and "bytes" not in pulls[0][0])
+    assert [(r["arrays"], r["bytes"]) for r, _ in split] == [(2, 8)] * len(split)  # two float32 scalars an interval
+    for read, parts in actions + split:
+        assert [p["name"] for p in parts] == [read["name"] + "/ready", read["name"] + "/copy"]
+
+
+class _Recorder:
+    """A device array stand-in that records what a read does to it."""
+
+    nbytes = 4
+
+    def __init__(self, log):
+        self.log = log
+
+    def copy_to_host_async(self):
+        self.log.append("copy issued")
+
+    def block_until_ready(self):
+        self.log.append("waited")
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append("converted")
+        return np.zeros(1, np.float32)
+
+
+@pytest.mark.parametrize("spans", [True, False], ids=["spans", "no_spans"])
+@pytest.mark.parametrize("enabled", [False, True], ids=["off", "on"])
+def test_a_reads_copy_is_in_flight_before_it_waits(enabled, spans):
+    """The split must not delay the transfer: a plain conversion issues the
+    copy as the read starts, so `pull` issues it before waiting, once
+    (`--pipeline on` issued it at dispatch), in both modes."""
+    log = []
+    t = PhaseTimers(spans=spans)
+    t.iteration(0)
+    t.mark("rollout/action_wait")
+    ActionPipeline(enabled=enabled, telem=t).fetch(_Recorder(log))
+    assert log == (["copy issued", "waited", "converted"] if spans else ["copy issued", "converted"])
+
+
+@pytest.mark.parametrize("switch", ["no_telemetry", "SHEEPRL_TPU_TRACE", "SHEEPRL_TPU_TELEMETRY"])
+def test_pull_with_the_spans_off_is_the_single_conversion(tmp_path, monkeypatch, switch):
+    """The kill switch leaves a read as it was: one conversion, no wait
+    for the device before it, nothing counted."""
+    telem = None
+    if switch != "no_telemetry":
+        monkeypatch.setenv(switch, "0")
+        telem = Telemetry.from_args(object(), str(tmp_path), algo="unit")
+
+    def refused(*a, **k):
+        raise AssertionError("block_until_ready with the spans off")
+
+    monkeypatch.setattr(jax, "block_until_ready", refused)
+    dev = jnp.arange(4, dtype=jnp.float32)
+    calls = []
+
+    def convert():
+        calls.append(1)
+        return np.asarray(dev)
+
+    if telem is not None:
+        telem.iteration(0)
+        telem.mark("rollout/action_wait")
+    np.testing.assert_array_equal(pull(dev, telem, convert), np.arange(4, dtype=np.float32))
+    np.testing.assert_array_equal(Pipeline(enabled=True, telem=telem).action.fetch(dev), np.arange(4, dtype=np.float32))
+    assert calls == [1]
+    if telem is not None:
+        telem.iteration(None)
+        assert not telem.timers.ring
+        telem.close()
 
 
 def test_pipeline_sampler_is_cached_per_buffer():

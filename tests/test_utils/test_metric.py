@@ -106,21 +106,27 @@ def _steps(n_steps):
 
 def _drained(per_step, metric_cls, deferred):
     """What an interval's drain hands the logger after `per_step` train
-    steps' metrics went into the aggregator as the mains feed it."""
+    steps' metrics went into the aggregator as the mains feed it, and the
+    device arrays its read counted on the `log/pull` span."""
     from sheeprl_tpu.parallel.pipeline import MetricDrain
+    from sheeprl_tpu.telemetry import PhaseTimers
 
     agg = MetricAggregator({n: metric_cls() for n in NAMES})
     for metrics in per_step:
         for name, val in metrics.items():
             agg.update(name, val)
-    drain = MetricDrain(enabled=deferred)
+    timers = PhaseTimers()
+    drain = MetricDrain(enabled=deferred, telem=timers)
+    timers.iteration(0)
+    timers.mark("log/pull")
     out = drain.drain(agg, 7)
     if deferred:
         assert out == []  # the interval's copies are in flight: resolved by the next drain
         out = drain.flush()
     ((resolved, step),) = out
     assert step == 7
-    return resolved, agg.arrays
+    (read,) = [s for s in timers.drain() if s["name"] == "log/pull"]
+    return resolved, read["arrays"]
 
 
 @pytest.mark.parametrize("n_steps", [1, 4])
@@ -150,6 +156,7 @@ class _CountingVector:
 
     def __init__(self, values):
         self._values = np.asarray(values, np.float32)
+        self.nbytes = self._values.nbytes
         self.copies = self.conversions = 0
 
     def copy_to_host_async(self):

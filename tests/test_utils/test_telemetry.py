@@ -240,6 +240,141 @@ def test_phases_lie_in_the_profilers_host_plane_on_its_clock(tmp_path):
     assert [s["name"] for s in t.drain()] == ["before", "iteration", "inner", "rollout/pack", "log/write", "iteration"]
 
 
+class _Clock:
+    """perf_counter / time for phase.py that move only when the test says."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self):
+        return self.now
+
+    def time(self):
+        return self.now
+
+
+def _a_loop(t, clock, children):
+    """Two iterations with a read inside a mark and inside a mark inside a
+    phase; `children` splits each read as `pull` does."""
+    for step in (1, 2):
+        t.iteration(step)
+        t.mark("rollout/action_wait", phase="rollout")
+        clock.now += 1.0
+        if children:
+            t.child("ready")
+            clock.now += 3.0
+            t.child("copy")
+            clock.now += 0.5
+            t.child(None)
+        else:
+            clock.now += 3.5
+        t.mark(None)
+        with t.phase("update"):
+            t.mark("log/pull", phase="log")
+            if children:
+                t.child("ready")
+            clock.now += 2.0
+            t.mark(None)
+        clock.now += 0.25
+
+
+def test_a_child_adds_to_no_sum_and_leaves_the_flush_as_it_was(monkeypatch):
+    from sheeprl_tpu.telemetry import phase
+
+    flushed = []
+    for children in (False, True):
+        clock = _Clock()
+        monkeypatch.setattr(phase, "time", clock)
+        t = PhaseTimers()
+        _a_loop(t, clock, children)
+        flushed.append(t.flush())
+    assert flushed[0] == flushed[1] == {"rollout": 9.0, "update": 4.0, "log": 4.0}
+
+
+def test_a_child_is_named_and_parented_by_the_innermost_open_span():
+    t = PhaseTimers()
+    t.child("ready")  # nothing open: no child
+    _a_loop(t, _Clock(), children=True)
+    spans = list(t.drain())
+    by_id = {s["span"]: s for s in spans}
+    parts = [s for s in spans if s["name"].endswith(("/ready", "/copy"))]
+    assert [s["name"] for s in parts] == ["rollout/action_wait/ready", "rollout/action_wait/copy", "log/pull/ready"] * 2
+    for s in parts:
+        parent = by_id[s["parent"]]
+        assert s["name"].startswith(parent["name"] + "/") and s["step"] == parent["step"]
+        assert parent["p0"] <= s["p0"] and s["t1"] <= parent["t1"]
+    # a mark opened inside a live phase is the innermost span: the child (and a count) is the mark's,
+    # and the mark closing ends the child left open inside it
+    pulls = [s for s in spans if s["name"] == "log/pull"]
+    assert {by_id[s["parent"]]["name"] for s in parts if s["name"] == "log/pull/ready"} == {"log/pull"}
+    assert all(by_id[s["parent"]]["name"] == "iteration" for s in pulls)
+
+
+def test_a_count_goes_to_the_span_opened_last():
+    t = PhaseTimers()
+    t.iteration(0)
+    with t.phase("update"):
+        t.mark("log/pull", phase="log")
+        t.count(arrays=2)
+        t.mark(None)
+        t.count(sequences=8)
+    t.mark("rollout/pack")
+    with t.phase("inner"):
+        t.count(n=1)
+    t.iteration(None)
+    by = {s["name"]: s for s in t.drain()}
+    assert by["log/pull"]["arrays"] == 2 and by["update"]["sequences"] == 8 and by["inner"]["n"] == 1
+    assert "arrays" not in by["update"] and "n" not in by["rollout/pack"]
+
+
+def test_a_child_opens_no_annotation_in_a_profiler_session(monkeypatch):
+    """With a session open the read's span is annotated and its parts are
+    not: a profiler's reader puts what happens in them down to the read."""
+    from sheeprl_tpu.telemetry import phase
+
+    opened = []
+
+    class _Annotation:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(phase, "TraceAnnotation", _Annotation)
+    monkeypatch.setattr(phase, "StepTraceAnnotation", _Annotation)
+    t = PhaseTimers()
+    _a_loop(t, _Clock(), children=True)
+    assert opened == ["sheeprl/iteration", "sheeprl/rollout/action_wait", "sheeprl/update", "sheeprl/log/pull"] * 2
+    assert sum(s["name"].endswith(("/ready", "/copy")) for s in t.drain()) == 6
+
+
+@pytest.mark.parametrize("switch", ["spans_false", "SHEEPRL_TPU_TRACE", "SHEEPRL_TPU_TELEMETRY"])
+def test_the_kill_switches_leave_no_child(tmp_path, monkeypatch, switch):
+    if switch == "spans_false":
+        t = PhaseTimers(spans=False)
+    else:
+        monkeypatch.setenv(switch, "0")
+        t = Telemetry.from_args(object(), str(tmp_path), algo="unit")
+    t.iteration(1)
+    t.mark("log/pull")
+    t.child("ready")
+    t.child("copy")
+    t.child(None)
+    t.iteration(None)
+    ring = t.ring if switch == "spans_false" else t.timers.ring
+    assert not t.spans and not ring
+    if switch != "spans_false":
+        t.close()
+
+
 # ---------------------------------------------------------------------------
 # compile tracker
 # ---------------------------------------------------------------------------

@@ -13,6 +13,10 @@ finished OR crashed run:
   - a phase-breakdown table: total seconds and share of accounted time per
     phase (`rollout`, `buffer/sample`, `train/dispatch`, ...), from the
     `Time/<phase>_seconds` series in the `log` events;
+  - the blocking device-to-host reads, from the `span` records: per read
+    span that the program split (`rollout/action_wait`, `log/pull`), its
+    median `ready` (waiting for the device) and `copy` (copying the ready
+    arrays) and its bytes a read;
   - throughput (mean / last step-per-second) and XLA compile accounting
     (total compiles, compile seconds, recompiles AFTER the first logging
     interval — the retrace-storm signal);
@@ -46,6 +50,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import statistics
 import sys
 
 
@@ -138,6 +143,7 @@ def summarize(events: list[dict]) -> dict:
         "sync_events": [],          # sync.* events (order_violation,
                                     # sanitizer_start/stop)
         "sync_gauges": {},          # last Sync/* gauge values
+        "spans": [],                # the loop's `span` records (phase.py)
     }
     for ev in events:
         ts = ev.get("ts")
@@ -193,6 +199,8 @@ def summarize(events: list[dict]) -> dict:
             summary["serve_events"].append(ev)
         elif isinstance(kind, str) and kind.startswith("sync."):
             summary["sync_events"].append(ev)
+        elif kind == "span":
+            summary["spans"].append(ev)
         elif kind == "log":
             summary["log_events"] += 1
             if ev.get("step") is not None:
@@ -244,7 +252,40 @@ def summarize(events: list[dict]) -> dict:
                 summary["phase_seconds"][phase] = (
                     summary["phase_seconds"].get(phase, 0.0) + secs
                 )
+    summary["reads"] = read_split(summary["spans"])
     return summary
+
+
+def read_split(spans: list[dict]) -> dict:
+    """Per read span that the program split into `ready` and `copy`
+    (parallel/pipeline.py's `pull`): the count of reads, the median of each
+    part, and the median `bytes` a read."""
+    by_id = {s.get("span"): s for s in spans}
+    parts: dict = {}
+    for s in spans:
+        read = by_id.get(s.get("parent"))
+        if read is None:
+            continue
+        part = s.get("name", "")[len(read.get("name", "")) + 1:]
+        if part in ("ready", "copy") and s["name"] == f"{read['name']}/{part}":
+            parts.setdefault(read["span"], {})[part] = s.get("dur_ms", 0.0)
+    grouped: dict = {}
+    for span_id, split in parts.items():
+        read = by_id[span_id]
+        g = grouped.setdefault(read["name"], {"ready": [], "copy": [], "bytes": []})
+        g["ready"].append(split.get("ready", 0.0))
+        g["copy"].append(split.get("copy", 0.0))
+        if "bytes" in read:
+            g["bytes"].append(read["bytes"])
+    return {
+        name: {
+            "reads": len(g["ready"]),
+            "ready_ms": statistics.median(g["ready"]),
+            "copy_ms": statistics.median(g["copy"]),
+            "bytes": statistics.median(g["bytes"]) if g["bytes"] else None,
+        }
+        for name, g in grouped.items()
+    }
 
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -717,6 +758,18 @@ def render(summary: dict) -> str:
         lines.append("no phase timings recorded")
 
     lines.append("")
+    lines.append("== reads (device-to-host) ==")
+    if summary["reads"]:
+        for name, r in sorted(summary["reads"].items()):
+            moved = "" if r["bytes"] is None else f", {r['bytes']:.0f} bytes a read"
+            lines.append(
+                f"{name}: {r['reads']} reads, median ready {r['ready_ms']:.3f} ms "
+                f"+ copy {r['copy_ms']:.3f} ms{moved}"
+            )
+    else:
+        lines.append("no split reads recorded (spans off, or a run from before the split)")
+
+    lines.append("")
     lines.append("== throughput / compiles ==")
     if summary["sps_series"]:
         sps = summary["sps_series"]
@@ -1073,6 +1126,11 @@ def selftest() -> int:
     for step in (10, 20, 30):
         telem.mark("rollout")
         telem.mark("train/dispatch")
+        telem.mark("log/pull", phase="log")
+        telem.count(arrays=1, bytes=52)  # what parallel/pipeline.py's `pull` records
+        telem.child("ready")
+        telem.child("copy")
+        telem.child(None)
         telem.mark("log")
         metrics = {"Loss/x": 0.5}
         if step == 20:
@@ -1100,6 +1158,8 @@ def selftest() -> int:
     assert len(summary["compile_events"]) == 1
     assert summary["compile_events"][0]["jit"] == "train_step"
     assert summary["compile_events"][0]["cache_misses"] == 1
+    reads = summary["reads"]["log/pull"]
+    assert reads["reads"] == 3 and reads["bytes"] == 52 and reads["ready_ms"] >= 0.0, reads
 
     # comms-budget section: writer (sheepshard ledger schema) and this
     # reader stay in sync — rendered from a synthetic ledger, and the

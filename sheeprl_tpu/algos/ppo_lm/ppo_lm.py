@@ -20,6 +20,17 @@ Every program of the loop has one shape whatever a step brings: the prefill
 takes a group's prompts padded to the longest (a group's members end on the
 same step, so resets arrive a group at a time), the train step always
 `per_rank_batch_size` sequences of `P_max + R_max` positions.
+
+One policy step is always queued ahead of the host's read: step t + 1 needs
+nothing from the host (its fed token is step t's sample, already on the
+device), so it is dispatched on the state step t left before step t's action
+is pulled, and the device runs it while the host packs, steps the
+environments and logs. Where step t ended a group (its rows are prefilled) or
+an update runs (the player changes), the step queued ahead read what the host
+is about to change: it is dropped (the state rolled back a position), and
+dispatched again with the same key behind the prefill and the train steps.
+The samples, the trained batches and the weights are the plain order's, bit
+for bit; only when a step is enqueued moves.
 """
 
 from __future__ import annotations
@@ -40,6 +51,7 @@ from ...envs import make_vector_env
 from ...envs import token_task  # noqa: F401  (registers the token-task ids)
 from ...ops import precision
 from ...parallel import Pipeline, distributed_setup, process_index
+from ...parallel.pipeline import start_copies
 from ...telemetry import Telemetry
 from ...utils.checkpoint import load_checkpoint, load_checkpoint_args, save_checkpoint
 from ...utils.env import make_dict_env
@@ -77,6 +89,19 @@ def make_policy_step(args: PPOLMArgs):
             return state.replace(last=ids), packed, logits
 
     return donating_jit(lm_policy_step, donate_argnums=(1,))
+
+
+def make_rollback():
+    """-> jitted (pos, packed) -> (pos, last): the positions and the fed tokens
+    that the policy step before the last one left, from the last one's
+    positions and that earlier step's packed action (its sampled ids are fed
+    again, a position back). The caches are not touched: the slot at that
+    position keeps what the dropped step wrote, which the next policy step
+    writes before it reads it. `pos` is donated."""
+    def lm_rollback(pos, packed):
+        return pos - 1, packed[:, 0]
+
+    return donating_jit(lm_rollback, donate_argnums=(0,))
 
 
 def make_train_step(args: PPOLMArgs, optimizer):
@@ -193,6 +218,7 @@ def main(argv: Sequence[str] | None = None) -> None:
         return spec
 
     policy_step = plan.register("policy_step", make_policy_step(args), example=lambda: (player, pstate, key))
+    rollback = plan.register("rollback", make_rollback(), example=lambda: (sds((args.num_envs,), jnp.int32), sds((args.num_envs, 3), jnp.int32)))
     train_step = plan.register("train_step", make_train_step(args, optimizer), example=lambda: (state, player, batch_spec()), role="update")
     plan.start()
 
@@ -216,8 +242,18 @@ def main(argv: Sequence[str] | None = None) -> None:
             record.start(int(i), obs["prompt"][i], int(obs["prompt_len"][i, 0]))
         return pstate
 
+    def dispatch(pstate, step_key):
+        """A policy step queued on the device, the copy of its packed action
+        issued before the step after it is queued (with the pipeline off too)."""
+        pstate, packed_dev, _ = policy_step(player, pstate, step_key)
+        start_copies(packed_dev)
+        return pstate, packed_dev, pipe.action.dispatch(packed_dev)
+
     obs, _ = envs.reset(seed=seeds)
     pstate = prefill(pstate, obs, np.arange(args.num_envs))
+    key, ahead_key = jax.random.split(key)
+    pstate, ahead_dev, ahead = dispatch(pstate, ahead_key)
+    kept = 1  # the step read next was queued ahead and kept (1), or dispatched again after a drop (0)
 
     num_updates = args.total_steps // args.num_envs if not args.dry_run else 10**9  # a dry run ends with its first update
     update_sequences = args.update_sequences if not args.dry_run else args.group_size
@@ -226,11 +262,11 @@ def main(argv: Sequence[str] | None = None) -> None:
     for global_step in range(start_step, num_updates + 1):
         guard.tick(global_step)
         telem.iteration(global_step)
-        # ---- one token of every environment ---------------------------------
+        # ---- one token of every environment: step t read, step t + 1 queued behind it
         telem.mark("rollout/decode_dispatch", phase="rollout")
-        key, step_key = jax.random.split(key)
-        pstate, packed_dev, _ = policy_step(player, pstate, step_key)
-        handle = pipe.action.dispatch(packed_dev)
+        packed_dev, handle = ahead_dev, ahead
+        key, ahead_key = jax.random.split(key)
+        pstate, ahead_dev, ahead = dispatch(pstate, ahead_key)
         telem.mark("rollout/action_wait", phase="rollout")
         packed = np.asarray(handle.get())  # the ONLY per-step d2h pull: the token, its log-probability and exit loop
         telem.mark("rollout/pack", phase="rollout")
@@ -239,7 +275,7 @@ def main(argv: Sequence[str] | None = None) -> None:
         read = np.minimum(-(-(fed + 1) // block) * block, dims.s_max)
         record.add(tokens, np.ascontiguousarray(packed[:, 1]).view(np.float32))
         telem.count(tokens_committed=len(tokens), loop_applications=len(tokens) * R * N, exit_loop=np.bincount(packed[:, 2], minlength=R).tolist(),
-                    attn_slots_read=int(read.sum()), attn_slots_held=args.num_envs * dims.s_max)
+                    attn_slots_read=int(read.sum()), attn_slots_held=args.num_envs * dims.s_max, ahead_kept=kept)
         telem.mark("rollout/env_step", phase="rollout")
         obs, rewards, terms, truncs, infos = envs.step(list(tokens[:, None]))
         dones = np.logical_or(terms, truncs)
@@ -248,17 +284,25 @@ def main(argv: Sequence[str] | None = None) -> None:
             if "episode" in info:
                 aggregator.update("Rewards/rew_avg", float(info["episode"]["r"]))
                 aggregator.update("Game/ep_len_avg", float(info["episode"]["l"]))
-        if dones.any():
+        which = np.flatnonzero(dones)
+        for i in which:
+            record.finish(int(i), float(rewards[i]))
+        update = len(record.ready) >= update_sequences
+        kept = int(not (len(which) or update))
+        if not kept:  # step t + 1 read slots the prefill rewrites, or the player the update replaces
+            telem.mark("rollout/ahead_drop", phase="rollout")
+            pos, last = rollback(pstate.pos, packed_dev)
+            pstate = pstate.replace(pos=pos, last=last)
+            telem.count(ahead_dropped=1)
+            telem.mark(None)
+        if len(which):
             telem.mark("rollout/prefill", phase="rollout")
-            which = np.nonzero(dones)[0]
-            for i in which:
-                record.finish(int(i), float(rewards[i]))
             pstate = prefill(pstate, obs, which)
             telem.count(cache_slots_reset=len(which))
             telem.mark(None)
 
         # ---- the update: one epoch over the finished sequences --------------
-        if len(record.ready) >= update_sequences:
+        if update:
             with telem.phase("update"):
                 seqs, record.ready = record.ready[:update_sequences], record.ready[update_sequences:]
                 pad = trained = 0
@@ -279,6 +323,10 @@ def main(argv: Sequence[str] | None = None) -> None:
                 telem.mark(None)
                 telem.count(sequences_trained=trained, pad_positions=pad, positions=len(lengths) * args.per_rank_batch_size * dims.s_max, lengths=lengths)
             updates_done += 1
+        if not kept:  # step t + 1 again, with the key it was given, queued behind the prefill and the train steps
+            telem.mark("rollout/decode_dispatch", phase="rollout")
+            pstate, ahead_dev, ahead = dispatch(pstate, ahead_key)
+            telem.mark(None)
 
         sps = (global_step - start_step + 1) * args.num_envs / (time.perf_counter() - start_time)
         telem.mark("log/pull", phase="log")

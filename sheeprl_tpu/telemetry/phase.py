@@ -67,8 +67,10 @@ __all__ = ["ITERATION", "PhaseTimers"]
 ANNOTATION_PREFIX = "sheeprl/"
 ITERATION = "iteration"
 # closed spans kept: the newest hours of a slow loop, minutes of a fast one
-# (12 to 18 spans an iteration in the DreamerV3 main), ~200 B each
-RING_SPANS = 32768
+# (12 to 18 spans an iteration in the DreamerV3 main), ~200 B each. The
+# `ppo_lm` main opens ~11 an iteration of ~5 ms, so a benchmark window of
+# 35 s and the traced stretch after it hold ~36,000: half the ring
+RING_SPANS = 65536
 
 
 class _Open:

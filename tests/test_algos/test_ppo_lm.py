@@ -284,11 +284,204 @@ def test_the_policy_steps_count_the_cache_slots_they_read(tmp_path, monkeypatch,
         events = [json.loads(line) for line in f if line.strip()]
     packs = [e for e in events if e.get("event") == "span" and e["name"] == "rollout/pack"]
     block = pk.DECODE_BLOCK if kernel else LONG.s_max
-    assert len(packs) == len(fed) >= LONG.r_max
-    for span, pos in zip(packs, fed):
+    assert len(packs) >= LONG.r_max
+    # the call each pack read: one step is queued ahead of every read, and a dropped one is dispatched again
+    drops = {e["step"] for e in events if e.get("event") == "span" and e["name"] == "rollout/ahead_drop"}
+    packed_calls, calls = [], 0
+    for span in packs:
+        packed_calls.append(calls)
+        calls += 1 + (span["step"] in drops)
+    assert len(fed) == calls + 1  # the last step queued is never read
+    for span, pos in zip(packs, [fed[i] for i in packed_calls]):
         assert span["attn_slots_held"] == 8 * LONG.s_max
         assert span["attn_slots_read"] == int(np.minimum(-(-(pos + 1) // block) * block, LONG.s_max).sum())
     if kernel:
         assert max(s["attn_slots_read"] for s in packs) < 8 * LONG.s_max  # some row's cache read short of its end
     picks = [(e["selected"], e["reason"]) for e in events if e.get("event") == "kernel.select" and e["family"] == "decode_attn"]
     assert picks == [(True, "ok") if kernel else (False, "disabled")]
+
+
+# ---- one policy step queued ahead of the host's read: dropped, rolled back and run again where the host changes what it read
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "twin"])
+def test_a_step_rolled_back_and_run_again_equals_one_step(kernel_interpret, kernel):
+    """A step, a second one dropped (rolled back a position), two rows given
+    new prompts (one of them fed where its last block holds the slot past the
+    prompts' that the dropped step wrote), and the second step run again at
+    its key: the packed action, the logits, the positions and every (loop,
+    layer) cache's slots up to each row's position are those of the plain
+    order, bit for bit."""
+    c = config(**WIDE)
+    args, model, _ = program(c)
+    pk.set_pallas(kernel, interpret=kernel)
+    step = ppo_lm.make_policy_step(args)
+    rollback = ppo_lm.make_rollback()
+    prefill = jax.jit(lambda s, prompts, lengths, idx: model.prefill(s, prompts, lengths, idx))
+    rng = np.random.default_rng(13)
+    prompts = rng.integers(0, V, (2, 4, LONG.p_max)).astype(np.int32)
+    # row 1's dropped step writes slot 248, which no prefill writes; it restarts fed at 199, in the same block
+    lengths = np.array([[5, LONG.p_max, 130, 127], [0, 200, 129, 0]])
+    state = prefill(model.init_states(4, LONG.s_max, jnp.float32), jnp.asarray(prompts[0]), jnp.asarray(lengths[0]), jnp.arange(4))
+    state, first, _ = step(model, state, jax.random.PRNGKey(1))
+    copy = lambda s: jax.tree_util.tree_map(jnp.copy, s)
+    renew = lambda s: prefill(s, jnp.asarray(prompts[1, 1:3]), jnp.asarray(lengths[1, 1:3]), jnp.asarray([1, 2]))
+
+    plain, packed, logits = step(model, renew(copy(state)), jax.random.PRNGKey(2))
+    ahead, _, _ = step(model, copy(state), jax.random.PRNGKey(2))
+    pos, last = rollback(ahead.pos, first)
+    again, packed_again, logits_again = step(model, renew(ahead.replace(pos=pos, last=last)), jax.random.PRNGKey(2))
+
+    assert np.array_equal(np.asarray(packed_again), np.asarray(packed)) and np.array_equal(np.asarray(logits_again), np.asarray(logits))
+    pos = np.asarray(plain.pos)
+    assert pos.tolist() == np.asarray(again.pos).tolist() == [6, 200, 129, 128]
+    assert np.array_equal(np.asarray(again.last), np.asarray(plain.last))
+    for a, b in zip(again.cache_k + again.cache_v, plain.cache_k + plain.cache_v):
+        a, b = np.asarray(a), np.asarray(b)
+        for row, p in enumerate(pos):
+            assert np.array_equal(a[row, :p], b[row, :p])
+        assert np.array_equal(a[[0, 2, 3]], b[[0, 2, 3]])  # the rows that went on: the slot written again, all equal
+        assert not np.array_equal(a[1, LONG.p_max], b[1, LONG.p_max])  # the restarted row keeps the dropped step's slot past its position
+
+
+class ScheduledTask(TokenTask):
+    """The one-id-a-step token task with the response lengths given by the
+    seed a group shares, episode after episode."""
+
+    def __init__(self, lengths: dict, **kw):
+        super().__init__(block_length=1, max_prompt=16, min_prompt=4, max_response=8, min_response=1, **kw)
+        self.lengths, self.schedule = lengths, iter(())
+
+    def reset(self, *, seed=None, options=None):
+        if seed is not None:
+            self.schedule = iter(self.lengths[seed])
+        return super().reset(seed=seed, options=options)
+
+    def pose(self):
+        prompt, _ = super().pose()
+        return prompt, next(self.schedule)
+
+
+def test_the_loop_one_step_ahead_trains_what_the_plain_order_trains(tmp_path, monkeypatch):
+    """Ten iterations through the CLI, 4 groups of 2 whose first episodes end
+    at steps 3, 5, 5 and 5 (three groups on one step) and two of whose second
+    ones end together at step 8, an update per 4 finished sequences in 2 train
+    steps: a group end with no update (step 3), updates with a group end and
+    one with none (step 6). Every trained batch (`ids`, `targets`,
+    `loss_pos`, `logprob_old` as bits) and the weights after every train step
+    equal those of the plain order (step t dispatched after the host's turn
+    at t - 1), written here from the module's own programs on the same
+    weights and keys. Each packed step was queued ahead and kept, or
+    dispatched again after a drop."""
+    import gymnasium as gym
+
+    from sheeprl_tpu.algos.ppo_bd.ppo_bd import TrainState
+    from sheeprl_tpu.algos.ppo_lm.agent import player_copy
+    from sheeprl_tpu.envs import make_vector_env
+    from sheeprl_tpu.ops import precision
+    from sheeprl_tpu.utils.env import make_dict_env
+
+    seed, envs_n, group, iterations = 7, 8, 2, 10
+    lengths = {seed: [3, 4, 8], seed + 1: [5, 3, 8], seed + 2: [5, 3, 8], seed + 3: [5, 5, 8]}  # by group, episode after episode
+    env_id = "TokenTaskARScheduled-v0"
+    if env_id in gym.registry:
+        del gym.registry[env_id]
+    gym.register(env_id, entry_point=ScheduledTask, kwargs={"lengths": lengths})
+    flags = dict(num_envs=envs_n, group_size=group, update_sequences=4, per_rank_batch_size=2, num_hidden_layers=1, hidden_size=32, head_dim=8,
+                 intermediate_size=64, total_ut_steps=2, seed=seed, total_steps=envs_n * iterations)
+
+    def trained(record):
+        return lambda batch, new: record.append(({k: np.asarray(batch[k]) for k in ("ids", "targets", "loss_pos", "logprob_old")},
+                                                 [np.asarray(x) for x in jax.tree_util.tree_leaves(new.model)]))
+
+    got, calls, real_train, real_policy = [], [0], ppo_lm.make_train_step, ppo_lm.make_policy_step
+
+    def recording_train(*a, **kw):
+        step, keep = real_train(*a, **kw), trained(got)
+
+        def call(state, player, batch):
+            out = step(state, player, batch)
+            keep(batch, out[0])
+            return out
+
+        return call
+
+    def counting_policy(*a, **kw):
+        step = real_policy(*a, **kw)
+
+        def call(*args):
+            calls[0] += 1
+            return step(*args)
+
+        return call
+
+    monkeypatch.setattr(ppo_lm, "make_train_step", recording_train)
+    monkeypatch.setattr(ppo_lm, "make_policy_step", counting_policy)
+    try:
+        cli_run(["ppo_lm", "--sync_env", f"--env_id={env_id}", f"--root_dir={tmp_path}", "--run_name=test", *(f"--{k}={v}" for k, v in flags.items())])
+    finally:
+        monkeypatch.undo()
+
+    # the plain order: dispatch step t, read it, step the environments, prefill, update; then step t + 1
+    args = PPOLMArgs(env_id=env_id, sync_env=True, **flags)
+    envs = make_vector_env([make_dict_env(env_id, seed + i // group, rank=0, args=args, run_name=str(tmp_path), vector_env_idx=i) for i in range(envs_n)])
+    space = envs.single_observation_space
+    dims = Dims(p_max=space["prompt"].shape[0], r_max=int(space["response_len"].high.max()))
+    key = jax.random.PRNGKey(seed)
+    key, model_key = jax.random.split(key)
+    model = ppo_lm.build_models(model_key, args, int(space["prompt"].high.max()) + 1)
+    optimizer = ppo_lm.make_optimizer(args)
+    state = TrainState(model=model, opt_state=optimizer.init(model))
+    compute = precision.compute_dtype(args.precision)
+    player = jax.jit(lambda m: player_copy(m, compute))(state.model)
+    pstate = player.init_states(envs_n, dims.s_max, compute)
+    policy_step, train_step = ppo_lm.make_policy_step(args), ppo_lm.make_train_step(args, optimizer)
+    prefill_step = jax.jit(lambda p, s, prompts, n, idx: p.prefill(s, prompts, n, idx), donate_argnums=(1,))
+    record, want = Record(envs_n, group, dims), []
+    keep = trained(want)
+
+    def prefill(pstate, obs, which):
+        for lo in range(0, len(which), group):
+            idx = np.full(group, envs_n, np.int32)
+            idx[: len(which[lo : lo + group])] = which[lo : lo + group]
+            rows = np.minimum(idx, envs_n - 1)
+            pstate = prefill_step(player, pstate, jnp.asarray(obs["prompt"][rows].astype(np.int32)), jnp.asarray(obs["prompt_len"][rows, 0].astype(np.int32)), jnp.asarray(idx))
+        for i in which:
+            record.start(int(i), obs["prompt"][i], int(obs["prompt_len"][i, 0]))
+        return pstate
+
+    obs, _ = envs.reset(seed=[seed + i // group for i in range(envs_n)])
+    pstate = prefill(pstate, obs, np.arange(envs_n))
+    for _ in range(iterations):
+        key, step_key = jax.random.split(key)
+        pstate, packed, _ = policy_step(player, pstate, step_key)
+        packed = np.asarray(packed)
+        record.add(packed[:, 0], np.ascontiguousarray(packed[:, 1]).view(np.float32))
+        obs, rewards, terms, truncs, _ = envs.step(list(packed[:, 0][:, None]))
+        which = np.flatnonzero(np.logical_or(terms, truncs))
+        for i in which:
+            record.finish(int(i), float(rewards[i]))
+        if len(which):
+            pstate = prefill(pstate, obs, which)
+        if len(record.ready) >= args.update_sequences:
+            seqs, record.ready = record.ready[: args.update_sequences], record.ready[args.update_sequences :]
+            for lo in range(0, len(seqs), args.per_rank_batch_size):
+                batch = {k: jnp.asarray(v) for k, v in build_batch(seqs[lo : lo + args.per_rank_batch_size], args.per_rank_batch_size, dims).items()}
+                state, player, _ = train_step(state, player, batch)
+                keep(batch, state)
+    envs.close()
+
+    assert len(got) == len(want) == 8  # updates at steps 5, 6 (no group ends), 8 and 10, two train steps each
+    for (batch, weights), (want_batch, want_weights) in zip(got, want):
+        for k in ("ids", "targets", "loss_pos"):
+            assert np.array_equal(batch[k], want_batch[k]), k
+        assert np.array_equal(batch["logprob_old"].view(np.int32), want_batch["logprob_old"].view(np.int32))
+        assert all(np.array_equal(a.view(np.uint32), b.view(np.uint32)) for a, b in zip(weights, want_weights))
+
+    with open(os.path.join(tmp_path, "test", "telemetry.jsonl")) as f:
+        spans = [e for e in map(json.loads, filter(str.strip, f)) if e.get("event") == "span"]
+    packs = [s for s in spans if s["name"] == "rollout/pack"]
+    drops = [s["step"] for s in spans if s["name"] == "rollout/ahead_drop"]
+    prefilled = {s["step"]: s["cache_slots_reset"] for s in spans if s["name"] == "rollout/prefill"}
+    assert len(packs) == iterations and drops == [3, 5, 6, 7, 8, 10] and prefilled == {3: 2, 5: 6, 7: 2, 8: 4, 10: 2}
+    assert len(packs) == sum(s["ahead_kept"] for s in packs) + len([d for d in drops if d < iterations])  # kept, or dispatched again
+    assert calls[0] == 1 + len(packs) + len(drops)  # one queued before the loop, one ahead an iteration, one again a drop
+    assert all(s["ahead_dropped"] == 1 for s in spans if s["name"] == "rollout/ahead_drop")
